@@ -49,10 +49,9 @@ def bench_word2vec(n_sentences=100000, sent_len=20, vocab=10000, epochs=1,
     import jax.numpy as jnp
 
     def sync():
-        # real device barrier: the SGNS epochs dispatch asynchronously, so
-        # wall time without a sync measures the host pipeline only
-        # (block_until_ready can no-op on remote-attach backends; a host
-        # materialization cannot)
+        # device barrier: the SGNS epochs dispatch asynchronously, so wall
+        # time without a sync measures the host pipeline only; reading a
+        # scalar back to the host waits for the table
         float(jnp.asarray(w2v.lookup_table.syn0).sum())
 
     total_words = n_sentences * sent_len * epochs

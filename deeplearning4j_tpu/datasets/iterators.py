@@ -42,8 +42,7 @@ class DataSet:
 
     def device_tuple(self):
         """(features, labels, features_mask, labels_mask) as device arrays,
-        cached so refitting the same DataSet pays host->device transfer once
-        (the transfer, not compute, dominates through a thin host link).
+        cached so refitting the same DataSet pays host->device transfer once.
 
         The cache holds references to the host arrays and is invalidated when
         any field is REASSIGNED (`is` comparison — shuffle() etc. do this).

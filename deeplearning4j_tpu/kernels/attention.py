@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_attention_spmd", "attention_reference"]
+__all__ = ["flash_attention", "flash_attention_heads", "flash_attention_spmd",
+           "attention_reference"]
 
 _NEG_INF = float("-inf")
 
@@ -184,6 +185,7 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             pltpu.VMEM((bq, D), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp)
     if not emit_lse:
         return res[0][:, :T], None
@@ -317,6 +319,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, sm_scale, block_q, block_k,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, gp, lse, dsum)
 
     # kv-major grid: swap the roles of the index maps
@@ -336,6 +339,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, sm_scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, gp, lse, dsum)
     return dq[:, :T], dk[:, :S], dv[:, :S]
 
@@ -376,10 +380,26 @@ def flash_attention(q, k, v, causal: bool = False,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     # TPU lowering needs sublane-dim blocks in multiples of 8
-    block_q = max(8, _round_up(int(block_q), 8))
-    block_k = max(8, _round_up(int(block_k), 8))
-    return _flash(q, k, v, bool(causal), float(sm_scale), block_q,
-                  block_k, bool(interpret))
+    bq = max(8, _round_up(int(block_q), 8))
+    bk = max(8, _round_up(int(block_k), 8))
+    return _flash(q, k, v, bool(causal), float(sm_scale), bq, bk,
+                  bool(interpret))
+
+
+def flash_attention_heads(q, k, v, causal: bool = False,
+                          sm_scale: Optional[float] = None,
+                          block_q: int = 128, block_k: int = 128,
+                          interpret: Optional[bool] = None):
+    """Multi-head flash attention: q [B, T, H, Dh], k/v [B, S, H, Dh] ->
+    [B, T, H, Dh]. Heads fold into the kernel's batch axis ([B*H, T, Dh]).
+    A `vmap` over the head axis does not lower: it leaves the head as a
+    squeezed block dimension second from last, and Mosaic needs the last
+    two block dimensions to be the (sequence block, Dh) tile."""
+    B, T, H, D = q.shape
+    fold = lambda a: a.transpose(0, 2, 1, 3).reshape(B * H, a.shape[1], D)
+    out = flash_attention(fold(q), fold(k), fold(v), causal, sm_scale,
+                          block_q, block_k, interpret)
+    return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
 def flash_attention_spmd(q, k, v, causal: bool = False, *, mesh,
@@ -403,16 +423,14 @@ def flash_attention_spmd(q, k, v, causal: bool = False, *, mesh,
     bytes to prove nothing leaked. Requires B % d == 0 and H % m == 0
     (the trainer's batch sharding and `tp_validate` already enforce
     both)."""
-    from ..parallel.compat import shard_map   # lazy: no parallel-stack
-                                              # import at kernel load
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(data_axis, None, model_axis, None)
 
     def local_block(qb, kb, vb):
-        f = lambda q2, k2, v2: flash_attention(
-            q2, k2, v2, causal, sm_scale, block_q, block_k, interpret)
-        return jax.vmap(f, in_axes=2, out_axes=2)(qb, kb, vb)
+        return flash_attention_heads(qb, kb, vb, causal, sm_scale, block_q,
+                                     block_k, interpret)
 
     return shard_map(local_block, mesh=mesh,
                      in_specs=(spec, spec, spec), out_specs=spec,

@@ -104,6 +104,7 @@ def _fwd_call(x, gamma, beta, eps, interpret):
                    pl.BlockSpec((1, bc), lambda c: (0, c),
                                 memory_space=pltpu.VMEM)),
         interpret=interpret,
+        name="bn_relu_fwd",
     )(xp, gp, bp)
     return y[:, :C], mean[0, :C], var[0, :C]
 
@@ -139,6 +140,7 @@ def _bwd_call(x, gamma, beta, mean, var, dy, eps, interpret):
                    pl.BlockSpec((1, bc), lambda c: (0, c),
                                 memory_space=pltpu.VMEM)),
         interpret=interpret,
+        name="bn_relu_bwd",
     )(xp, gp, bp, mp, vp, dyp)
     return dx[:, :C], dg[0, :C], db[0, :C]
 

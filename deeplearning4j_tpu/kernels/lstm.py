@@ -128,6 +128,7 @@ def _fwd_impl(x, W, b, peep, h0, c0, offs, interpret,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((B, H), f32), pltpu.VMEM((B, H), f32)],
         interpret=interpret,
+        name="lstm_fwd",
     )(x, W, b, peep, h0, c0)
 
 
@@ -237,6 +238,7 @@ def _bwd_impl(x, W, peep, h0, c0, hs, cs, ii, ff, oo, gg,
                         pltpu.VMEM((1, 4 * H), f32),
                         pltpu.VMEM((1, 3 * H), f32)],
         interpret=interpret,
+        name="lstm_bwd",
     )(x, W, peep, hs, cs, cs, ii, ff, oo, gg, h0, c0, dhs, dhT, dcT)
 
 

@@ -11,10 +11,10 @@ like the reference's runtime cuDNN-helper probe
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
-import tempfile
 import threading
 from typing import Optional
 
@@ -32,12 +32,19 @@ _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 
+# <checkout>/.native_build (git-ignored): the library is built from what the
+# checkout holds and never outlives it
+_BUILD_DIR = os.path.join(os.path.dirname(os.path.dirname(_HERE)),
+                          ".native_build")
+
+
 def _lib_path() -> str:
-    cache = os.environ.get(
-        "DL4J_TPU_NATIVE_DIR",
-        os.path.join(os.path.expanduser("~"), ".deeplearning4j_tpu", "lib"))
-    os.makedirs(cache, exist_ok=True)
-    return os.path.join(cache, "libdl4j_native.so")
+    """Library path keyed by the source's content hash: an edited
+    `dl4j_native.cpp` gets a new name, so a stale build is never loaded."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    return os.path.join(_BUILD_DIR, f"libdl4j_native-{digest}.so")
 
 
 def _build(dest: str) -> bool:
@@ -63,7 +70,7 @@ def _build(dest: str) -> bool:
         os.replace(tmp, dest)
         return True
     except (OSError, subprocess.TimeoutExpired) as e:
-        log.info("native build unavailable: %s", e)
+        log.warning("native build unavailable: %s", e)
         return False
     finally:
         try:
@@ -126,9 +133,7 @@ def _load() -> Optional[ctypes.CDLL]:
             return None
         try:
             path = _lib_path()
-            src_mtime = os.path.getmtime(_SRC)
-            if not os.path.exists(path) \
-                    or os.path.getmtime(path) < src_mtime:
+            if not os.path.exists(path):
                 # the one-time cc build MUST complete under _LOCK:
                 # concurrent importers have nothing to do until the
                 # artifact exists, and exactly-once is the point
@@ -140,7 +145,7 @@ def _load() -> Optional[ctypes.CDLL]:
                 return None
             _LIB = lib
         except Exception as e:   # ANY probe failure degrades to pure Python
-            log.info("native tier unavailable: %s", e)
+            log.warning("native tier unavailable: %s", e)
             return None
         return _LIB
 

@@ -23,8 +23,8 @@ Megatron communication recipe, expressed through GSPMD constraints
 instead of hand-written collectives.
 
 Attention itself reuses `kernels/attention.py`: the Pallas flash kernel
-(full custom-VJP backward) vmapped over the head axis on TPU, the
-einsum `attention_reference` elsewhere. Pallas custom calls cannot be
+(full custom-VJP backward) with heads folded into its batch axis on TPU,
+the einsum `attention_reference` elsewhere. Pallas custom calls cannot be
 auto-partitioned by GSPMD, so inside a trainer-managed sharded step the
 kernel rides `flash_attention_spmd` — the same kernel under `shard_map`
 over (data, model); the Megatron head sharding makes each shard's local
@@ -59,9 +59,9 @@ class TransformerBlock(LayerConf):
 
     Input/output [B, T, n_model] (the package's RNN layout). Causal by
     default (GPT-style LM). `flash` selects the attention implementation:
-    True = `kernels.attention.flash_attention` (Pallas, vmapped over
-    heads), False = `kernels.attention.attention_reference` (einsum),
-    "auto" = flash on the TPU backend, reference elsewhere, and
+    True = `kernels.attention.flash_attention_heads` (Pallas, heads
+    folded into the batch axis), False =
+    `kernels.attention.attention_reference` (einsum), "auto" = flash on the TPU backend, reference elsewhere, and
     "spmd" = `kernels.attention.flash_attention_spmd` — the kernel under
     `shard_map` over the (data, model) mesh recorded in `flash_spmd`
     (an instance attr `(mesh, data_axis, model_axis)` the trainer's
@@ -160,10 +160,13 @@ class TransformerBlock(LayerConf):
         return bool(flash)
 
     def _attend(self, q, k, v, mask):
-        """q/k/v [B, T, H, Dh] -> [B, T, H, Dh]. The head axis stays an
-        explicit einsum axis (no batch-merge reshape) so a ``model``-axis
-        sharding on H partitions the whole attention locally."""
-        from ...kernels.attention import attention_reference, flash_attention
+        """q/k/v [B, T, H, Dh] -> [B, T, H, Dh]. On the einsum paths the
+        head axis stays an explicit axis (no batch-merge reshape) so a
+        ``model``-axis sharding on H partitions the whole attention
+        locally; the kernel paths run on unsharded or shard-local blocks
+        and fold heads into the batch."""
+        from ...kernels.attention import (attention_reference,
+                                          flash_attention_heads)
 
         if mask is not None:
             # padded timesteps (time_buckets): keys at masked positions
@@ -192,10 +195,11 @@ class TransformerBlock(LayerConf):
             return flash_attention_spmd(
                 q, k, v, self.causal, mesh=mesh,
                 data_axis=data_axis, model_axis=model_axis)
-        fn = flash_attention if self._use_flash() else attention_reference
-        # [B, T, H, Dh]: map the kernel ([B, T, D] contract) over heads
-        return jax.vmap(fn, in_axes=(2, 2, 2, None), out_axes=2)(
-            q, k, v, self.causal)
+        if self._use_flash():
+            return flash_attention_heads(q, k, v, self.causal)
+        # [B, T, H, Dh]: map the oracle ([B, T, D] contract) over heads
+        return jax.vmap(attention_reference, in_axes=(2, 2, 2, None),
+                        out_axes=2)(q, k, v, self.causal)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         x = self.maybe_dropout_input(x, train, rng)

@@ -83,10 +83,8 @@ def saved_bytes(fn: Callable, *args, policy: Optional[str] = None) -> int:
     what the policy ADDS. Uses `jax.ad_checkpoint.saved_residuals` on
     concrete zero-filled arguments — a trace-time measurement, no
     training step involved."""
-    try:
-        from jax.ad_checkpoint import saved_residuals
-    except ImportError:      # not re-exported publicly on jax 0.4.x
-        from jax._src.ad_checkpoint import saved_residuals
+    # private: the installed jax does not re-export saved_residuals
+    from jax._src.ad_checkpoint import saved_residuals
 
     def concrete(a):
         if hasattr(a, "shape") and hasattr(a, "dtype"):

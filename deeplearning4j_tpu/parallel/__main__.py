@@ -35,9 +35,11 @@ def main(argv=None):
     import jax
 
     from ..datasets.records import RecordReaderDataSetIterator
+    from ..util.platform import enable_compilation_cache
     from ..util.serializer import ModelSerializer
     from . import ParallelTrainer, TrainingMode, make_mesh
 
+    enable_compilation_cache()
     net = ModelSerializer.restore(args.model)
     it = RecordReaderDataSetIterator(
         args.data, batch_size=args.batch_size,

@@ -828,7 +828,7 @@ class PipelinedDenseStack:
 
     def pipelined_forward(self, params, x, n_microbatches: Optional[int] = None):
         """x: [B, F] -> [B, F] through the pipeline."""
-        from .compat import shard_map
+        from jax import shard_map
 
         M = n_microbatches or self.n_stages
         B = x.shape[0]

@@ -145,7 +145,7 @@ def ring_self_attention(q, k, v, axis_name: str, causal: bool = False):
 def ring_attention_sharded(q, k, v, mesh: Mesh, axis: str = "seq",
                            causal: bool = False):
     """Host-level entry: shard [B, T, H] on T over `axis` and run the ring."""
-    from .compat import shard_map
+    from jax import shard_map
 
     spec = P(None, axis, None)
     fn = shard_map(functools.partial(ring_self_attention, axis_name=axis,

@@ -42,6 +42,12 @@ import time
 
 
 def _provision(n_devices: int) -> None:
+    """Make this process see `n_devices` devices. With JAX_PLATFORMS=cpu it
+    provisions the virtual CPU mesh; otherwise it takes the real
+    accelerators. A chip belongs to one process, so only the CPU-forced
+    form may be started from a parent that has touched JAX (bench.py's
+    children use `child_env_with_virtual_devices` for that reason); the
+    accelerator form must be the only JAX process on the machine."""
     if os.environ.get("JAX_PLATFORMS") == "cpu":
         # caller asked for the virtual CPU mesh (bench.py does)
         from ..util.platform import provision_virtual_devices
@@ -647,7 +653,7 @@ def measure_pipeline(s_stages: int = 4, microbatches=(1, 2, 4, 8),
     # a fixed cost that would masquerade as bubble at small M
     import functools as _ft
 
-    from .compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
     from jax.sharding import NamedSharding as _NS, PartitionSpec as _P
 
     from .pipeline import pipeline_forward as _pf

@@ -27,7 +27,8 @@ Tensor-parallel / FSDP param shardings compose with SYNC mode via
 params replicated but shard optimizer state (and stage-2 reduced
 gradients) over the data axis — reduce-scatter -> sharded update ->
 allgather instead of allreduce -> replicated update (see `zero.py`),
-killing the replicated-updater tax BENCH_r05 measured at ~2.3 s/step.
+killing the replicated-updater tax the r05 capture (BASELINE.md) measured
+at ~2.3 s/step.
 """
 from __future__ import annotations
 
@@ -597,7 +598,7 @@ class ParallelTrainer:
             self._opt = jax.device_put(
                 jax.tree_util.tree_map(stack, m.updater_state), stack_sh)
 
-            from .compat import shard_map
+            from jax import shard_map
             axis = self.data_axis
 
             def local_step(params, state, opt, step, x, y, fm, lm, rng):

@@ -2,8 +2,8 @@
 
 The plain SYNC data-parallel step pays a "replicated updater" tax: every
 device holds the FULL optimizer state and redundantly applies the FULL
-parameter update after the gradient allreduce (BENCH_r05 attributes
-~2.3 s/step of the 8-device Adam wall time to exactly this,
+parameter update after the gradient allreduce (the r05 capture,
+BASELINE.md, attributes ~2.3 s/step of the 8-device Adam wall time to this,
 `DP-replicated-updater-cost-ms`). ZeRO (Rajbhandari et al., 2020) removes
 it by partitioning optimizer state — and, at stage 2, the reduced
 gradients — across the data-parallel axis:

@@ -650,6 +650,8 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="run the CI smoke (ephemeral port) and exit")
     args = ap.parse_args(argv)
+    from ..util.platform import enable_compilation_cache
+    enable_compilation_cache()
     if args.smoke:
         raise SystemExit(_smoke())
     srv = InferenceServer(host=args.host, port=args.port,

@@ -1144,7 +1144,7 @@ def test_ir_redundant_reshard_and_invalid_axis_caught():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from deeplearning4j_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     ir, probes = _ir(), _probes()
     mesh = probes.virtual_mesh()

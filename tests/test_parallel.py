@@ -194,7 +194,7 @@ def test_ring_attention_differentiable():
     k = jnp.asarray(r.normal(size=(B, T, H)))
     v = jnp.asarray(r.normal(size=(B, T, H)))
 
-    from deeplearning4j_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     import functools
     from deeplearning4j_tpu.parallel import ring_self_attention
