@@ -17,8 +17,10 @@ the entry points a user calls, at the full width of models the repo ships:
   mesh      (>= 4 devices) ResNet-50 data-parallel and the LM under
             zero1_tp (2, 2) with flash under shard_map
 
-Phases run in order and stop at the first that fails. The last stdout line
-is one JSON object: {"ok": true, "device": {...}, "phases": [...]}.
+Phases run in order and stop at the first that fails. The last two stdout
+lines are JSON objects: first the per-phase summary {"phases": [...],
+"cache_dir": ...}, then — last, and with exactly these keys —
+{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}.
 Exit codes: 0 all phases passed; 1 a phase failed; 2 no TPU.
 
 Every phase function takes `sizes` and `interpret`; tests call them on the
@@ -192,6 +194,13 @@ def device_info() -> Dict:
     d = jax.devices()[0]
     return {"platform": d.platform, "kind": d.device_kind,
             "count": jax.device_count()}
+
+
+def result_line(ok: bool) -> str:
+    """The last stdout line: one JSON object with exactly the keys "ok" and
+    "device", the device exactly {"platform", "kind", "count"} as JAX reports
+    it. The driver parses this line and refuses anything more or less."""
+    return json.dumps({"ok": bool(ok), "device": device_info()})
 
 
 def phase_device() -> Dict:
@@ -788,8 +797,8 @@ def main() -> int:
              f"jax.device_count() is {jax.device_count()}")
         phases.append({"phase": "mesh", "result": "not run: "
                        f"{jax.device_count()} device(s), needs 4"})
-    print(json.dumps({"ok": bool(ok), "device": device_info(),
-                      "phases": phases, "cache_dir": cache_dir}))
+    print(json.dumps({"phases": phases, "cache_dir": cache_dir}))
+    print(result_line(ok), flush=True)
     return 0 if ok else 1
 
 

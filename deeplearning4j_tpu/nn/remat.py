@@ -80,7 +80,7 @@ def saved_bytes(fn: Callable, *args, policy: Optional[str] = None) -> int:
     under the named policy (0 = recompute everything from the boundary
     inputs). Residuals that are just the boundary's own arguments are
     excluded — they are alive either way; the accounting counts only
-    what the policy ADDS. Uses `jax.ad_checkpoint.saved_residuals` on
+    what the policy ADDS. Uses jax's (private) `saved_residuals` on
     concrete zero-filled arguments — a trace-time measurement, no
     training step involved."""
     # private: the installed jax does not re-export saved_residuals

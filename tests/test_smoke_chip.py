@@ -39,6 +39,17 @@ def test_script_exits_nonzero_without_a_chip():
     assert '"ok"' not in p.stdout
 
 
+def test_result_line_has_exactly_the_contract_keys():
+    import json
+    for ok in (True, False):
+        rec = json.loads(chip_smoke.result_line(ok))
+        assert set(rec) == {"ok", "device"} and rec["ok"] is ok
+        dev = rec["device"]
+        assert set(dev) == {"platform", "kind", "count"}
+        assert isinstance(dev["platform"], str) and isinstance(dev["kind"], str)
+        assert type(dev["count"]) is int
+
+
 def test_mosaic_kernels_reads_names_from_lowered_text():
     text = ('stablehlo.custom_call @tpu_custom_call(%0) {kernel_name = '
             '"flash_fwd"} ... @tpu_custom_call(%1) {kernel_name = "lstm_bwd"}')
