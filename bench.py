@@ -451,17 +451,6 @@ def main():
         extras["Serving-decode-tokens-per-s"] = \
             f"error: {type(e).__name__}"
     try:
-        # observability overhead (ISSUE 17): per-request tracing + SLO
-        # surface on the serving plane and the flight recorder on the
-        # LeNet fit path, enabled-vs-disabled in alternating paired
-        # windows; median paired ratio per arm with the >=0.95 gate
-        from deeplearning4j_tpu.telemetry.obs_bench import \
-            run_obs_overhead_bench
-        extras["Obs-overhead"] = run_obs_overhead_bench(
-            pairs=3, clients=8, requests_per_client=60)
-    except Exception as e:
-        extras["Obs-overhead"] = f"error: {type(e).__name__}"
-    try:
         # pipeline parallelism (ISSUE 15): the transformer LM trained
         # mesh-native 1F1B vs host-GPipe vs ZERO1×TP in alternating
         # paired windows — tokens/s per arm, the paired

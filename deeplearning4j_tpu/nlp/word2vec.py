@@ -35,7 +35,7 @@ from .sentence_iterator import (BasicLabelAwareIterator, LabelAwareIterator,
 from .tokenization import DefaultTokenizerFactory, TokenizerFactory
 from .vocab import VocabCache, VocabConstructor, VocabWord
 from ..telemetry.compile_watch import watch_compiles
-from ..telemetry.runtime import active as _tel_active, null_span as _null_span
+from ..telemetry.runtime import span as _span
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -275,11 +275,9 @@ class SequenceVectors(WordVectorsModel):
         if syn1neg is None:
             syn1neg = jnp.zeros((1, 1), jnp.float32)
 
-        tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
         runners = {}
         for epoch in range(self.epochs):
-            with span("host/pair_gen"):
+            with _span("host/pair_gen"):
                 pairs = self._gen_pairs(seqs)
             tasks = []
             if "sg" in pairs:
@@ -327,7 +325,7 @@ class SequenceVectors(WordVectorsModel):
                 if runner is None:
                     runner = runners[kind] = watch_compiles(
                         make_epoch_runner(step), f"word2vec/{kind}_epoch")
-                with span("device/dispatch", kind=f"w2v_{kind}_epoch"):
+                with _span("device/dispatch", kind=f"w2v_{kind}_epoch"):
                     syn0, syn1, syn1neg, _loss = runner(
                         syn0, syn1, syn1neg,
                         self._pair_place(
@@ -346,8 +344,6 @@ class SequenceVectors(WordVectorsModel):
     def _fit_sg_corpus(self, seqs):
         """SGNS fast path: corpus on device, windows + negatives generated
         inside the scanned step (see make_skipgram_corpus_runner)."""
-        tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
         table = self.lookup_table
         runner_key = (id(table), self.window_size)
         if getattr(self, "_sg_runner_key", None) != runner_key:
@@ -383,7 +379,7 @@ class SequenceVectors(WordVectorsModel):
                                                                  key):
             base_flat, base_sid = cache[1], cache[2]
         else:
-            with span("host/flatten_corpus"):
+            with _span("host/flatten_corpus"):
                 base_flat, base_sid = self._flatten_corpus(seqs,
                                                            subsample=False)
             self._sg_flat_cache = (key, base_flat, base_sid)
@@ -419,7 +415,7 @@ class SequenceVectors(WordVectorsModel):
                              self.learning_rate * (1.0 - frac))
             lrs[T:] = 0.0
             rng, k = jax.random.split(rng)
-            with span("device/dispatch", kind="w2v_sgns_epoch"):
+            with _span("device/dispatch", kind="w2v_sgns_epoch"):
                 syn0, syn1neg, _loss = runner(
                     syn0, syn1neg, corpus_dev[0], corpus_dev[1],
                     pos_dev, jnp.asarray(lrs, jnp.float32), k)

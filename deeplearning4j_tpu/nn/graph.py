@@ -27,7 +27,9 @@ from .layers.feedforward import BaseOutputLayerConf
 from ..datasets.iterators import DataSet, DataSetIterator, MultiDataSet
 from ..eval.evaluation import Evaluation
 from ..telemetry.compile_watch import watch_compiles
-from ..telemetry.runtime import active as _tel_active, null_span as _null_span
+from ..telemetry.runtime import (active as _tel_active,
+                                 null_span as _null_span, span as _span)
+from ..telemetry.tracing import named_step
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -375,7 +377,7 @@ class ComputationGraph:
                 params, grads, opt_state, step)
             return new_params, new_state, new_opt, score
 
-        return train_step
+        return named_step("train_step", train_step)
 
     def apply_vertex_updates(self, params, grads, opt_state, step):
         """Apply per-vertex updaters to the gradient tree — the update
@@ -701,15 +703,19 @@ class ComputationGraph:
             self.conf.conf.max_num_line_search_iterations)
 
     def _fit_batch(self, ds):
+        with _span("dl4j/fit/step") as fit_step:
+            self._fit_step(ds)
+            fit_step.set(iteration=self.iteration_count)
+
+    def _fit_step(self, ds):
         from .conf import OptimizationAlgorithm as OA
 
         tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
-        with span("host/batch_prep"):
+        with _span("host/batch_prep"):
             inputs, labels, fmasks, lmasks = self._to_inputs(ds)
         self._rng, step_rng = jax.random.split(self._rng)
         if self.conf.conf.optimization_algo != OA.STOCHASTIC_GRADIENT_DESCENT:
-            with span("device/dispatch", kind="line_search"):
+            with _span("device/dispatch", kind="line_search"):
                 self.params, self.state, score = self._line_solver.fit_batch(
                     self.params, self.state, inputs, labels, step_rng,
                     fmasks, lmasks)
@@ -717,23 +723,28 @@ class ComputationGraph:
             self.last_batch_size = int(
                 next(iter(inputs.values())).shape[0])
             self.iteration_count += 1
-            for listener in self.listeners:
-                listener.iteration_done(self, self.iteration_count)
+            self._iteration_done()
             return
         step = jnp.asarray(self.iteration_count, jnp.int32)
-        with span("device/dispatch", kind="train_step"):
+        with _span("device/dispatch", kind="train_step"):
             (self.params, self.state, self.updater_state,
              score) = self._train_step(self.params, self.state,
                                        self.updater_state, step, inputs,
                                        labels, step_rng, fmasks, lmasks)
         if tel is not None and tel.sync_per_step:
-            with span("device/sync"):
+            with _span("device/sync"):
                 jax.block_until_ready(score)
         self._score = score
         self.last_batch_size = int(next(iter(inputs.values())).shape[0])
         self.iteration_count += 1
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration_count)
+        self._iteration_done()
+
+    def _iteration_done(self):
+        # a listener that reads the score blocks here until the device has
+        # finished the step
+        with _span("dl4j/fit/listeners"):
+            for listener in self.listeners:
+                listener.iteration_done(self, self.iteration_count)
 
     def fit_scan_arrays(self, xs, ys, epochs: int = 1):
         """Device-resident multi-step training: the whole [T]-step pass runs
@@ -755,13 +766,11 @@ class ComputationGraph:
             raise ValueError(
                 "fit_scan_arrays supports SGD-updater training only; "
                 "line-search optimizers are per-batch sequential — use fit()")
-        tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
         if not isinstance(xs, dict):
             xs = {self.conf.network_inputs[0]: xs}
         if not isinstance(ys, dict):
             ys = {self.conf.network_outputs[0]: ys}
-        with span("host/batch_prep"):
+        with _span("host/batch_prep"):
             xs = {k: jnp.asarray(v) for k, v in xs.items()}
             ys = {k: jnp.asarray(v) for k, v in ys.items()}
         key = (tuple(sorted((k, tuple(v.shape), str(v.dtype))
@@ -797,14 +806,14 @@ class ComputationGraph:
             warn_scan_replay(self.listeners)
         for _ in range(epochs):
             self._rng, k = jax.random.split(self._rng)
-            with span("device/dispatch", kind="scan_epoch"):
+            with _span("device/dispatch", kind="scan_epoch"):
                 (self.params, self.state, self.updater_state,
                  scores) = epoch_fn(
                     self.params, self.state, self.updater_state,
                     jnp.asarray(self.iteration_count, jnp.int32), xs, ys, k)
             self.last_batch_size = int(next(iter(xs.values())).shape[1])
             if self.listeners:
-                with span("device/sync", kind="scan_scores"):
+                with _span("device/sync", kind="scan_scores"):
                     host_scores = np.asarray(scores)
                 for i in range(n_steps):
                     self._score = host_scores[i]

@@ -38,7 +38,9 @@ from .layers.feedforward import BaseOutputLayerConf
 from ..datasets.iterators import ArrayDataSetIterator, DataSet, DataSetIterator
 from ..eval.evaluation import Evaluation
 from ..telemetry.compile_watch import watch_compiles
-from ..telemetry.runtime import active as _tel_active, null_span as _null_span
+from ..telemetry.runtime import (active as _tel_active,
+                                 null_span as _null_span, span as _span)
+from ..telemetry.tracing import named_step
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -361,7 +363,7 @@ class MultiLayerNetwork:
             return (tuple(new_params), new_state, tuple(new_opt), score,
                     new_carries)
 
-        return train_step
+        return named_step("train_step", train_step)
 
     @functools.cached_property
     def train_step_fn(self):
@@ -801,12 +803,10 @@ class MultiLayerNetwork:
                 "fit_scan_arrays supports SGD-updater training only; "
                 "line-search optimizers (CG/LBFGS) are per-batch sequential "
                 "— use fit()")
-        tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
         tbptt = (self.conf.backprop_type == BackpropType.TRUNCATED_BPTT
                  and xs.ndim >= 4)
         firsts = None
-        with span("host/batch_prep"):
+        with _span("host/batch_prep"):
             xs_d, ys_d = jnp.asarray(xs), jnp.asarray(ys)
         fm_d = jnp.asarray(fmask) if fmask is not None else None
         lm_d = jnp.asarray(lmask) if lmask is not None else None
@@ -867,7 +867,7 @@ class MultiLayerNetwork:
                         if guard is not None and guard._needs_snapshot
                         else None)
                 self._rng, k = jax.random.split(self._rng)
-                with span("device/dispatch", kind="scan_epoch"):
+                with _span("device/dispatch", kind="scan_epoch"):
                     (self.params, self.state, self.updater_state,
                      scores) = epoch_fn(
                         self.params, self.state, self.updater_state,
@@ -876,7 +876,7 @@ class MultiLayerNetwork:
                         carries0 if tbptt else (), k)
                 guard_scores = None
                 if guard is not None:
-                    with span("device/sync", kind="guard_scores"):
+                    with _span("device/sync", kind="guard_scores"):
                         guard_scores = np.asarray(scores)
                     if not guard.check_scores(self, guard_scores, snap):
                         # epoch discarded, pre-epoch state back — still
@@ -892,7 +892,7 @@ class MultiLayerNetwork:
                     if guard_scores is not None:
                         host_scores = guard_scores   # already synced
                     else:
-                        with span("device/sync", kind="scan_scores"):
+                        with _span("device/sync", kind="scan_scores"):
                             host_scores = np.asarray(scores)
                     for i in range(n_steps):
                         self._score = host_scores[i]
@@ -1020,11 +1020,15 @@ class MultiLayerNetwork:
                     f"{kind} InputType flat size {it.flat_size()}")
 
     def _fit_batch(self, ds: DataSet):
+        with _span("dl4j/fit/step") as fit_step:
+            self._fit_step(ds)
+            fit_step.set(iteration=self.iteration_count)
+
+    def _fit_step(self, ds: DataSet):
         from .conf import OptimizationAlgorithm as OA
 
         tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
-        with span("host/batch_prep"):
+        with _span("host/batch_prep"):
             x, y, fmask, lmask = ds.device_tuple()
             self._check_input_width(x)
         self.last_input = x   # reference setInput keeps the batch around;
@@ -1039,24 +1043,27 @@ class MultiLayerNetwork:
         if self.conf.conf.optimization_algo != OA.STOCHASTIC_GRADIENT_DESCENT:
             # line-search path (Solver.java -> CG/LBFGS/line GD); the
             # updater chain is SGD-only, as in the reference's BaseOptimizer
-            with span("device/dispatch", kind="line_search"):
+            with _span("device/dispatch", kind="line_search"):
                 self.params, self.state, score = self._line_solver.fit_batch(
                     self.params, self.state, x, y, step_rng, fmask, lmask)
         else:
             step = jnp.asarray(self.iteration_count, dtype=jnp.int32)
-            with span("device/dispatch", kind="train_step"):
+            with _span("device/dispatch", kind="train_step"):
                 (self.params, self.state, self.updater_state,
                  score) = self._train_step(
                     self.params, self.state, self.updater_state, step, x, y,
                     step_rng, fmask, lmask)
         if tel is not None and tel.sync_per_step:
-            with span("device/sync"):
+            with _span("device/sync"):
                 jax.block_until_ready(score)
         self._score = score
         self.last_batch_size = int(x.shape[0])
         self.iteration_count += 1
-        for listener in self.listeners:
-            listener.iteration_done(self, self.iteration_count)
+        # a listener that reads the score blocks here until the device has
+        # finished the step
+        with _span("dl4j/fit/listeners"):
+            for listener in self.listeners:
+                listener.iteration_done(self, self.iteration_count)
 
     def _zero_carries(self, batch: int, dtype=jnp.float32):
         return tuple(
@@ -1069,7 +1076,6 @@ class MultiLayerNetwork:
         `MultiLayerNetwork.java:1119`): split the series into fwd-length
         chunks; hidden state flows forward between chunks, gradients do not."""
         tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
         T = x.shape[1]
         L = self.conf.tbptt_fwd_length
         carries = self._zero_carries(int(x.shape[0]), x.dtype)
@@ -1084,7 +1090,7 @@ class MultiLayerNetwork:
                 chunk(x), chunk(y), chunk(fmask), chunk(lmask))
             self._rng, step_rng = jax.random.split(self._rng)
             step = jnp.asarray(self.iteration_count, dtype=jnp.int32)
-            with span("device/dispatch", kind="tbptt_chunk"):
+            with _span("device/dispatch", kind="tbptt_chunk"):
                 (self.params, self.state, self.updater_state, score,
                  carries) = self._tbptt_step(
                     self.params, self.state, self.updater_state, step,
@@ -1092,7 +1098,7 @@ class MultiLayerNetwork:
                     None if fmask is None else fmask[:, sl],
                     None if lmask is None else lmask[:, sl], carries)
             if tel is not None and tel.sync_per_step:
-                with span("device/sync"):
+                with _span("device/sync"):
                     jax.block_until_ready(score)
             self._score = score
             self.last_batch_size = int(x.shape[0])

@@ -63,7 +63,7 @@ from typing import Optional
 import numpy as np
 
 from ..telemetry.recorder import flight_recorder
-from ..telemetry.runtime import active as _tel_active, null_span as _null_span
+from ..telemetry.runtime import span as _span
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -566,12 +566,10 @@ class SuperstepRunner:
 
     # ------------------------------------------------------------------
     def run_epoch(self, data):
-        tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
         if self._pipelined:
-            self._run_pipelined(data, span)
+            self._run_pipelined(data)
         else:
-            self._run_sequential(data, span)
+            self._run_sequential(data)
         if self._untrained and self.ckpt is not None:
             # untrainable tail batches with no following window: flush the
             # cursor at the epoch edge (model state is final here, so the
@@ -579,27 +577,27 @@ class SuperstepRunner:
             self.ckpt.on_batches(self._untrained)
             self._untrained = 0
 
-    def _run_sequential(self, data, span):
+    def _run_sequential(self, data):
         """Guard/checkpoint mode: each window is finalized (losses synced,
         guard verdict applied, checkpoint cursor advanced) before the next
         window is dispatched — a rollback can never race a dispatch."""
         while True:
             t_win = time.perf_counter()
-            with span("host/batch_prep", kind="superstep_window"):
+            with _span("host/batch_prep", kind="superstep_window"):
                 window = self._collect(data)
                 staged = self._stage(window)
             if not window:
                 return
             snap = self._pre_window_snapshot()
             t_d = time.perf_counter()
-            with span("device/dispatch", kind="superstep"):
+            with _span("device/dispatch", kind="superstep"):
                 scores = self.adapter.dispatch(staged, len(window),
                                                self.model.iteration_count)
             t_d = time.perf_counter() - t_d
-            self._finalize(window, scores, snap, span)
+            self._finalize(window, scores, snap)
             self._observe_auto(window, t_d, time.perf_counter() - t_win)
 
-    def _run_pipelined(self, data, span):
+    def _run_pipelined(self, data):
         """No guard, no checkpointer: window i+1 is collected, stacked and
         transferred while window i computes on device. With no listeners
         attached, window i's finalize (loss sync) is additionally DEFERRED
@@ -614,31 +612,31 @@ class SuperstepRunner:
         lag = not (getattr(self.model, "listeners", None) or [])
         step0 = self.model.iteration_count
         inflight = None   # (window, scores_dev) — one window of lag
-        with span("host/batch_prep", kind="superstep_window"):
+        with _span("host/batch_prep", kind="superstep_window"):
             window = self._collect(data)
             staged = self._stage(window)
         t_prev = time.perf_counter()
         while window:
             t_d = time.perf_counter()
-            with span("device/dispatch", kind="superstep"):
+            with _span("device/dispatch", kind="superstep"):
                 scores = self.adapter.dispatch(staged, len(window), step0)
             t_d = time.perf_counter() - t_d
             step0 += self._steps_in(len(window))
             cur = (window, scores)
-            with span("host/batch_prep", kind="superstep_window"):
+            with _span("host/batch_prep", kind="superstep_window"):
                 window = self._collect(data)
                 staged = self._stage(window)
             if lag:
                 if inflight is not None:
-                    self._finalize(inflight[0], inflight[1], None, span)
+                    self._finalize(inflight[0], inflight[1], None)
                 inflight = cur
             else:
-                self._finalize(cur[0], cur[1], None, span)
+                self._finalize(cur[0], cur[1], None)
             now = time.perf_counter()
             self._observe_auto(cur[0], t_d, now - t_prev)
             t_prev = now
         if inflight is not None:
-            self._finalize(inflight[0], inflight[1], None, span)
+            self._finalize(inflight[0], inflight[1], None)
 
     # ------------------------------------------------------------------
     def _pre_window_snapshot(self):
@@ -649,10 +647,10 @@ class SuperstepRunner:
         # model trees, so post-dispatch the originals are invalidated
         return g._snapshot(self.model)
 
-    def _finalize(self, window, scores_dev, snap, span):
+    def _finalize(self, window, scores_dev, snap):
         model = self.model
         n_micro = len(window)
-        with span("device/sync", kind="superstep_scores"):
+        with _span("device/sync", kind="superstep_scores"):
             if self._m == 1:
                 host_scores = np.asarray(scores_dev)
                 micro_scores = None

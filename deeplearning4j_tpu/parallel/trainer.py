@@ -45,7 +45,8 @@ from .mesh import MeshAxes, make_mesh
 from .sharding import ShardingStrategy, param_specs
 from ..datasets.iterators import DataSet, DataSetIterator, MultiDataSet
 from ..telemetry.compile_watch import watch_compiles
-from ..telemetry.runtime import active as _tel_active, null_span as _null_span
+from ..telemetry.runtime import (active as _tel_active,
+                                 null_span as _null_span, span as _span)
 
 __all__ = ["ParallelTrainer", "ParallelWrapper", "TrainingMode",
            "configure_flash_attention"]
@@ -1023,10 +1024,9 @@ class ParallelTrainer:
 
         tmap = jax.tree_util.tree_map
         tel = _tel_active()
-        span = tel.span if tel is not None else _null_span
         phase = (self.stats.time if self.stats is not None
                  else (lambda key: contextlib.nullcontext()))
-        with phase("data"), span("host/batch_prep"):
+        with phase("data"), _span("host/batch_prep"):
             local_shard = bool(getattr(ds, "is_local_shard", False))
             xd, yd, fm, lm = self._to_batch(ds)
             n = self.n_data
@@ -1062,7 +1062,7 @@ class ParallelTrainer:
         step = jnp.asarray(self.iteration_count, jnp.int32)
         if self.mode == TrainingMode.SYNC:
             with phase("step"):
-                with span("device/dispatch", kind="sync_step"):
+                with _span("device/dispatch", kind="sync_step"):
                     (self._params, self._state, self._opt,
                      score) = self._step_fn(
                         self._params, self._state, self._opt, step,
@@ -1076,14 +1076,14 @@ class ParallelTrainer:
                     self._feed_collective_hasher()
                 if self.stats is not None or (tel is not None
                                               and tel.sync_per_step):
-                    with span("device/sync"):
+                    with _span("device/sync"):
                         float(jnp.asarray(score))  # sync for honest timing
         else:
             with phase("step"):
                 resh = lambda t: tmap(
                     lambda a: a.reshape(n, -1, *a.shape[1:]), t)
                 xs, ys, fms, lms = resh(xd), resh(yd), resh(fm), resh(lm)
-                with span("device/dispatch", kind="local_step"):
+                with _span("device/dispatch", kind="local_step"):
                     (self._params, self._state, self._opt,
                      scores) = self._local_step(
                         self._params, self._state, self._opt, step, xs, ys,
@@ -1091,10 +1091,10 @@ class ParallelTrainer:
                 self._score = scores.mean()
                 if self.stats is not None or (tel is not None
                                               and tel.sync_per_step):
-                    with span("device/sync"):
+                    with _span("device/sync"):
                         float(jnp.asarray(self._score))
             if (self.iteration_count + 1) % self.averaging_frequency == 0:
-                with phase("average"), span("device/average"):
+                with phase("average"), _span("device/average"):
                     self._params, self._opt = self._average(self._params,
                                                             self._opt)
                     if self.stats is not None:

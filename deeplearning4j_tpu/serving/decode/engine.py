@@ -31,7 +31,6 @@ stay — the continuous-batching isolation contract the tests assert.
 """
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -39,6 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...telemetry.compile_watch import watch_compiles
+from ...telemetry.runtime import span as _span
+from ...telemetry.tracing import named_step
 from ..registry import ServingError, _abstract_sig
 from .cache import BlockPool, KvCacheSpec, make_cache, pack_kv, unpack_kv
 
@@ -134,7 +135,7 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec):
             logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
         return _repack(cache, kv, sc), last.astype(jnp.float32)
 
-    return prefill
+    return named_step("prefill", prefill)
 
 
 def build_decode_fn(model, snapshot, spec: KvCacheSpec):
@@ -161,7 +162,7 @@ def build_decode_fn(model, snapshot, spec: KvCacheSpec):
         logits = head.preout(params[-1], {}, x)[:, 0]
         return _repack(cache, kv, sc), logits.astype(jnp.float32)
 
-    return decode
+    return named_step("tick", decode)
 
 
 def _pow2_buckets(lo: int, hi: int) -> Tuple[int, ...]:
@@ -302,61 +303,61 @@ class DecodeEngine:
         return list(table) + [0] * (w - len(table))
 
     def run_prefill(self, v, pool: BlockPool, prompt: Sequence[int],
-                    table: Sequence[int], ctx=None) -> np.ndarray:
+                    table: Sequence[int], observe=None) -> np.ndarray:
         """Write `prompt`'s K/V through `table`, return the next-token
-        logits [V]. Batch 1: one compile per prompt bucket. `ctx` is an
-        optional TraceContext — bucket_select + prefill child spans."""
-        self._check_version(v)
-        n = len(prompt)
-        t_sel = time.perf_counter()
-        tb = self.prompt_bucket_for(n)
-        if ctx is not None:
-            ctx.emit("bucket_select", t_sel, time.perf_counter(),
-                     model=self.name, phase="prefill", bucket=tb, tokens=n)
-        tokens = np.zeros((1, tb), np.int32)
-        tokens[0, :n] = np.asarray(prompt, np.int32)
-        exec_ = self.prefill_exec(v, tb)
-        t0 = time.perf_counter()
-        pool.cache, logits = exec_(
-            v.snapshot.data, pool.cache, jnp.asarray(tokens),
-            jnp.asarray([n], jnp.int32),
-            jnp.asarray([self._pad_table(table)], jnp.int32))
-        out = np.asarray(logits)[0]      # host sync: span covers real work
-        if ctx is not None:
-            ctx.emit("prefill", t0, time.perf_counter(),
-                     model=self.name, bucket=tb, tokens=n)
+        logits [V]. Batch 1: one compile per prompt bucket. Three spans,
+        children of whatever span the caller has open: prepare (host),
+        dispatch (uploads + enqueue), fetch (the wait for the device and
+        the copy down); `observe(span)` sees each once it has closed."""
+        with _span("dl4j/engine/prefill.prepare") as prepare:
+            self._check_version(v)
+            n = len(prompt)
+            tb = self.prompt_bucket_for(n)
+            prepare.set(bucket=tb, tokens=n)
+            tokens = np.zeros((1, tb), np.int32)
+            tokens[0, :n] = np.asarray(prompt, np.int32)
+            tab = np.asarray([self._pad_table(table)], np.int32)
+            exec_ = self.prefill_exec(v, tb)
+        with _span("dl4j/engine/prefill.dispatch") as dispatch:
+            pool.cache, logits = exec_(
+                v.snapshot.data, pool.cache, jnp.asarray(tokens),
+                jnp.asarray([n], jnp.int32), jnp.asarray(tab))
+        with _span("dl4j/engine/prefill.fetch") as fetch:
+            out = np.asarray(logits)[0]
+            fetch.set(bytes=out.nbytes)
+        if observe is not None:
+            for sp in (prepare, dispatch, fetch):
+                observe(sp)
         return out
 
     def run_tick(self, v, pool: BlockPool, tokens: Sequence[int],
                  positions: Sequence[int], tables: Sequence[Sequence[int]],
-                 bucket: int, ctxs=None) -> np.ndarray:
+                 bucket: int, observe=None) -> np.ndarray:
         """One decode tick over `len(tokens)` live rows padded up to
         `bucket` (pad rows park at the trash block, length 1, and their
         logits are discarded by the caller). Returns logits [rows, V].
-        `ctxs` is an optional per-row TraceContext list — every traced
-        row gets a decode_tick child span for this shared step."""
-        self._check_version(v)
-        rows = len(tokens)
-        if rows > bucket:
-            raise ServingError(f"{rows} rows > decode bucket {bucket}")
-        tok = np.zeros(bucket, np.int32)
-        pos = np.zeros(bucket, np.int32)
-        tab = np.zeros((bucket, self.spec.table_width), np.int32)
-        tok[:rows] = np.asarray(tokens, np.int32)
-        pos[:rows] = np.asarray(positions, np.int32)
-        for i, t in enumerate(tables):
-            tab[i] = self._pad_table(t)
-        exec_ = self.decode_exec(v, bucket)
-        t0 = time.perf_counter()
-        pool.cache, logits = exec_(
-            v.snapshot.data, pool.cache, jnp.asarray(tok),
-            jnp.asarray(pos), jnp.asarray(tab))
-        out = np.asarray(logits)[:rows]  # host sync: span covers real work
-        if ctxs:
-            t1 = time.perf_counter()
-            for i, c in enumerate(ctxs[:rows]):
-                if c is not None:
-                    c.emit("decode_tick", t0, t1, model=self.name,
-                           bucket=bucket, rows=rows,
-                           position=int(positions[i]))
-        return out
+        Spans and `observe` as in `run_prefill`."""
+        with _span("dl4j/engine/tick.prepare", bucket=bucket) as prepare:
+            self._check_version(v)
+            rows = len(tokens)
+            if rows > bucket:
+                raise ServingError(f"{rows} rows > decode bucket {bucket}")
+            tok = np.zeros(bucket, np.int32)
+            pos = np.zeros(bucket, np.int32)
+            tab = np.zeros((bucket, self.spec.table_width), np.int32)
+            tok[:rows] = np.asarray(tokens, np.int32)
+            pos[:rows] = np.asarray(positions, np.int32)
+            for i, t in enumerate(tables):
+                tab[i] = self._pad_table(t)
+            exec_ = self.decode_exec(v, bucket)
+        with _span("dl4j/engine/tick.dispatch") as dispatch:
+            pool.cache, logits = exec_(
+                v.snapshot.data, pool.cache, jnp.asarray(tok),
+                jnp.asarray(pos), jnp.asarray(tab))
+        with _span("dl4j/engine/tick.fetch") as fetch:
+            full = np.asarray(logits)
+            fetch.set(bytes=full.nbytes)
+        if observe is not None:
+            for sp in (prepare, dispatch, fetch):
+                observe(sp)
+        return full[:rows]

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ...telemetry.recorder import flight_recorder
+from ...telemetry.runtime import span as _span
 from ..batcher import FlushEma
 from ..registry import ServingError
 from .cache import OutOfBlocksError
@@ -51,7 +52,8 @@ class GenerationError(ServingError):
 class _Seq:
     __slots__ = ("sid", "ctx", "prompt_len", "max_tokens", "temperature",
                  "stop_ids", "rng", "blocks", "cached", "event", "result",
-                 "error", "trace", "enqueued_at")
+                 "error", "trace", "submitted_at", "enqueued_at",
+                 "first_token_at")
 
     def __init__(self, sid, prompt, max_tokens, temperature, stop_ids, seed,
                  trace=None):
@@ -68,7 +70,9 @@ class _Seq:
         self.result: Optional[Dict] = None
         self.error: Optional[Exception] = None
         self.trace = trace              # TraceContext, or None
-        self.enqueued_at = time.perf_counter()
+        self.submitted_at = time.perf_counter()
+        self.enqueued_at = self.submitted_at    # restarts on a re-queue
+        self.first_token_at: Optional[float] = None
 
     @property
     def generated(self) -> List[int]:
@@ -115,8 +119,10 @@ class GenerationScheduler:
         self._ids = itertools.count(1)
         self._rotate = 0
         self._version = None
+        self._evictions = self._prefills = self._ticks = 0
         self._tokens_c = self._admit_c = self._evict_c = None
-        self._phase_h = None
+        self._phase_h = self._host_h = self._queue_h = None
+        self._first_h = self._rows_h = None
         if metrics is not None:
             self._tokens_c = metrics.counter(
                 "dl4j_decode_tokens_total", "generated tokens",
@@ -132,6 +138,24 @@ class GenerationScheduler:
                 "dl4j_decode_phase_seconds",
                 "wall seconds per compiled generation step",
                 labels=("model", "phase"))
+            self._host_h = metrics.histogram(
+                "dl4j_decode_host_seconds",
+                "wall seconds per scheduler-thread span; phase is the "
+                "span name's last element (loop, idle, admit, tick, "
+                "reserve, sample, tick.prepare, tick.dispatch, ...)",
+                labels=("model", "phase"))
+            self._queue_h = metrics.histogram(
+                "dl4j_decode_queue_wait_seconds",
+                "seconds from a sequence's (re-)enqueue to its admission",
+                labels=("model",))
+            self._first_h = metrics.histogram(
+                "dl4j_decode_first_token_seconds",
+                "seconds from submit to the first sampled token",
+                labels=("model",))
+            self._rows_h = metrics.histogram(
+                "dl4j_decode_tick_rows", "live rows per decode tick",
+                labels=("model",),
+                buckets=tuple(float(b) for b in self.engine.decode_buckets))
         self._worker = threading.Thread(
             target=self._run,
             name=(f"dl4j-decode-sched-{name}" if arm == "stable"
@@ -207,22 +231,27 @@ class GenerationScheduler:
         p = np.exp(z)
         return int(seq.rng.choice(len(p), p=p / p.sum()))
 
-    def _append_sample(self, seq: _Seq, logits: np.ndarray) -> bool:
-        """Sample the next token; True if the sequence is finished."""
+    def _append_sample(self, seq: _Seq, logits: np.ndarray) -> Optional[str]:
+        """Sample the next token; the finish reason if that ends the
+        sequence (the caller finishes it), else None."""
         tok = self._sample(seq, logits)
         if self._tokens_c is not None:
             self._tokens_c.inc(model=self.name)
         if tok in seq.stop_ids:
-            self._finish(seq, "stop")
-            return True
+            return "stop"
         seq.ctx.append(tok)
         if len(seq.generated) >= seq.max_tokens:
-            self._finish(seq, "length")
-            return True
+            return "length"
         if len(seq.ctx) >= self.engine.max_context:
-            self._finish(seq, "context")
-            return True
-        return False
+            return "context"
+        return None
+
+    def _observe(self, sp):
+        """A closed span's seconds into dl4j_decode_host_seconds, under
+        its name's last path element. The engine calls this too."""
+        if self._host_h is not None:
+            self._host_h.observe(sp.seconds, model=self.name,
+                                 phase=sp.name.rpartition("/")[2])
 
     def _evict_one(self, keep: _Seq) -> bool:
         """Preempt the NEWEST running sequence other than `keep` back to
@@ -238,6 +267,7 @@ class GenerationScheduler:
         victim.enqueued_at = time.perf_counter()   # re-queued: wait restarts
         with self._lock:
             self._waiting.appendleft(victim)
+        self._evictions += 1
         if self._evict_c is not None:
             self._evict_c.inc(model=self.name)
         rec = flight_recorder()
@@ -291,42 +321,94 @@ class GenerationScheduler:
                 if self.mode == "static" and self._running:
                     return
                 seq = self._waiting.popleft()
-            if not self._reserve(seq, len(seq.ctx)):
-                continue
-            t0 = time.perf_counter()
+            with _span("dl4j/sched/admit", sid=seq.sid,
+                       prompt_len=seq.prompt_len) as admit:
+                self._admit_one(v, seq, admit)
+            self._observe(admit)
+
+    def _admit_one(self, v, seq: _Seq, admit):
+        """Reserve, prefill and sample the first token of one sequence
+        taken off the queue, inside its `dl4j/sched/admit` span."""
+        t_pop = admit.t0 * 1e-9
+        queue_wait = t_pop - seq.enqueued_at   # enqueue (or re-queue) -> here
+        admit.set(queue_wait_s=queue_wait)
+        if self._queue_h is not None:
+            self._queue_h.observe(queue_wait, model=self.name)
+        if seq.trace is not None:
+            seq.trace.emit("queue_wait", seq.enqueued_at, t_pop,
+                           model=self.name, sid=seq.sid,
+                           ctx_len=len(seq.ctx))
+        with _span("dl4j/sched/reserve") as reserve:
+            evicted = self._evictions
+            ok = self._reserve(seq, len(seq.ctx))
+            reserve.set(evicted=self._evictions - evicted)
+        self._observe(reserve)
+        if not ok:
+            return
+        t0 = time.perf_counter()
+        try:
+            logits = self.engine.run_prefill(v, self.pool, seq.ctx,
+                                             seq.blocks,
+                                             observe=self._observe)
+        except Exception as e:          # noqa: BLE001 - fail the seq
+            self._fail(seq, e)
+            return
+        t1 = time.perf_counter()
+        if self._phase_h is not None:
+            self._phase_h.observe(t1 - t0, model=self.name, phase="prefill")
+        # the ordinal the readers select by: the phase histogram's count
+        # after this prefill
+        self._prefills += 1
+        admit.set(prefill=self._prefills)
+        if seq.trace is not None:
+            seq.trace.emit("prefill", t0, t1, model=self.name,
+                           tokens=len(seq.ctx))
+        if self._admit_c is not None:
+            self._admit_c.inc(model=self.name)
+        rec = flight_recorder()
+        if rec.enabled:
+            # KV-pool pressure at the admission decision point
+            rec.record("decode/admit", model=self.name, sid=seq.sid,
+                       prompt_len=seq.prompt_len,
+                       blocks=len(seq.blocks),
+                       free_blocks=self.pool.free_blocks())
+        seq.cached = len(seq.ctx)
+        with _span("dl4j/sched/sample") as sample:
+            reason = self._append_sample(seq, logits)
+            sample.set(finished=int(reason is not None))
+        self._observe(sample)
+        if seq.first_token_at is None:      # not a re-prefill after eviction
+            seq.first_token_at = sample.t1 * 1e-9
+            first = seq.first_token_at - seq.submitted_at
+            admit.set(first_token_s=first)
+            if self._first_h is not None:
+                self._first_h.observe(first, model=self.name)
             if seq.trace is not None:
-                # enqueue (or eviction re-queue) -> admission
-                seq.trace.emit("queue_wait", seq.enqueued_at, t0,
-                               model=self.name, sid=seq.sid,
-                               ctx_len=len(seq.ctx))
-            try:
-                logits = self.engine.run_prefill(v, self.pool, seq.ctx,
-                                                 seq.blocks, ctx=seq.trace)
-            except Exception as e:          # noqa: BLE001 - fail the seq
-                self._fail(seq, e)
-                continue
-            if self._phase_h is not None:
-                self._phase_h.observe(time.perf_counter() - t0,
-                                      model=self.name, phase="prefill")
-            if self._admit_c is not None:
-                self._admit_c.inc(model=self.name)
-            rec = flight_recorder()
-            if rec.enabled:
-                # KV-pool pressure at the admission decision point
-                rec.record("decode/admit", model=self.name, sid=seq.sid,
-                           prompt_len=seq.prompt_len,
-                           blocks=len(seq.blocks),
-                           free_blocks=self.pool.free_blocks())
-            seq.cached = len(seq.ctx)
-            if not self._append_sample(seq, logits):
-                self._running.append(seq)
+                seq.trace.emit("first_token", seq.submitted_at,
+                               seq.first_token_at, model=self.name,
+                               sid=seq.sid)
+        if reason is None:
+            self._running.append(seq)
+        else:
+            self._finish(seq, reason)
 
     def _tick(self, v):
+        if not self._running:
+            return
+        with _span("dl4j/sched/tick") as tick:
+            self._tick_rows(v, tick)
+        self._observe(tick)
+
+    def _tick_rows(self, v, tick):
         # room for each row's next slot BEFORE composing the batch, so
         # an eviction never invalidates a row already in the padded step
-        for seq in list(self._running):
-            if seq in self._running:        # _reserve may evict/fail rows
-                self._reserve(seq, seq.cached + 1)
+        with _span("dl4j/sched/reserve") as reserve:
+            evicted = self._evictions
+            for seq in list(self._running):
+                if seq in self._running:    # _reserve may evict/fail rows
+                    self._reserve(seq, seq.cached + 1)
+            reserve.set(evicted=self._evictions - evicted)
+        self._observe(reserve)
         if not self._running:
             return
         avail = len(self._running)
@@ -341,15 +423,29 @@ class GenerationScheduler:
         logits = self.engine.run_tick(
             v, self.pool, [s.ctx[s.cached] for s in batch],
             [s.cached for s in batch], [s.blocks for s in batch], bucket,
-            ctxs=[s.trace for s in batch])
+            observe=self._observe)
         dt = time.perf_counter() - t0
         self._ema.observe(bucket, dt)
         if self._phase_h is not None:
             self._phase_h.observe(dt, model=self.name, phase="decode")
-        for seq, row in zip(batch, logits):
-            seq.cached += 1
-            if self._append_sample(seq, row):
-                self._running.remove(seq)
+            self._rows_h.observe(len(batch), model=self.name)
+        # the ordinal the readers select by: the phase histogram's count
+        # after this tick. A request's ticks are found by membership.
+        self._ticks += 1
+        tick.set(tick=self._ticks, rows=len(batch), bucket=bucket,
+                 requests=[s.trace.trace_id for s in batch
+                           if s.trace is not None])
+        with _span("dl4j/sched/sample") as sample:
+            finished = 0
+            for seq, row in zip(batch, logits):
+                seq.cached += 1
+                reason = self._append_sample(seq, row)
+                if reason is not None:
+                    self._running.remove(seq)
+                    self._finish(seq, reason)
+                    finished += 1
+            sample.set(finished=finished)
+        self._observe(sample)
 
     def _resolve_version(self):
         """The version this scheduler's arm serves this tick. Canary
@@ -362,31 +458,44 @@ class GenerationScheduler:
             return arm_version(self.name, self.arm)
         return self.registry.get(self.name)
 
+    def _poll(self):
+        """(idle, closed, waiting) under the lock."""
+        with self._lock:
+            waiting = len(self._waiting)
+            return not waiting and not self._running, self._closed, waiting
+
     def _run(self):
         while True:
-            # idle wait happens on the Event, never under self._lock, so
-            # submit()/stop() can always get in to enqueue or close
-            while True:
-                with self._lock:
-                    idle = not self._waiting and not self._running
-                    closed = self._closed
-                if not idle:
-                    break
-                if closed:
-                    return
-                self._wake.wait(self._idle_wait_s)
-                self._wake.clear()
-            try:
-                v = self._resolve_version()
-                if self._version is not v:
-                    self._flush_running()
-                    self._version = v
-                self._admit(v)
-                self._tick(v)
-            except Exception as e:          # noqa: BLE001 - never die quietly
-                for seq in list(self._running):
-                    self._running.remove(seq)
-                    self._fail(seq, e)
-                with self._lock:
-                    while self._waiting:
-                        self._fail(self._waiting.popleft(), e)
+            idle, closed, waiting = self._poll()
+            if idle and not closed:
+                # one span for the whole idle period; the wait happens on
+                # the Event, never under self._lock, so submit()/stop()
+                # can always get in to enqueue or close
+                with _span("dl4j/sched/idle") as sp:
+                    while idle and not closed:
+                        self._wake.wait(self._idle_wait_s)
+                        self._wake.clear()
+                        idle, closed, waiting = self._poll()
+                self._observe(sp)
+            if idle:
+                return
+            with _span("dl4j/sched/loop", waiting=waiting,
+                       running=len(self._running)) as loop:
+                self._loop_once()
+            self._observe(loop)
+
+    def _loop_once(self):
+        try:
+            v = self._resolve_version()
+            if self._version is not v:
+                self._flush_running()
+                self._version = v
+            self._admit(v)
+            self._tick(v)
+        except Exception as e:          # noqa: BLE001 - never die quietly
+            for seq in list(self._running):
+                self._running.remove(seq)
+                self._fail(seq, e)
+            with self._lock:
+                while self._waiting:
+                    self._fail(self._waiting.popleft(), e)

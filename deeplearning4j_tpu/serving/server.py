@@ -40,9 +40,9 @@ CanaryState — the signal that drives automatic promotion or rollback.
 Tracing: every request gets a `TraceContext` (trace id + SLO tier from
 the `X-DL4J-SLO-Tier` header); the trace id comes back on EVERY
 response as the `X-DL4J-Trace` header and inside every structured error
-body, and the request's spans (root + queue_wait/bucket_select/prefill/
-decode_tick/scatter through the batching planes) land in the active
-telemetry session's Tracer as one connected Perfetto track. Latency is
+body, and the request's spans (root + queue_wait/prefill/first_token/
+scatter through the batching planes) land in the process-wide span log
+(`telemetry.tracer()`) as one connected Perfetto track. Latency is
 also observed per tier into the SLO surface (`dl4j_slo_latency_seconds`,
 `dl4j_slo_burn_rate`).
 

@@ -11,8 +11,11 @@ step, an enabled one a few microseconds per span.
 Four pieces:
   * `MetricsRegistry` (registry.py) — thread-safe counters / gauges /
     histograms / timers with Prometheus-text and JSONL exporters.
-  * `Tracer` (tracing.py) — spans in Chrome trace-event JSON, loadable in
-    Perfetto / chrome://tracing.
+  * `Tracer` (tracing.py) — the span log: one process-wide ring
+    (`tracer()`, `install_tracer()` for tests, `enabled`) that
+    `telemetry.span(name, **attrs)` writes to, also as
+    `jax.profiler.TraceAnnotation`s inside a profiler session; exports
+    Chrome trace-event JSON, loadable in Perfetto / chrome://tracing.
   * `CompileWatcher` (compile_watch.py) — counts XLA compilations per
     jitted entry point and warns on recompilation storms from shape churn
     (the silent TPU killer).
@@ -37,13 +40,14 @@ from .listener import TelemetryListener
 from .recorder import FlightRecorder, flight_recorder, install
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry, Timer)
 from .resources import ResourceWatermarks
-from .runtime import TelemetrySession, active, disable, enable, enabled
+from .runtime import (TelemetrySession, active, disable, enable, enabled,
+                      span)
 from .trace_context import SloSurface, TraceContext
-from .tracing import Tracer
+from .tracing import Tracer, install as install_tracer, tracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Timer", "MetricsRegistry",
-    "Tracer", "CompileWatcher", "watch_compiles", "ResourceWatermarks",
+    "Tracer", "tracer", "install_tracer", "span", "CompileWatcher", "watch_compiles", "ResourceWatermarks",
     "TelemetrySession", "TelemetryListener",
     "TraceContext", "SloSurface", "FlightRecorder", "flight_recorder",
     "install",

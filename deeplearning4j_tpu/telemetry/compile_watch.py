@@ -20,6 +20,8 @@ import warnings
 import weakref
 from typing import Callable, Dict, List, Optional, Tuple
 
+from .tracing import tracer
+
 __all__ = ["CompileWatcher", "watch_compiles", "RecompilationStormWarning",
            "roster", "roster_names"]
 
@@ -88,14 +90,13 @@ def _signature(args, kwargs):
 
 
 class CompileWatcher:
-    def __init__(self, registry=None, tracer=None, storm_threshold: int = 3):
+    def __init__(self, registry=None, storm_threshold: int = 3):
         self._lock = threading.Lock()
         self._counts: Dict[str, int] = {}
         self._time: Dict[str, float] = {}
         self._warned = set()
         self._sigs: Dict[str, set] = {}
         self.storm_threshold = max(1, int(storm_threshold))
-        self.tracer = tracer
         self._compilations = self._compile_s = None
         if registry is not None:
             self._compilations = registry.counter(
@@ -141,9 +142,8 @@ class CompileWatcher:
         if self._compilations is not None:
             self._compilations.inc(n, function=name)
             self._compile_s.observe(wall_s, function=name)
-        if self.tracer is not None:
-            self.tracer.instant(f"xla/compile:{name}", count=total,
-                                wall_s=round(wall_s, 4))
+        tracer().instant(f"xla/compile:{name}", count=total,
+                         wall_s=round(wall_s, 4))
         if storm:
             warnings.warn(
                 f"XLA recompilation storm: '{name}' has compiled {total} "

@@ -1,34 +1,29 @@
 """Process-wide telemetry session + the hot-path hooks the models consult.
 
 The instrumented paths (`MultiLayerNetwork._fit_batch`,
-`ComputationGraph._fit_batch`, `fit_scan_arrays`, `ParallelTrainer`,
-`Word2Vec.fit`) each do:
+`ComputationGraph._fit_batch`, the decode scheduler and engine) do:
 
-    tel = runtime.active()
-    span = tel.span if tel is not None else runtime.null_span
-    with span("host/batch_prep"): ...
+    with telemetry.span("host/batch_prep"): ...
 
-so a disabled session costs one module-global read and a shared no-op
-context manager per step — cheap enough to leave compiled in everywhere.
-
-`TelemetrySession.span` records BOTH a Chrome trace event and an
-aggregate `dl4j_span_seconds{span=...}` histogram observation: the trace
-answers "what happened around step 4017", the registry answers "where did
-the epoch's wall time go" even after the trace buffer wraps.
+`span` always records into the process-wide span log (`tracing.tracer()`,
+a fixed ring: a couple of microseconds a span) and, inside a profiler
+session, into the profiler's trace. Where a `TelemetrySession` is active
+it also observes the aggregate `dl4j_span_seconds{span=...}` histogram:
+the log answers "what happened around step 4017", the registry answers
+"where did the epoch's wall time go" even after the ring has wrapped.
 """
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Dict, Optional
 
 from .compile_watch import CompileWatcher
 from .registry import MetricsRegistry
 from .resources import ResourceWatermarks
-from .tracing import Tracer
+from .tracing import Tracer, _Span, tracer as _tracer
 
 __all__ = ["TelemetrySession", "active", "enable", "disable", "enabled",
-           "null_span"]
+           "null_span", "span"]
 
 
 class _NullCtx:
@@ -49,24 +44,24 @@ def null_span(name=None, **args) -> _NullCtx:
     return _NULL
 
 
-class _TimedSpan:
-    __slots__ = ("_sess", "_name", "_args", "_t0")
+class _TimedSpan(_Span):
+    """A span of the log that the active session's histogram sees too."""
 
-    def __init__(self, sess, name, args):
-        self._sess = sess
-        self._name = name
-        self._args = args
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
+    __slots__ = ()
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        s = self._sess
-        s.tracer._complete(self._name, self._t0, t1, self._args)
-        s.span_seconds.observe(t1 - self._t0, span=self._name)
+        _Span.__exit__(self, *exc)
+        sess = _active
+        if sess is not None:
+            sess.span_seconds.observe(self.seconds, span=self.name)
         return False
+
+
+def span(name: str, **attrs) -> _TimedSpan:
+    """The one call hot paths make: a span in the process-wide log (and
+    in the profiler's trace while one is taken), a child of the span open
+    on this thread."""
+    return _TimedSpan(_tracer(), name, attrs)
 
 
 class TelemetrySession:
@@ -82,13 +77,11 @@ class TelemetrySession:
     """
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
-                 tracer: Optional[Tracer] = None,
                  sync_per_step: bool = False,
                  storm_threshold: int = 3,
                  report_window: int = 10):
         self.registry = registry if registry is not None else MetricsRegistry()
-        self.tracer = tracer if tracer is not None else Tracer()
-        self.compiles = CompileWatcher(self.registry, self.tracer,
+        self.compiles = CompileWatcher(self.registry,
                                        storm_threshold=storm_threshold)
         self.watermarks = ResourceWatermarks(self.registry)
         self.sync_per_step = bool(sync_per_step)
@@ -97,8 +90,13 @@ class TelemetrySession:
             "dl4j_span_seconds", "wall seconds per runtime span",
             labels=("span",))
 
-    def span(self, name: str, **args) -> _TimedSpan:
-        return _TimedSpan(self, name, args or None)
+    @property
+    def tracer(self) -> Tracer:
+        """The process-wide span log: a session owns no buffer."""
+        return _tracer()
+
+    def span(self, name: str, **attrs) -> _TimedSpan:
+        return span(name, **attrs)
 
     # -- artifacts ------------------------------------------------------
     def prometheus_text(self) -> str:

@@ -3,16 +3,14 @@
 `TraceContext` is the Dapper-style correlation object created once per
 HTTP request in `serving/server.py` and carried on `_Pending` through
 the DynamicBatcher and on `_Seq` through the decode scheduler/engine.
-Every hop emits a child span into the ACTIVE session's bounded `Tracer`
-(looked up lazily, so a context outlives enable/disable churn) with
-`trace_id` / `span_id` / `parent_id` in its args — one request renders
-as one connected track in Perfetto, and the parent-child links are what
-the acceptance test walks.
-
-Costs when tracing is off: a context is still created (the
-`X-DL4J-Trace` header must always exist for client-side correlation)
-but emission is one module-global read + an early return. The serving
-hot paths take `ctx=None` and skip even that.
+Every hop emits a child span into the process-wide span log
+(`tracing.tracer()`, always on: whether a telemetry session is active or
+not) with the request's `trace_id`, its own `span_id` and its
+`parent_id` — one request renders as one connected track in Perfetto,
+and the parent-child links are what the acceptance test walks. The
+timestamps are explicit (`time.perf_counter()` seconds, often taken on
+another thread), so these spans go to the log only, not to a profiler
+trace.
 
 `SloSurface` is the declared-target half: per-tier latency histograms
 (`dl4j_slo_latency_seconds{tier}`), breach counters and a burn-rate
@@ -30,7 +28,7 @@ import time
 import uuid
 from typing import Dict, Optional, Tuple
 
-from . import runtime
+from .tracing import tracer as _tracer
 
 __all__ = ["TraceContext", "SloSurface", "DEFAULT_SLO_TARGETS",
            "DEFAULT_TIER"]
@@ -45,9 +43,8 @@ DEFAULT_SLO_TARGETS = {
 }
 
 
-def _active_tracer():
-    sess = runtime.active()
-    return sess.tracer if sess is not None else None
+def _ns(seconds: float) -> int:
+    return round(seconds * 1e9)
 
 
 class _CtxSpan:
@@ -106,13 +103,9 @@ class TraceContext:
         thread). Returns the new span id; `parent` defaults to the root
         span."""
         sid = f"{self.trace_id}.{next(self._ids)}"
-        tr = _active_tracer()
-        if tr is not None:
-            a = dict(args)
-            a["trace_id"] = self.trace_id
-            a["span_id"] = sid
-            a["parent_id"] = self.span_id if parent is None else parent
-            tr._complete(name, t_start, t_end, a)
+        _tracer().emit(name, _ns(t_start), _ns(t_end), span_id=sid,
+                       parent=self.span_id if parent is None else parent,
+                       trace_id=self.trace_id, **args)
         return sid
 
     def span(self, name: str, *, parent: Optional[str] = None,
@@ -123,15 +116,9 @@ class TraceContext:
     def emit_root(self, name: str, **args):
         """Emit the root span covering the whole request (t_start ->
         now). Its parent_id is None — the trace's anchor."""
-        tr = _active_tracer()
-        if tr is None:
-            return
-        a = dict(args)
-        a["trace_id"] = self.trace_id
-        a["span_id"] = self.span_id
-        a["parent_id"] = None
-        a["tier"] = self.tier
-        tr._complete(name, self.t_start, time.perf_counter(), a)
+        _tracer().emit(name, _ns(self.t_start), time.perf_counter_ns(),
+                       span_id=self.span_id, parent=None,
+                       trace_id=self.trace_id, tier=self.tier, **args)
 
     def elapsed(self) -> float:
         return time.perf_counter() - self.t_start

@@ -71,13 +71,11 @@
 #                            bench rep with the remat activation-bytes
 #                            column
 #   ./runtests.sh obs        observability smoke: the ISSUE 17 suite
-#                            (connected /generate trace, Tracer
-#                            saturation accounting, flight-recorder ring
-#                            + guard-trip dumps, SLO surface,
-#                            /debug/flightrecord) plus one paired
-#                            enabled-vs-disabled obs-overhead bench rep
-#                            (serving + LeNet fit arms; the >=0.95
-#                            paired-ratio gate)
+#                            (connected /generate trace, flight-recorder
+#                            ring + guard-trip dumps, SLO surface,
+#                            /debug/flightrecord) and the span log's
+#                            (ring, nesting, scheduler and fit spans,
+#                            the same names in a profiler trace)
 #   ./runtests.sh elastic    elastic-training smoke (ISSUE 19): the
 #                            coordinated two-phase-commit suite (every
 #                            commit boundary crash-injected, torn
@@ -198,11 +196,7 @@ if [[ "${1:-}" == "fault" ]]; then
 fi
 if [[ "${1:-}" == "obs" ]]; then
     echo "=== observability smoke ==="
-    python -m pytest tests/test_observability.py -q
-    echo "=== paired enabled-vs-disabled obs-overhead bench rep ==="
-    exec env JAX_PLATFORMS=cpu \
-        python -m deeplearning4j_tpu.telemetry.obs_bench \
-        --pairs 2 --clients 4 --requests 40 --fit-batches 4
+    exec python -m pytest tests/test_observability.py tests/test_span_log.py -q
 fi
 if [[ "${1:-}" == "telemetry" ]]; then
     echo "=== telemetry smoke ==="

@@ -3,14 +3,16 @@
 Covers the tentpole acceptance criteria:
 
   * one `/generate` request under continuous batching yields ONE
-    connected trace — HTTP root -> queue_wait -> bucket_select ->
-    prefill -> >=3 decode_tick -> scatter — asserted by walking the
-    span parent-child links;
+    connected trace — HTTP root -> queue_wait -> prefill ->
+    first_token -> scatter, and >=3 scheduler ticks that list the
+    request — asserted by walking the span parent-child links and the
+    ticks' membership;
   * an injected non-finite step trips the guard and produces a
     flight-recorder dump carrying the failing step's score, the
     collective-sequence hash and the 64 preceding events;
-  * the write paths stay bounded and off-lock: Tracer saturation under
-    N concurrent threads drops EXACTLY the overflow (no torn events),
+  * the write paths stay bounded and off-lock: the Tracer ring under
+    N concurrent threads keeps EXACTLY the newest `capacity` events and
+    counts the overwritten ones (no torn events),
     and FlightRecorder.record takes no lock at all (proven under the
     sanitizer's lock-order shims).
 
@@ -42,7 +44,9 @@ from deeplearning4j_tpu.telemetry.recorder import (FlightRecorder,
 from deeplearning4j_tpu.telemetry.trace_context import (DEFAULT_SLO_TARGETS,
                                                         SloSurface,
                                                         TraceContext)
-from deeplearning4j_tpu.telemetry.tracing import _COUNTER_TID_BASE, Tracer
+from deeplearning4j_tpu.telemetry.tracing import (_COUNTER_TID_BASE, Tracer,
+                                                   install as install_tracer,
+                                                   tracer)
 
 pytestmark = pytest.mark.sanitize(
     allow_threads=("dl4j-decode-sched-", "dl4j-serving-http",
@@ -114,12 +118,28 @@ def test_trace_context_parent_links():
         assert sid_b != sid_c and sid_b.startswith(ctx.trace_id + ".")
 
 
-def test_trace_context_without_session_is_inert():
-    ctx = TraceContext.begin()
-    sid = ctx.emit("nothing", 0.0, 0.1)    # no active tracer: id only
-    assert sid.startswith(ctx.trace_id)
-    ctx.emit_root("nothing")               # no-op, no raise
-    assert ctx.elapsed() >= 0.0
+def test_trace_context_records_without_a_session():
+    """The span log is always on: a context records whether or not a
+    telemetry session is active, and nothing once the log is disabled."""
+    prev = install_tracer(Tracer(capacity=16))
+    try:
+        ctx = TraceContext.begin()
+        sid = ctx.emit("child", 0.0, 0.1)
+        assert sid.startswith(ctx.trace_id)
+        ctx.emit_root("root")
+        got = {e["name"]: e for e in tracer().snapshot()}
+        assert got["child"]["id"] == sid
+        assert got["child"]["parent"] == got["root"]["id"] == ctx.span_id
+        assert got["child"]["t1"] - got["child"]["t0"] == 100_000_000
+        assert got["root"]["parent"] is None
+        assert got["root"]["trace_id"] == ctx.trace_id
+        tracer().enabled = False
+        assert ctx.emit("more", 0.0, 0.1).startswith(ctx.trace_id)
+        ctx.emit_root("more")
+        assert len(tracer()) == 2
+        assert ctx.elapsed() >= 0.0
+    finally:
+        install_tracer(prev)
 
 
 def test_slo_surface_burn_accounting():
@@ -166,8 +186,8 @@ def test_counter_tracks_get_named_rows():
 
 
 def test_tracer_saturation_exact_drop_accounting():
-    n_threads, per_thread, max_events = 8, 200, 301
-    tr = Tracer(max_events=max_events)   # 1 slot already holds metadata
+    n_threads, per_thread, capacity = 8, 200, 301
+    tr = Tracer(capacity=capacity)
     barrier = threading.Barrier(n_threads)
 
     def writer(i):
@@ -181,9 +201,12 @@ def test_tracer_saturation_exact_drop_accounting():
         t.start()
     for t in threads:
         t.join()
-    assert len(tr) == max_events
-    assert tr.dropped_events == 1 + n_threads * per_thread - max_events
-    # no torn events: every stored instant is complete
+    assert len(tr) == capacity
+    assert tr.dropped_events == n_threads * per_thread - capacity
+    # the newest `capacity` events survive, none torn
+    snap = tr.snapshot()
+    assert [e["seq"] for e in snap] == list(
+        range(n_threads * per_thread - capacity, n_threads * per_thread))
     for e in tr.events():
         if e["ph"] == "i":
             assert {"name", "ts", "pid", "tid"} <= set(e)
@@ -290,12 +313,15 @@ def test_generate_yields_one_connected_trace(served, fresh_recorder):
     assert root["parent_id"] is None and root["tier"] == "interactive"
     rid = root["span_id"]
     # the request's whole lifecycle hangs off the one root span
-    for stage in ("queue_wait", "bucket_select", "prefill", "scatter"):
+    for stage in ("queue_wait", "prefill", "first_token", "scatter"):
         assert len(by_name[stage]) == 1, stage
         assert by_name[stage][0]["args"]["parent_id"] == rid, stage
-    ticks = by_name["decode_tick"]
+    # its decode ticks are the scheduler's tick spans that list it
+    ticks = [e for e in sess.tracer.events()
+             if e["name"] == "dl4j/sched/tick"
+             and trace_id in e["args"].get("requests", ())]
     assert len(ticks) >= 3
-    assert all(t["args"]["parent_id"] == rid for t in ticks)
+    assert len({t["args"]["tick"] for t in ticks}) == len(ticks)
     # every span of the trace shares the trace_id and a unique span_id
     sids = [e["args"]["span_id"] for e in evts]
     assert len(set(sids)) == len(sids)

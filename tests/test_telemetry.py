@@ -151,7 +151,7 @@ def test_jsonl_export_roundtrip(tmp_path):
 
 def test_tracer_chrome_trace(tmp_path):
     tr = Tracer()
-    with tr.span("outer", step=1):
+    with tr.span("outer", step=1) as outer:
         with tr.span("inner"):
             pass
     tr.instant("marker")
@@ -163,16 +163,20 @@ def test_tracer_chrome_trace(tmp_path):
     assert "outer" in names and "inner" in names and "marker" in names
     x = next(e for e in evs if e["name"] == "outer")
     assert x["ph"] == "X" and x["dur"] >= 0 and "ts" in x
-    assert x["args"] == {"step": 1}
+    assert x["args"]["step"] == 1 and x["args"]["parent_id"] is None
+    inner = next(e for e in evs if e["name"] == "inner")
+    assert inner["args"]["parent_id"] == x["args"]["span_id"] == outer.id
 
 
 def test_tracer_bounded_buffer():
-    tr = Tracer(max_events=5)  # slot 0 holds the process_name metadata
+    tr = Tracer(capacity=5)     # a ring: the newest 5 stay
     for i in range(20):
         tr.instant(f"e{i}")
     assert len(tr) == 5
-    assert tr.dropped_events == 16
-    assert tr.chrome_trace()["otherData"]["dropped_events"] == 16
+    assert tr.dropped_events == 15
+    assert [e["name"] for e in tr.snapshot()] == [f"e{i}"
+                                                  for i in range(15, 20)]
+    assert tr.chrome_trace()["otherData"]["dropped_events"] == 15
 
 
 # ---------------------------------------------------------------------------
