@@ -63,7 +63,11 @@ class Sizes:
     # kernels: (B, T, D, dtype, causal) / (N, C, dtype) / (T, B, F, H)
     flash_shapes: Tuple = ((8, 1024, 64, "bfloat16", True),
                            (4, 1024, 64, "float32", True),
-                           (2, 100, 64, "bfloat16", False))
+                           (2, 100, 64, "bfloat16", False),
+                           # tilings the GPT-2 shape does not choose: a
+                           # ragged causal tail, Dh 128 at four chunks
+                           (8, 1000, 64, "bfloat16", True),
+                           (2, 2048, 128, "bfloat16", False))
     bn_shapes: Tuple = ((256, 2048, "bfloat16"), (512, 512, "bfloat16"),
                         (64, 100, "float32"))
     lstm_shapes: Tuple = ((64, 64, 77, 200), (64, 64, 200, 200))

@@ -111,6 +111,149 @@ def test_flash_attention_bf16():
                                np.asarray(ref), rtol=2e-2, atol=2e-2)
 
 
+# ----------------- flash attention: tiles from the shape -------------------
+
+from deeplearning4j_tpu.kernels import attention as flash_mod
+from deeplearning4j_tpu.kernels.attention import flash_schedule, flash_tiling
+
+
+@pytest.mark.parametrize("T,S,Dh,dtype,causal", [
+    (1024, 1024, 64, "bfloat16", True),     # the GPT-2 training shape
+    (50, 50, 64, "float32", True),
+    (96, 80, 64, "float32", False),
+    (128, 5, 64, "float32", False),
+    (2048, 2048, 128, "bfloat16", False),
+    (8192, 8192, 128, "bfloat16", True),
+    (1024, 1024, 64, "float32", True),
+    (512, 1536, 32, "bfloat16", False),
+])
+def test_flash_tiling_respects_budget_and_tiling_rules(T, S, Dh, dtype, causal):
+    til = flash_tiling(T, S, Dh, dtype, causal)
+    assert til.vmem_bytes <= flash_mod._VMEM_BUDGET
+    # derived tiles are whole (8, 128) tiles both as rows and as lanes
+    assert til.block_q % 128 == 0 and til.block_k % 128 == 0
+    # padding: whole resident blocks, and less than one block of it
+    assert til.t_pad % (til.block_q * til.q_chunks) == 0
+    assert til.s_pad % (til.block_k * til.k_chunks) == 0
+    assert 0 <= til.t_pad - T < til.block_q * til.q_chunks
+    assert 0 <= til.s_pad - S < til.block_k * til.k_chunks
+    # a few hundred grid steps a call where there were thousands: at most
+    # one q-major step for every 256 query rows of a head
+    sched = flash_schedule(til, T, S, causal)
+    assert sched["steps_q_major"] <= max(1, T // 256)
+    assert sched["chunks_visited"] + sched["chunks_dead"] == (
+        (til.t_pad // til.block_q) * (til.s_pad // til.block_k))
+    # explicit blocks cap the derived ones, in the dtype's sublane multiple
+    sub = 8 if dtype == "float32" else 16
+    capped = flash_tiling(T, S, Dh, dtype, causal, block_q=32, block_k=16)
+    assert capped.block_q <= 32 and capped.block_k <= 16
+    assert capped.block_q % sub == 0 and capped.block_k % sub == 0
+    assert capped.vmem_bytes <= til.vmem_bytes
+    wide = flash_tiling(T, S, Dh, dtype, causal, block_q=256, block_k=128)
+    assert wide.block_q <= 256 and wide.block_k <= 128
+
+
+def test_flash_tiling_causal_steps_hold_no_dead_tile():
+    til = flash_tiling(1024, 1024, 64, "bfloat16", True)
+    sched = flash_schedule(til, 1024, 1024, True)
+    nq, nk = 1024 // til.block_q, 1024 // til.block_k
+    assert nq > 1 and til.k_chunks > 1      # several q tiles, an inner loop
+    assert sched["dead_steps"] == 0
+    assert sched["chunks_dead"] > 0         # the dead ones are never visited
+    live = sum(1 for i in range(nq) for j in range(nk)
+               if j * til.block_k <= (i + 1) * til.block_q - 1)
+    assert sched["chunks_visited"] == live
+    full = flash_schedule(til, 1024, 1024, False)
+    assert full["chunks_visited"] == nq * nk and full["chunks_dead"] == 0
+
+
+def test_flash_tiling_shrinks_tiles_to_the_budget(monkeypatch):
+    monkeypatch.setattr(flash_mod, "_VMEM_BUDGET", 2 << 20)
+    til = flash_tiling(4096, 4096, 128, "bfloat16", True)
+    assert til.vmem_bytes <= 2 << 20
+    assert til.block_q * til.block_k < 512 * 512
+    assert til.k_chunks < 4096 // til.block_k   # K/V no longer all resident
+
+
+def _value_and_grads(fn, q, k, v):
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+    loss = lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_).astype(jnp.float32) * w)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+def test_flash_attention_derived_tiling_value_and_grad(causal, dtype, tol):
+    """T = S = 1024 at Dh 64: the derived tiling has two q tiles and an inner
+    loop of two chunks, one of them on the diagonal when causal."""
+    til = flash_tiling(1024, 1024, 64, dtype, causal)
+    assert 1024 // til.block_q > 1 and til.k_chunks > 1
+    q, k, v = (a.astype(dtype) for a in _qkv(B=1, T=1024, S=1024, D=64, seed=7))
+    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    ref = attention_reference(q, k, v, causal=causal)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), rtol=tol, atol=tol)
+    _, gk = _value_and_grads(lambda *a: flash_attention(
+        *a, causal=causal, interpret=True), q, k, v)
+    _, gr = _value_and_grads(lambda *a: attention_reference(
+        *a, causal=causal), q, k, v)
+    for a, b in zip(gk, gr):
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                   rtol=10 * tol, atol=tol * np.abs(b).max())
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("T,S", [(96, 96), (80, 112)])
+def test_flash_attention_streams_resident_blocks(monkeypatch, causal, T, S):
+    """A budget too small for all of K/V (or Q/dO) at once: the kernels
+    stream resident blocks over a third grid axis, and under the causal mask
+    a dead block re-names a live one."""
+    monkeypatch.setattr(flash_mod, "_VMEM_BUDGET", 48 << 10)
+    flash_mod._planned.cache_clear()
+    til = flash_tiling(T, S, 32, "float32", causal, 16, 16)
+    assert 1 < til.k_chunks < -(-S // 16) and 1 < til.q_chunks < -(-T // 16)
+    q, k, v = _qkv(B=2, T=T, S=S, D=32, seed=11)
+    try:
+        val, gk = _value_and_grads(lambda *a: flash_attention(
+            *a, causal=causal, block_q=16, block_k=16, interpret=True), q, k, v)
+    finally:
+        flash_mod._planned.cache_clear()
+    ref, gr = _value_and_grads(lambda *a: attention_reference(
+        *a, causal=causal), q, k, v)
+    np.testing.assert_allclose(float(val), float(ref), rtol=2e-5)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_flash_tiling_record_once_per_compiled_shape():
+    from deeplearning4j_tpu import telemetry
+    previous = telemetry.tracer()
+    telemetry.install_tracer(telemetry.Tracer())
+    flash_mod._planned.cache_clear()
+    flash_mod._fwd_call.clear_cache()    # a shape compiled before is not
+    flash_mod._bwd_call.clear_cache()    # built, and so not recorded, again
+    try:
+        grad = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(flash_attention(
+            q_, k_, v_, causal=True, interpret=True))))
+        for T in (40, 40, 72):          # forward and backward, twice, then new
+            q, k, v = _qkv(B=1, T=T, S=T, D=16)
+            grad(q, k, v)
+        recs = [e for e in telemetry.tracer().snapshot()
+                if e["name"] == "dl4j/kernels/flash_tiling"]
+    finally:
+        telemetry.install_tracer(previous)
+        flash_mod._planned.cache_clear()
+    assert [r["attrs"]["T"] for r in recs] == [40, 72]
+    a = recs[0]["attrs"]
+    assert recs[0]["ph"] == "i"
+    assert (a["S"], a["Dh"], a["dtype"], a["causal"]) == (40, 16, "float32", True)
+    assert a["block_q"] == 128 and a["steps_q_major"] == a["steps_a_call"] == 1
+    assert a["chunks_visited"] == 1 and a["dead_steps"] == 0
+
+
 # ------------------------- fused BN + ReLU --------------------------------
 
 def test_fused_bn_relu_matches_reference():
