@@ -4,11 +4,28 @@ Kwon et al., SOSP 2023, applied to our layer stack).
 One preallocated arena per servable holds EVERY concurrent sequence's
 keys and values:
 
-    arena  [num_blocks, block_len, 2*L, H, Dh]
+    arena  [2*L, num_blocks, block_len, H*Dh]
 
 where channel ``2l`` is layer l's keys and ``2l+1`` its values. A
 sequence owns an ordered list of block ids (its BLOCK TABLE); cache slot
-``t`` of a sequence lives at ``(table[t // block_len], t % block_len)``.
+``t`` of a sequence lives at ``(table[t // block_len], t % block_len)``
+of every channel.
+
+Why this order. The heads are merged into the last dimension because the
+TPU tiles an array's two minor dimensions (8 x 128 for float32): a last
+dimension of ``Dh`` = 64 is half a lane tile, so the device stores a
+``[..., H, Dh]`` arena with ANOTHER dimension minor (``num_blocks``,
+padded up), and every executable converts the whole arena to row-major
+on entry and back on exit: two arena-sized transposing copies a call.
+With ``H*Dh`` last the entry layout is row-major and the donated arena
+is updated in place. The channel comes first so that a layer's keys (or
+values) are one contiguous slab, not a strided slice of every block: the
+gather through the block tables, ``kv[c, tables]``, then reads the rows'
+own blocks only (written ``kv[c][tables]`` the slab was copied out
+first, 64 MiB a channel at the served size).
+`tests/test_flash_compile_tpu.py` compiles both steps for a described
+v5e and holds them to it.
+
 The compiled steps scatter new K/V by block index and gather a
 sequence's whole cache view through its table — HBM is shared at block
 granularity, so thousands of sequences with wildly different lengths
@@ -21,9 +38,9 @@ by the per-row valid length, so the compiled step needs no branches for
 dead rows. Allocation never hands out block 0.
 
 int8 KV (``kv_dtype="int8"``): the arena stores int8 plus a per-slot
-scale arena ``[num_blocks, block_len, 2*L]`` — `serving/quantize.py`'s
+scale arena ``[2*L, num_blocks, block_len]`` — `serving/quantize.py`'s
 per-tensor symmetric scheme (scale = absmax / 127) applied per cached
-(position, layer, K|V) vector, quantized at scatter time and
+(layer, K|V, position) vector of ``H*Dh``, quantized at scatter time and
 dequantized inside the gather. Halves-of-halves memory for the cache at
 ~1e-2-level logit drift; the equivalence/bit-exactness contracts are
 asserted on the fp32 cache only.
@@ -91,8 +108,8 @@ class KvCacheSpec:
 def make_cache(spec: KvCacheSpec) -> Dict[str, jnp.ndarray]:
     """Fresh zeroed cache pytree — ONE donated argument of the compiled
     steps. fp32: {"kv": arena}; int8 adds the per-slot scale arena."""
-    shape = (spec.num_blocks, spec.block_len, 2 * spec.n_layers,
-             spec.n_heads, spec.d_head)
+    shape = (2 * spec.n_layers, spec.num_blocks, spec.block_len,
+             spec.n_heads * spec.d_head)
     if spec.kv_dtype == "int8":
         return {"kv": jnp.zeros(shape, jnp.int8),
                 "scale": jnp.ones(shape[:3], jnp.float32)}
@@ -100,14 +117,14 @@ def make_cache(spec: KvCacheSpec) -> Dict[str, jnp.ndarray]:
 
 
 def pack_kv(spec: KvCacheSpec, x):
-    """Prepare K or V slices [..., H, Dh] for a cache scatter. Returns
+    """Prepare K or V vectors [..., H*Dh] for a cache scatter. Returns
     (values, scales_or_None): int8 quantizes per leading-index vector
-    (per-tensor symmetric over the trailing [H, Dh])."""
+    (per-tensor symmetric over the trailing H*Dh)."""
     if spec.kv_dtype != "int8":
         return x, None
-    absmax = jnp.max(jnp.abs(x), axis=(-2, -1))
+    absmax = jnp.max(jnp.abs(x), axis=-1)
     scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x / scale[..., None, None]), -127, 127)
+    q = jnp.clip(jnp.round(x / scale[..., None]), -127, 127)
     return q.astype(jnp.int8), scale.astype(jnp.float32)
 
 
@@ -115,7 +132,7 @@ def unpack_kv(spec: KvCacheSpec, q, scale):
     """Dequantize a gathered cache view (inverse of `pack_kv`)."""
     if spec.kv_dtype != "int8":
         return q
-    return q.astype(jnp.float32) * scale[..., None, None]
+    return q.astype(jnp.float32) * scale[..., None]
 
 
 class BlockPool:

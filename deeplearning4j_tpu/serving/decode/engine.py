@@ -23,14 +23,22 @@ same-architecture hot-swap reuses every decode executable.
     table, attend with causal offsets + per-row valid length
     (`kernels.attention` kv_length path), project logits.
 
-The cache pytree is DONATED: the arena updates in place on device, so a
+The cache pytree is DONATED and laid out `[2L, num_blocks, block_len,
+H*Dh]` (`cache.py` says why), so the arena updates in place on device: a
 tick costs one [B,*] pass plus the table gathers, never an arena copy.
+That holds on the chip and is kept by a test:
+`tests/test_flash_compile_tpu.py::test_decode_steps_update_the_arena_in_place_on_v5e`
+compiles both steps for a described v5e and refuses an arena-sized
+temporary, a copy of a channel's slab or a lost alias; every executable
+built leaves its `temp_bytes` beside `arena_bytes` in the span log
+(`dl4j/engine/executable`).
 Rows are independent throughout (no cross-row reductions), which is
 what makes token-granularity join/leave bit-exact for the rows that
 stay — the continuous-batching isolation contract the tests assert.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -39,7 +47,7 @@ import numpy as np
 
 from ...telemetry.compile_watch import watch_compiles
 from ...telemetry.runtime import span as _span
-from ...telemetry.tracing import named_step
+from ...telemetry.tracing import named_step, tracer as _tracer
 from ..registry import ServingError, _abstract_sig
 from .cache import BlockPool, KvCacheSpec, make_cache, pack_kv, unpack_kv
 
@@ -73,35 +81,50 @@ def split_decode_layers(model):
 
 
 def _cache_arg_specs(spec: KvCacheSpec):
-    return jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), make_cache(spec))
+    """The cache pytree's shapes, with no arena made to learn them."""
+    return jax.eval_shape(lambda: make_cache(spec))
 
 
 def _scatter(spec, kv, sc, values, blk, off, channel):
-    """Write K or V `values` (leading index shape == blk/off) into the
-    arena at (blk, off, channel), quantizing for int8 caches."""
-    vals, scales = pack_kv(spec, values)
-    kv = kv.at[blk, off, channel].set(vals)
+    """Write K or V `values` [..., H, Dh] (leading index shape ==
+    blk/off) into the arena at (channel, blk, off), heads merged,
+    quantizing for int8 caches."""
+    vals, scales = pack_kv(spec, values.reshape(*values.shape[:-2], -1))
+    kv = kv.at[channel, blk, off].set(vals)
     if scales is not None:
-        sc = sc.at[blk, off, channel].set(scales)
+        sc = sc.at[channel, blk, off].set(scales)
     return kv, sc
 
 
 def _gather(spec, kv, sc, tables, channel):
     """Sequence-major cache view [B, W*block_len, H, Dh] of one channel,
     dequantized: every row reads its own blocks through its table (dead
-    table slots point at the trash block; always length-masked)."""
-    view = kv[:, :, channel][tables]            # [B, W, bl, H, Dh]
-    b = tables.shape[0]
-    view = view.reshape(b, -1, spec.n_heads, spec.d_head)
-    if sc is None:
-        return view
-    scale = sc[:, :, channel][tables].reshape(b, -1)
-    return unpack_kv(spec, view, scale)
+    table slots point at the trash block; always length-masked). Only
+    the gathered view is reshaped, never the arena."""
+    view = kv[channel, tables]                   # [B, W, bl, H*Dh]
+    if sc is not None:
+        view = unpack_kv(spec, view, sc[channel, tables])
+    return view.reshape(tables.shape[0], -1, spec.n_heads, spec.d_head)
 
 
 def _repack(cache, kv, sc):
     return {"kv": kv, "scale": sc} if "scale" in cache else {"kv": kv}
+
+
+def _shared_steps(blocks, make):
+    """`make(block)` jitted, one for each of `blocks`, shared by blocks that
+    differ in nothing but their name: a stack's identical blocks are then
+    traced and lowered once, not once each in every one of a servable's
+    executables (24 blocks, on the chip's host: 0.88 s of tracing and
+    lowering an executable became 0.21, eight executables a set-up). The
+    layer's channel is an argument for that; XLA inlines the calls and
+    folds it, so the arena is still updated in place (the v5e compile
+    test holds both steps to it)."""
+    keys = [dataclasses.replace(block, name=None) for block in blocks]
+    first = [keys.index(key) for key in keys]   # the first block equal to it
+    steps = {i: jax.jit(make(blocks[i]))  # graftlint: disable=unwatched-jit-entry,jit-in-loop
+             for i in set(first)}
+    return [steps[i] for i in first]
 
 
 def build_prefill_fn(model, snapshot, spec: KvCacheSpec):
@@ -110,6 +133,17 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec):
     `data` tuple stays a runtime argument, so re-quantized checkpoints
     share the executable (the stateless plane's convention)."""
     emb, blocks, head = split_decode_layers(model)
+
+    def layer_step(layer):
+        def step(p, x, kv, sc, channel, blk, off, pos, lengths):
+            q, k, v = layer.decode_qkv(p, x)
+            kv, sc = _scatter(spec, kv, sc, k, blk, off, channel)
+            kv, sc = _scatter(spec, kv, sc, v, blk, off, channel + 1)
+            a = layer.decode_attend(q, k, v, pos, lengths)
+            return layer.decode_finish(p, x, a), kv, sc
+        return step
+
+    steps = _shared_steps(blocks, layer_step)
 
     def prefill(data, cache, tokens, lengths, tables):
         params = snapshot.rebuild(data)
@@ -124,12 +158,9 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec):
         # overwritten wholesale — reuse is bit-identical to fresh
         blk = tables[:, tidx // spec.block_len]
         off = jnp.broadcast_to(tidx % spec.block_len, (b, tp))
-        for i, layer in enumerate(blocks):
-            q, k, v = layer.decode_qkv(params[1 + i], x)
-            kv, sc = _scatter(spec, kv, sc, k, blk, off, 2 * i)
-            kv, sc = _scatter(spec, kv, sc, v, blk, off, 2 * i + 1)
-            a = layer.decode_attend(q, k, v, pos, lengths)
-            x = layer.decode_finish(params[1 + i], x, a)
+        for i, step in enumerate(steps):
+            x, kv, sc = step(params[1 + i], x, kv, sc, jnp.int32(2 * i),
+                             blk, off, pos, lengths)
         logits = head.preout(params[-1], {}, x)          # [B, Tp, V]
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
@@ -142,6 +173,20 @@ def build_decode_fn(model, snapshot, spec: KvCacheSpec):
     """Pure one-token decode tick (see module docstring)."""
     emb, blocks, head = split_decode_layers(model)
 
+    def layer_step(layer):
+        def step(p, x, kv, sc, channel, blk, off, tables, positions, lengths):
+            q, k, v = layer.decode_qkv(p, x)
+            kv, sc = _scatter(spec, kv, sc, k[:, 0], blk, off, channel)
+            kv, sc = _scatter(spec, kv, sc, v[:, 0], blk, off, channel + 1)
+            k_all = _gather(spec, kv, sc, tables, channel)
+            v_all = _gather(spec, kv, sc, tables, channel + 1)
+            a = layer.decode_attend(q, k_all, v_all, positions[:, None],
+                                    lengths)
+            return layer.decode_finish(p, x, a), kv, sc
+        return step
+
+    steps = _shared_steps(blocks, layer_step)
+
     def decode(data, cache, tokens, positions, tables):
         params = snapshot.rebuild(data)
         b = tokens.shape[0]
@@ -150,19 +195,17 @@ def build_decode_fn(model, snapshot, spec: KvCacheSpec):
         kv, sc = cache["kv"], cache.get("scale")
         blk = tables[jnp.arange(b), positions // spec.block_len]
         off = positions % spec.block_len
-        for i, layer in enumerate(blocks):
-            q, k, v = layer.decode_qkv(params[1 + i], x)
-            kv, sc = _scatter(spec, kv, sc, k[:, 0], blk, off, 2 * i)
-            kv, sc = _scatter(spec, kv, sc, v[:, 0], blk, off, 2 * i + 1)
-            k_all = _gather(spec, kv, sc, tables, 2 * i)
-            v_all = _gather(spec, kv, sc, tables, 2 * i + 1)
-            a = layer.decode_attend(q, k_all, v_all, positions[:, None],
-                                    lengths)
-            x = layer.decode_finish(params[1 + i], x, a)
+        for i, step in enumerate(steps):
+            x, kv, sc = step(params[1 + i], x, kv, sc, jnp.int32(2 * i),
+                             blk, off, tables, positions, lengths)
         logits = head.preout(params[-1], {}, x)[:, 0]
         return _repack(cache, kv, sc), logits.astype(jnp.float32)
 
     return named_step("tick", decode)
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
 
 
 def _pow2_buckets(lo: int, hi: int) -> Tuple[int, ...]:
@@ -256,42 +299,43 @@ class DecodeEngine:
                 "generation cache geometry; re-enable generation")
         return v
 
+    def _compile(self, v, build_fn, phase: str, bucket: int, *arg_specs):
+        """Lower and compile one step over the abstract donated cache, and
+        leave the record that says whether the arena is updated in place:
+        the span-log instant `dl4j/engine/executable`, once per
+        executable built (`temp_bytes` beside `arena_bytes`: a program
+        that converts or copies the arena holds a temporary of its size;
+        `alias_bytes` is what the donation gave back)."""
+        spec = self.spec
+        step = watch_compiles(
+            jax.jit(build_fn(v.model, v.snapshot, spec), donate_argnums=(1,)),
+            f"serving/decode:{self.name}/{phase}-{bucket}").__wrapped__
+        compiled = step.lower(v.snapshot.data, _cache_arg_specs(spec),
+                              *arg_specs).compile()
+        mem = compiled.memory_analysis()
+        _tracer().instant(
+            "dl4j/engine/executable", model=self.name, phase=phase,
+            bucket=bucket, arena_bytes=spec.arena_nbytes(),
+            temp_bytes=getattr(mem, "temp_size_in_bytes", None),
+            alias_bytes=getattr(mem, "alias_size_in_bytes", None))
+        return compiled
+
     def prefill_exec(self, v, t_bucket: int):
         sig = _abstract_sig(v.snapshot, v.state, v.precision)
-        spec = self.spec
-
-        def build():
-            prefill_step = watch_compiles(
-                jax.jit(build_prefill_fn(v.model, v.snapshot, spec),
-                        donate_argnums=(1,)),
-                f"serving/decode:{self.name}/prefill-t{t_bucket}").__wrapped__
-            i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-            return prefill_step.lower(
-                v.snapshot.data, _cache_arg_specs(spec),
-                i32(1, t_bucket), i32(1), i32(1, spec.table_width)
-            ).compile()
-
+        w = self.spec.table_width
         return self.registry.compile_cached(
-            self.name, ("decode", sig, "prefill", t_bucket), build,
+            self.name, ("decode", sig, "prefill", t_bucket),
+            lambda: self._compile(v, build_prefill_fn, "prefill", t_bucket,
+                                  _i32(1, t_bucket), _i32(1), _i32(1, w)),
             f"prefill-t{t_bucket}")
 
     def decode_exec(self, v, bucket: int):
         sig = _abstract_sig(v.snapshot, v.state, v.precision)
-        spec = self.spec
-
-        def build():
-            decode_step = watch_compiles(
-                jax.jit(build_decode_fn(v.model, v.snapshot, spec),
-                        donate_argnums=(1,)),
-                f"serving/decode:{self.name}/tick-b{bucket}").__wrapped__
-            i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-            return decode_step.lower(
-                v.snapshot.data, _cache_arg_specs(spec),
-                i32(bucket), i32(bucket), i32(bucket, spec.table_width)
-            ).compile()
-
+        w = self.spec.table_width
         return self.registry.compile_cached(
-            self.name, ("decode", sig, "tick", bucket), build,
+            self.name, ("decode", sig, "tick", bucket),
+            lambda: self._compile(v, build_decode_fn, "tick", bucket,
+                                  _i32(bucket), _i32(bucket), _i32(bucket, w)),
             f"decode-b{bucket}")
 
     # -- host-facing phases ----------------------------------------------

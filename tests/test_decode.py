@@ -285,9 +285,65 @@ def test_kv_block_reuse_after_release_bitexact(served):
     fresh, out2 = gen3(pool2, fresh)
     for a, b in zip(out1, out2):
         np.testing.assert_array_equal(a, b)
-    kv1 = np.asarray(pool1.cache["kv"])[reused]
-    kv2 = np.asarray(pool2.cache["kv"])[fresh]
+    kv1 = np.asarray(pool1.cache["kv"])[:, reused]    # [2L, blocks, bl, H*Dh]
+    kv2 = np.asarray(pool2.cache["kv"])[:, fresh]
+    assert kv1.shape[:2] == (2 * eng.spec.n_layers, len(reused))
+    assert np.abs(kv1).max() > 0
     np.testing.assert_array_equal(kv1, kv2)
+
+
+@pytest.mark.parametrize("heads,d_head", [(4, 8), (2, 64)],
+                         ids=["HDh32", "HDh128"])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_cache_scatter_gather_round_trip(kv_dtype, heads, d_head):
+    """What `_scatter` writes through a block table, `_gather` reads back
+    through it — heads merged on the way in and split on the way out —
+    and nothing else in the arena moves: other channels and unowned
+    blocks stay zero, and every dead table slot (a prompt's overflow, a
+    pad row) lands in and reads from the trash block."""
+    from deeplearning4j_tpu.serving.decode.cache import (KvCacheSpec,
+                                                         make_cache,
+                                                         pack_kv, unpack_kv)
+    from deeplearning4j_tpu.serving.decode.engine import _gather, _scatter
+
+    spec = KvCacheSpec(n_layers=2, n_heads=heads, d_head=d_head, block_len=4,
+                       num_blocks=6, max_context=12, kv_dtype=kv_dtype)
+    cache = make_cache(spec)
+    assert cache["kv"].shape == (4, 6, 4, heads * d_head)
+    # row 0 owns blocks 3 and 5 (8 slots), its third table slot is dead;
+    # row 1 is a pad row: every slot dead
+    tables = jnp.asarray([[3, 5, 0], [0, 0, 0]], jnp.int32)
+    tidx = jnp.arange(12)
+    blk = tables[:, tidx // spec.block_len]
+    off = jnp.broadcast_to(tidx % spec.block_len, (2, 12))
+    x = jnp.asarray(np.random.default_rng(0).normal(
+        size=(2, 12, heads, d_head)), jnp.float32)
+    kv, sc = _scatter(spec, cache["kv"], cache.get("scale"), x, blk, off, 1)
+    view = np.asarray(_gather(spec, kv, sc, tables, 1))
+    assert view.shape == (2, 12, heads, d_head)
+
+    q, scale = pack_kv(spec, x.reshape(2, 12, -1))
+    want = np.asarray(unpack_kv(spec, q, scale)).reshape(x.shape)
+    np.testing.assert_array_equal(view[0, :8], want[0, :8])
+    if kv_dtype == "int8":
+        assert kv.dtype == jnp.int8 and sc.shape == (4, 6, 4)
+        step = np.abs(np.asarray(x[0, :8])).max(axis=(-2, -1)) / 127.0
+        assert np.all(np.abs(view[0, :8] - np.asarray(x[0, :8]))
+                      <= 0.5 * step[:, None, None] + 1e-6)
+    else:
+        np.testing.assert_array_equal(want, np.asarray(x))
+    # the dead slots all read the one trash block, which holds one of the
+    # vectors that were sent there
+    trash = view[1, :4]
+    for dead in (view[0, 8:], view[1, 4:8], view[1, 8:]):
+        np.testing.assert_array_equal(dead, trash)
+    sent = np.concatenate([want[0, 8:], want[1]])
+    for slot in range(4):
+        assert any(np.array_equal(trash[slot], v) for v in sent[slot::4])
+    arena = np.asarray(kv)
+    assert not arena[[0, 2, 3]].any()             # other channels
+    assert not arena[1][[1, 2, 4]].any()          # unowned blocks
+    assert arena[1][[3, 5]].any()
 
 
 def test_eviction_resume_greedy_exact_and_counted():
@@ -343,6 +399,46 @@ def test_single_sequence_larger_than_pool_fails_cleanly():
         sched.stop()
 
 
+@pytest.mark.parametrize("phase", ["prefill", "tick"])
+def test_identical_blocks_are_traced_and_lowered_once(phase):
+    """A stack's equal blocks share one traced step (set-up pays the
+    tracing of a block once an executable, not once a block); a block
+    that differs gets its own."""
+    import re
+
+    import jax
+
+    from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_decode_fn,
+                                                          build_prefill_fn)
+    from deeplearning4j_tpu.serving.registry import _snapshot_params
+
+    b = (NeuralNetConfiguration.builder().seed(0).updater(Adam(1e-3)).list()
+         .layer(EmbeddingSequenceLayer(n_in=VOCAB, n_out=WIDTH)))
+    for mult in (4, 4, 2, 4):
+        b = b.layer(TransformerBlock(n_heads=4, ffn_mult=mult))
+    conf = (b.layer(RnnOutputLayer(n_out=VOCAB, activation="softmax",
+                                   loss="mcxent"))
+            .set_input_type(InputType.recurrent(1, TMAX)).build())
+    model = MultiLayerNetwork(conf).init()
+    snapshot = _snapshot_params(model, "fp32")
+    spec = KvCacheSpec(n_layers=4, n_heads=4, d_head=WIDTH // 4, block_len=4,
+                       num_blocks=9, max_context=TMAX)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    w = spec.table_width
+    if phase == "tick":
+        fn, args = build_decode_fn, (i32(2), i32(2), i32(2, w))
+    else:
+        fn, args = build_prefill_fn, (i32(1, 8), i32(1), i32(1, w))
+    text = jax.jit(fn(model, snapshot, spec), donate_argnums=(1,)).lower(
+        snapshot.data, _cache_arg_specs(spec), *args).as_text()
+    steps = re.findall(r"func\.func private @(step\w*)\(", text)
+    calls = re.findall(r"call @(step\w*)\(", text)
+    assert len(steps) == 2 and len(calls) == 4
+    assert sorted(calls.count(s) for s in steps) == [1, 3]
+
+
 # ---------------------------------------------------------------------------
 # int8 KV cache
 # ---------------------------------------------------------------------------
@@ -354,13 +450,22 @@ def test_int8_kv_cache_generates():
     sched = GenerationScheduler(reg, "gen", block_len=4, kv_dtype="int8",
                                 decode_buckets=(1, 2))
     try:
-        assert sched.pool.cache["kv"].dtype == jnp.int8
-        assert "scale" in sched.pool.cache
+        spec = sched.engine.spec
+        kv, scale = sched.pool.cache["kv"], sched.pool.cache["scale"]
+        assert kv.dtype == jnp.int8
+        assert kv.shape == (2 * spec.n_layers, spec.num_blocks,
+                            spec.block_len, spec.n_heads * spec.d_head)
+        assert scale.shape == kv.shape[:3]
         res = sched.submit([3, 7, 1, 4], max_tokens=6, timeout=300)
         assert res["generated_tokens"] == 6
         # prefill attends over the LOCAL (unquantized) projections, so
         # the FIRST sampled token is exact even with an int8 cache
         assert res["tokens"][0] == eager_greedy(model, [3, 7, 1, 4], 1)[0]
+        # 4 + 6 cache slots were written, in blocks the pool handed out:
+        # the block axis is the arena's second, and the trash block is 0
+        written = np.flatnonzero(
+            np.asarray(sched.pool.cache["kv"]).any(axis=(0, 2, 3)))
+        assert len(set(written) - {0}) == spec.blocks_for(4 + 6 - 1)
     finally:
         sched.stop()
 

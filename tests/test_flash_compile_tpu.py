@@ -1,12 +1,16 @@
 """The flash kernels compiled for a TPU v5e that is described, not attached:
 the chip's own compiler (Mosaic, libtpu) takes each tiling the chooser
 derives at real widths, so a slice off the (8, 128) tiling or a step over
-the VMEM limit fails here and not on the chip. Nothing runs: no result and
-no time comes from this file.
+the VMEM limit fails here and not on the chip. The decode plane's two
+steps are compiled the same way over an abstract, donated paged cache, and
+held to updating it in place. Nothing runs: no result and no time comes
+from this file.
 
 All of these tests live in this one file, and the topology is described
 inside a fixture, because only one process at a time may load the TPU's
 library."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -53,3 +57,88 @@ def test_flash_kernels_compile_for_v5e(one_chip, B, T, S, Dh, dtype, causal,
         for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
             assert name in text
         assert lowered.compile() is not None
+
+
+@pytest.fixture(scope="module")
+def decode_stack():
+    """(model, snapshot, spec) at the served cell's cache geometry: width
+    1024 in 16 heads, blocks of 16 slots, 1,025 of them, table width 64.
+    Twelve blocks deep with the narrowest FFN and a 256-token vocabulary,
+    so that no array over 4 MiB is made: the 1.5 GiB arena is abstract."""
+    from deeplearning4j_tpu import (EmbeddingSequenceLayer, InputType,
+                                    MultiLayerNetwork,
+                                    NeuralNetConfiguration, RnnOutputLayer,
+                                    Sgd, TransformerBlock)
+    from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
+    from deeplearning4j_tpu.serving.registry import _snapshot_params
+
+    depth = 12
+    b = (NeuralNetConfiguration.builder().seed(0).updater(Sgd(0.0)).list()
+         .layer(EmbeddingSequenceLayer(n_in=256, n_out=1024)))
+    for _ in range(depth):
+        b = b.layer(TransformerBlock(n_heads=16, ffn_mult=1))
+    conf = (b.layer(RnnOutputLayer(n_out=256, activation="softmax",
+                                   loss="mcxent"))
+            .set_input_type(InputType.recurrent(1, 1024)).build())
+    model = MultiLayerNetwork(conf).init()
+    spec = KvCacheSpec(n_layers=depth, n_heads=16, d_head=64, block_len=16,
+                       num_blocks=1025, max_context=1024)
+    return model, _snapshot_params(model, "fp32"), spec
+
+
+@pytest.mark.parametrize("phase,bucket", [("tick", 16), ("prefill", 512)])
+def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
+                                                       phase, bucket):
+    """The paged cache is donated and written by scatters, so a step may
+    hold no temporary of the arena's size: the device keeps the arena
+    row-major as it arrives (a last dimension of H*Dh is whole lane tiles),
+    the program neither converts nor copies it, and the output aliases the
+    input. With the arena `[num_blocks, block_len, 2L, H, Dh]` that PR 31
+    replaced, the device kept `num_blocks` minor and both steps converted
+    the arena on entry and back on exit: the tick held 1.94 times the arena
+    in temporaries at the served model's 24 blocks (6.55 GiB beside 3.375).
+    What stays, whatever the depth, is the tick's gathered view of one
+    layer (16 rows x 1,024 slots x 1,024 floats, 64 MiB, four of them
+    live): a twelfth of a 24-deep arena, a sixth of this one."""
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_decode_fn,
+                                                          build_prefill_fn)
+
+    model, snapshot, spec = decode_stack
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    w = spec.table_width
+    if phase == "tick":
+        fn, args = build_decode_fn, (i32(bucket), i32(bucket), i32(bucket, w))
+    else:
+        fn, args = build_prefill_fn, (i32(1, bucket), i32(1), i32(1, w))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn(model, snapshot, spec), donate_argnums=(1,)).lower(
+            on_chip(snapshot.data), on_chip(_cache_arg_specs(spec)),
+            *args).compile()
+    arena = spec.arena_nbytes()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < arena / 4
+    assert mem.alias_size_in_bytes >= arena
+    text = compiled.as_text()
+    dims = (2 * spec.n_layers, spec.num_blocks, spec.block_len,
+            spec.n_heads * spec.d_head)
+    shape = "f32[%d,%d,%d,%d]" % dims
+    # row-major on entry (and so, aliased, on exit): {3,2,1,0}
+    layout = text[text.index("entry_computation_layout="):].split("\n")[0]
+    assert shape + "{3,2,1,0:" in layout
+    assert shape + "{" not in layout.replace(shape + "{3,2,1,0:", "")
+    big = [m for m in re.finditer(
+        r"= (\w+)\[([\d,]*)\]\S* copy\(", text)
+        if _nbytes(m.group(1), m.group(2)) >= arena / (2 * spec.n_layers)]
+    assert not big, [m.group(0) for m in big]
+
+
+def _nbytes(dtype: str, dims: str) -> int:
+    n = 1
+    for d in filter(None, dims.split(",")):
+        n *= int(d)
+    bits = re.search(r"\d+$", dtype)          # f32, bf16, s8; pred has none
+    return n * (int(bits.group()) if bits else 8) // 8

@@ -320,6 +320,28 @@ def test_engine_spans_outside_the_scheduler_have_no_tick_parent(log):
     assert not _spans(log, "dl4j/sched/tick")
 
 
+def test_every_executable_built_leaves_its_memory_record(log):
+    """`dl4j/engine/executable`: once per executable built, never per
+    call, what the program holds beside the donated arena."""
+    registry = ModelRegistry(buckets=(1,))
+    registry.register("gen", _lm())
+    eng = DecodeEngine(registry, "gen", block_len=4, decode_buckets=(1, 2))
+    pool, v = eng.new_pool(), registry.get("gen")
+    blocks = pool.alloc(eng.spec.blocks_for(6))
+    eng.run_prefill(v, pool, [1, 2, 3], blocks)
+    for pos in (3, 4):
+        eng.run_tick(v, pool, [4], [pos], [blocks], bucket=1)
+    eng.decode_exec(v, 2)
+    records = [e["attrs"] for e in log.snapshot()
+               if e["ph"] == "i" and e["name"] == "dl4j/engine/executable"]
+    assert [(r["phase"], r["bucket"]) for r in records] == [
+        ("prefill", 8), ("tick", 1), ("tick", 2)]
+    for r in records:
+        assert r["model"] == "gen"
+        assert r["arena_bytes"] == eng.spec.arena_nbytes()
+        assert r["temp_bytes"] >= 0 and r["alias_bytes"] >= 0
+
+
 # ---------------------------------------------------------------------------
 # fit
 # ---------------------------------------------------------------------------
