@@ -13,7 +13,7 @@ the entry points a user calls, at the full width of models the repo ships:
             T=1024 on the flash kernel, then ModelSerializer zip ->
             ModelRegistry -> InferenceServer -> POST /generate over HTTP,
             prefill+decode logits checked against the full forward
-  char-rnn  zoo.char_rnn at the bench size on the fused LSTM kernel
+  char-rnn  zoo.char_rnn (2 x 200, b64, seq 128) on the fused LSTM kernel
   mesh      (>= 4 devices) ResNet-50 data-parallel and the LM under
             zero1_tp (2, 2) with flash under shard_map
 
@@ -89,7 +89,7 @@ class Sizes:
     prompt_len: int = 500        # + gen_tokens >= 512: a real context
     gen_tokens: int = 24
     check_ticks: int = 8
-    # char-rnn: the bench size of zoo.bench_char_rnn
+    # char-rnn: zoo.char_rnn at the reference example's size
     rnn_vocab: int = 77
     rnn_hidden: int = 200
     rnn_batch: int = 64
@@ -347,7 +347,7 @@ def _resnet_batch(sizes: Sizes):
 
 def phase_train(sizes: Sizes = FULL, interpret: bool = False) -> Dict:
     """zoo.resnet50 through ComputationGraph.fit per batch, then one
-    fit_scan_arrays window (the call bench_resnet50 makes)."""
+    fit_scan_arrays window (the whole window in one dispatch)."""
     import jax
     import jax.numpy as jnp
 
@@ -587,7 +587,7 @@ def _serve_lm(model, sizes: Sizes) -> Dict:
 # phase: char-rnn
 # ---------------------------------------------------------------------------
 def phase_char_rnn(sizes: Sizes = FULL, interpret: bool = False) -> Dict:
-    """zoo.char_rnn at the bench size through fit (TBPTT chunks); on the
+    """zoo.char_rnn through fit (TBPTT chunks); on the
     chip GravesLSTM._helper must have picked the fused kernel."""
     import jax
     import jax.numpy as jnp

@@ -50,8 +50,8 @@ def _find_baseline(paths: Sequence[str], explicit: Optional[str]
 
 def lint_metrics(paths: Sequence[str],
                  baseline: Optional[str] = None) -> Dict:
-    """Programmatic entry for bench.py: {'total', 'new', 'by_rule',
-    'new_by_rule', 'files', 'wall_s'} for the given targets."""
+    """Programmatic entry (`tools/graftlint --metrics`): {'total', 'new',
+    'by_rule', 'new_by_rule', 'files', 'wall_s'} for the given targets."""
     t0 = time.perf_counter()
     res = run_lint(paths, baseline_path=_find_baseline(paths, baseline))
     return {
@@ -66,7 +66,7 @@ def lint_metrics(paths: Sequence[str],
 
 def ir_lint_metrics(paths: Sequence[str] = (),
                     baseline: Optional[str] = None) -> Dict:
-    """IR-tier counterpart of `lint_metrics` for bench.py: runs the
+    """IR-tier counterpart of `lint_metrics`: runs the
     jaxpr/HLO pass over the probe roster (requires jax + the virtual
     mesh) and reports totals plus the measured whole-package IR wall
     time and the watch_compiles roster size."""

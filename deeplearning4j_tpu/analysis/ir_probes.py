@@ -406,6 +406,23 @@ def mesh2d_entries() -> List[IrEntry]:
     return entries
 
 
+def _tiny_lm(vocab: int, width: int, heads: int, seq: int, seed: int):
+    """One-block GPT-style LM (embedding -> TransformerBlock -> softmax
+    head): what the flash and the decode probes trace."""
+    from .. import (Adam, EmbeddingSequenceLayer, InputType,
+                    MultiLayerNetwork, NeuralNetConfiguration,
+                    RnnOutputLayer, TransformerBlock)
+
+    conf = (NeuralNetConfiguration.builder().seed(seed).updater(Adam(1e-3))
+            .list()
+            .layer(EmbeddingSequenceLayer(n_in=vocab, n_out=width))
+            .layer(TransformerBlock(n_heads=heads))
+            .layer(RnnOutputLayer(n_out=vocab, activation="softmax",
+                                  loss="mcxent"))
+            .set_input_type(InputType.recurrent(1, seq)).build())
+    return MultiLayerNetwork(conf).init()
+
+
 def _flash_arm(shape: Tuple[int, int], flash):
     """Build the ZERO1×TP transformer-LM trainer with the attention mode
     FORCED (``flash="spmd"`` -> shard_map'd Pallas kernel, interpret mode
@@ -416,11 +433,10 @@ def _flash_arm(shape: Tuple[int, int], flash):
     import jax.numpy as jnp
     import numpy as np
 
-    from ..parallel.scaling_bench import _build_transformer_lm
     from ..parallel.trainer import ParallelTrainer, ShardingStrategy
 
     vocab, seq, b = 32, 8, 8
-    tr = ParallelTrainer(_build_transformer_lm(vocab, 16, 4, 1, seq),
+    tr = ParallelTrainer(_tiny_lm(vocab, 16, 4, seq, seed=7),
                          mesh_shape=shape,
                          strategy=ShardingStrategy.ZERO1_TP, flash=flash)
     r = np.random.default_rng(0)
@@ -649,21 +665,11 @@ def _decode_build(seed: int = 0):
     """Tiny generate-capable LM (vocab=16, width=8, 1 block) registered
     into a fresh registry, plus the paged decode engine over it — small
     enough that tracing both decode-plane steps is milliseconds."""
-    from .. import (Adam, EmbeddingSequenceLayer, InputType,
-                    MultiLayerNetwork, NeuralNetConfiguration,
-                    RnnOutputLayer, TransformerBlock)
     from ..serving.decode.engine import DecodeEngine
     from ..serving.registry import ModelRegistry
 
-    conf = (NeuralNetConfiguration.builder().seed(seed).updater(Adam(1e-3))
-            .list()
-            .layer(EmbeddingSequenceLayer(n_in=16, n_out=8))
-            .layer(TransformerBlock(n_heads=2))
-            .layer(RnnOutputLayer(n_out=16, activation="softmax",
-                                  loss="mcxent"))
-            .set_input_type(InputType.recurrent(1, 16)).build())
     reg = ModelRegistry()
-    reg.register("ir-gen", MultiLayerNetwork(conf).init(), buckets=(1,))
+    reg.register("ir-gen", _tiny_lm(16, 8, 2, 16, seed), buckets=(1,))
     eng = DecodeEngine(reg, "ir-gen", block_len=4, decode_buckets=(1, 2))
     return eng, reg.get("ir-gen")
 

@@ -4,7 +4,8 @@ The SURVEY §7 phase-7 kernel target ("fused LSTM cell"), and the analog of
 the cuDNN RNN API the reference era lacked (SURVEY notes no cuDNN LSTM
 helper existed at v0.8; `LSTMHelpers.java` ran generic per-timestep ops).
 
-Why a kernel wins here where conv/BN kernels lost (see BASELINE.md): the
+Why a kernel can win here where, in an earlier installation's capture
+(BASELINE.md), conv/BN kernels did not: the
 XLA path is a `lax.scan` whose per-timestep work is a tiny [B, F+H] x
 [F+H, 4H] matmul — too small to hide per-op overhead, and the weights are
 re-read from HBM every timestep. At char-RNN size the FULL working set

@@ -33,8 +33,7 @@ equivalence in tests/test_precision_remat.py).
 
 `saved_bytes` is the static activation-byte accounting — what one
 checkpoint boundary actually saves for a concrete call — published
-through `_pp_info` the way `_ZeroPlan` publishes its byte accounting,
-and surfaced as the bench's activation-bytes column.
+through `_pp_info` the way `_ZeroPlan` publishes its byte accounting.
 """
 from __future__ import annotations
 

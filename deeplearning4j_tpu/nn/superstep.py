@@ -1,12 +1,13 @@
 """Device-resident supersteps: one training loop at fit_scan speed.
 
-The r05 capture (BASELINE.md) measured the per-batch ``fit()`` path at
-~226k samples/s on LeNet against ~1.5M for the device-resident
-``fit_scan`` path — a ~6.7x gap the telemetry dispatch spans attribute entirely to per-batch host
-dispatch. The superstep closes it without forking the API: ``fit(...,
-superstep=K)`` groups the iterator's batches into on-device windows of K
-and runs each window as ONE jitted ``lax.scan`` of the train step, so the
-host pays one dispatch per K batches instead of one per batch.
+An earlier installation's capture (BASELINE.md) found the per-batch
+``fit()`` path of a small model bound by per-batch host dispatch where
+the device-resident ``fit_scan`` path was not (not measured on the
+current chip). The superstep removes those dispatches without forking
+the API: ``fit(..., superstep=K)`` groups the iterator's batches into
+on-device windows of K and runs each window as ONE jitted ``lax.scan``
+of the train step, so the host pays one dispatch per K batches instead
+of one per batch.
 
 Per-batch API semantics are preserved:
 

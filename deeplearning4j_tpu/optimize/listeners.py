@@ -69,7 +69,6 @@ class ScoreIterationListener(IterationListener):
 
 class PerformanceListener(IterationListener):
     """Samples/sec + batches/sec reporting (`optimize/listeners/PerformanceListener.java`).
-    This is the metric surfaced by bench.py.
 
     Superstep/scan fits replay this hook at the window edge with the
     already-transferred per-window loss vector (model._score holds a HOST

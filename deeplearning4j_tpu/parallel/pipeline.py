@@ -26,11 +26,11 @@ fp32-master semantics as every other fit path. Composed into `ParallelTrainer` a
 `strategy="pp"` (pure pipe) and `"zero1_tp_pp"` (ZeRO-1 moments over
 `data` × Megatron TP over `model` × 1F1B over `pipe`).
 
-**Host-driven GPipe (legacy / bench baseline).** `PipelinedNetworkTrainer`
+**Host-driven GPipe (legacy).** `PipelinedNetworkTrainer`
 / `PipelinedGraphTrainer` run the GPipe two-phase schedule host-side with
-per-stage jits — dozens of dispatches per step. Kept as the paired
-baseline arm for `scaling_bench --mode pipeline` and for models whose
-heterogeneous stages the SPMD formulation cannot stack.
+per-stage jits — dozens of dispatches per step. Kept for
+`ComputationGraph` and for models whose heterogeneous stages the SPMD
+formulation cannot stack.
 
 Restriction (standard for SPMD pipelining): pipelined stages must share one
 program = identical layer structure and [.., F] -> [.., F] activation shape.

@@ -27,8 +27,8 @@ Tensor-parallel / FSDP param shardings compose with SYNC mode via
 params replicated but shard optimizer state (and stage-2 reduced
 gradients) over the data axis — reduce-scatter -> sharded update ->
 allgather instead of allreduce -> replicated update (see `zero.py`),
-killing the replicated-updater tax the r05 capture (BASELINE.md) measured
-at ~2.3 s/step.
+removing the replicated-updater work an earlier installation's capture
+(BASELINE.md) pointed at.
 """
 from __future__ import annotations
 

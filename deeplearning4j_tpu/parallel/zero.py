@@ -2,11 +2,11 @@
 
 The plain SYNC data-parallel step pays a "replicated updater" tax: every
 device holds the FULL optimizer state and redundantly applies the FULL
-parameter update after the gradient allreduce (the r05 capture,
-BASELINE.md, attributes ~2.3 s/step of the 8-device Adam wall time to this,
-`DP-replicated-updater-cost-ms`). ZeRO (Rajbhandari et al., 2020) removes
-it by partitioning optimizer state — and, at stage 2, the reduced
-gradients — across the data-parallel axis:
+parameter update after the gradient allreduce (an earlier
+installation's capture, BASELINE.md, attributed most of the 8-device
+Adam step on the virtual CPU mesh to this). ZeRO (Rajbhandari et al.,
+2020) removes it by partitioning optimizer state — and, at stage 2, the
+reduced gradients — across the data-parallel axis:
 
   reduce(-scatter) grads  ->  each device updates only ITS shard of the
   moments and params      ->  allgather of the updated params
@@ -306,14 +306,14 @@ class _ZeroPlan:
         # fp32 gradient-accumulator footprint per device: sharded leaves
         # land 1/N per device under ZERO2's post-reduce-scatter layout,
         # vs the full tree when accumulating replicated (the memory story
-        # tests/test_accumulation.py and the DP-accum bench assert)
+        # tests/test_accumulation.py asserts)
         acc_sharded = sum(
             (-(-(counts[i] // m_fac[i]) // n_dev) if i in self.sharded_set
              else counts[i] // m_fac[i])
             * 4 for i in range(len(leaves)))
         acc_repl = sum(counts[i] * 4 for i in range(len(leaves)))
-        # per-device param + optimizer-moment footprint (the headline the
-        # mesh2d bench reports: moments ~1/(d·m) of the replicated tree)
+        # per-device param + optimizer-moment footprint (moments ~1/(d·m)
+        # of the replicated tree; tests/test_mesh2d.py holds it)
         param_local = sum(counts[i] * itemsize[i] // m_fac[i]
                           for i in range(len(leaves)))
         moment_local = sum(

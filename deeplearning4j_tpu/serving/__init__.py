@@ -17,13 +17,8 @@ Four pieces, layered:
   * `InferenceServer` (server.py) — the HTTP front end (`/v1/models`,
     `/v1/models/<name>/predict`, `/v1/models/<name>/swap`, `/healthz`,
     Prometheus `/metrics` via the telemetry registry).
-
-`serving/bench.py` drives concurrent closed-loop clients through the
-data plane and reports p50/p99 latency + requests/s, batched vs
-unbatched (surfaced as bench.py extras["Serving-latency"]).
 """
 from .batcher import BatcherClosedError, DynamicBatcher
-from .bench import run_serving_bench
 from .quantize import QuantizedTree, cast_tree, quantize_tree
 from .registry import (AotCompileError, CanaryState, DEFAULT_BUCKETS,
                        ModelRegistry, PRECISIONS, ServableVersion,
@@ -37,5 +32,4 @@ __all__ = [
     "DynamicBatcher", "BatcherClosedError",
     "InferenceServer", "ClientError",
     "QuantizedTree", "quantize_tree", "cast_tree",
-    "run_serving_bench",
 ]

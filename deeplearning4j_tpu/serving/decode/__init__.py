@@ -8,7 +8,6 @@ the stateless serving plane.
   * `scheduler` — Orca-style token-granularity continuous batching:
                   sequences join and leave the decode batch between
                   ticks.
-  * `bench`     — the closed-loop continuous-vs-static generation bench.
 """
 from .cache import BlockPool, KvCacheSpec, OutOfBlocksError
 from .engine import DecodeEngine

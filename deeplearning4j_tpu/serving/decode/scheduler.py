@@ -6,8 +6,7 @@ loop: admit waiting sequences, grow/evict KV blocks, run ONE decode
 tick, sample, retire finished rows, repeat. The load-bearing property
 is WHERE admission happens: between every tick (token granularity), so
 a new request starts decoding the moment a batch slot and KV blocks
-exist instead of waiting for the whole current batch to drain — that
-is the continuous-vs-static tokens/s gap the bench measures.
+exist instead of waiting for the whole current batch to drain.
 
 Invariant per sequence: ``ctx`` is prompt + every sampled token, and
 ``cached`` counts how many of ctx's K/V live in the arena. Prefill
@@ -80,24 +79,17 @@ class _Seq:
 
 
 class GenerationScheduler:
-    """Continuous-batching generation loop for one servable.
+    """Continuous-batching generation loop for one servable: waiting
+    sequences are admitted between every tick."""
 
-    `mode="continuous"` admits between every tick; `mode="static"`
-    (the bench's control arm) only refills once the running set fully
-    drains — classic request-level batching."""
-
-    def __init__(self, registry, name: str, *, mode: str = "continuous",
-                 block_len: int = 16, num_blocks: Optional[int] = None,
+    def __init__(self, registry, name: str, *, block_len: int = 16,
+                 num_blocks: Optional[int] = None,
                  kv_dtype: str = "fp32",
                  decode_buckets: Sequence[int] = (1, 2, 4, 8),
                  prompt_buckets: Optional[Sequence[int]] = None,
                  metrics=None, idle_wait_s: float = 0.02,
                  arm: str = "stable"):
-        if mode not in ("continuous", "static"):
-            raise GenerationError(f"mode must be continuous|static, "
-                                  f"got {mode!r}")
         self.name = name
-        self.mode = mode
         # canary arm this scheduler serves: a "canary" scheduler
         # resolves the candidate version each tick (falling back to
         # stable after a rollback — the existing flush-on-version-change
@@ -317,8 +309,6 @@ class GenerationScheduler:
         while True:
             with self._lock:
                 if not self._waiting or len(self._running) >= cap:
-                    return
-                if self.mode == "static" and self._running:
                     return
                 seq = self._waiting.popleft()
             with _span("dl4j/sched/admit", sid=seq.sid,

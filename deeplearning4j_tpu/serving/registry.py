@@ -29,8 +29,8 @@ Executable reuse across swaps: compiled runners are cached per model
 entry keyed by the *abstract* signature (param/state shapes+dtypes,
 bucket, precision). Swapping in a same-architecture checkpoint reuses the
 existing executables with the new parameter snapshot — zero new XLA
-compiles, which the serving bench asserts (exactly one compile per
-(model, bucket) across a run with swaps).
+compiles: exactly one compile per (model, bucket) across a run with
+swaps (tests/test_serving.py holds the count).
 """
 from __future__ import annotations
 

@@ -110,7 +110,7 @@ def require(body: Dict, key: str):
 class InferenceServer:
     """HTTP front end over a ModelRegistry with per-model dynamic
     batching. `batching=False` serves every request on the direct
-    (chunk+pad, still AOT-compiled) path — the bench's unbatched arm."""
+    (chunk+pad, still AOT-compiled) path."""
 
     def __init__(self, registry: Optional[ModelRegistry] = None,
                  host: str = "127.0.0.1", port: int = 0,
@@ -144,7 +144,7 @@ class InferenceServer:
         self.host, self.port = self._httpd.server_address[:2]
         self._thread: Optional[threading.Thread] = None
 
-    # -- data plane (also driven directly by serving/bench.py) ----------
+    # -- data plane -----------------------------------------------------
     def _batcher(self, name: str, arm: str = "stable") -> DynamicBatcher:
         b = self._batchers.get((name, arm))  # GIL-atomic fast path, no mutex
         if b is not None:
@@ -194,7 +194,7 @@ class InferenceServer:
                           **opts) -> GenerationScheduler:
         """Attach a GenerationScheduler (continuous batching + paged KV
         cache) to servable `name`. `opts` pass through to the scheduler
-        (mode, block_len, num_blocks, kv_dtype, decode_buckets, ...).
+        (block_len, num_blocks, kv_dtype, decode_buckets, ...).
         Idempotent for a given (name, arm); called lazily with defaults
         by the first /generate request if never called explicitly. The
         stable arm's opts are remembered so a canary scheduler created
@@ -211,17 +211,6 @@ class InferenceServer:
                     arm=arm, **opts)
                 self._schedulers[(name, arm)] = sched
             return sched
-
-    def disable_generation(self, name: str):
-        """Drain and detach `name`'s schedulers, both arms (bench windows
-        swap continuous/static schedulers on one server this way)."""
-        with self._batchers_lock:
-            scheds = [self._schedulers.pop((name, a), None)
-                      for a in ("stable", "canary")]
-            self._sched_opts.pop(name, None)
-        for sched in scheds:
-            if sched is not None:
-                sched.stop(drain=True)
 
     def generate(self, name: str, prompt, *, max_tokens: int = 16,
                  temperature: float = 0.0, stop=(), seed=None,

@@ -1,7 +1,7 @@
 """Thread-safe metrics registry with Prometheus-text and JSONL exporters.
 
-Zero dependencies (stdlib only) so it can run in any process — bench
-subprocesses, the UI server, multi-host workers. Metric families follow
+Zero dependencies (stdlib only) so it can run in any process — child
+processes, the UI server, multi-host workers. Metric families follow
 Prometheus conventions: a family has a name, help text, a fixed label-name
 tuple, and one value series per label-value combination.
 """

@@ -225,7 +225,7 @@ class TelemetrySession:
         return out
 
     def summary(self) -> Dict:
-        """The compact dict bench.py embeds as extras.telemetry."""
+        """The session's counters and summaries as one compact dict."""
         rep = self.compiles.report()
         self.watermarks.sample()
         out = {

@@ -1,10 +1,10 @@
-"""Platform plumbing shared by the dryrun/bench/test harnesses: virtual CPU
+"""Platform plumbing shared by the dryrun and test harnesses: virtual CPU
 devices and the persistent compile cache.
 
 One home for the "N virtual CPU devices" recipe (the reference's analog is
 `local[N]` Spark in `BaseSparkTest.java:89`): XLA_FLAGS gets
 `--xla_force_host_platform_device_count=N` and the platform is forced to CPU.
-A process that must not take the accelerator (tests, CPU-mesh benches, any
+A process that must not take the accelerator (tests, any
 child of a parent that already holds the chip) forces the CPU this way; jax
 config beats the environment, so the in-process variant calls
 `jax.config.update("jax_platforms", "cpu")` BEFORE the first `jax.devices()`.

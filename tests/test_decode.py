@@ -23,6 +23,7 @@ donation mutation).
 """
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -635,6 +636,42 @@ def test_scheduler_stop_rejects_new_submissions():
     sched.stop()
     with pytest.raises(GenerationError):
         sched.submit([1, 2, 3], max_tokens=2)
+
+
+def test_scheduler_takes_no_mode(served):
+    """Continuous admission is the scheduler, not a mode of it: the
+    constructor takes no `mode`, and a waiting sequence is admitted
+    while another is still decoding. Read off the span log, whose order
+    no clock decides: the newcomer's `dl4j/sched/admit` span is followed
+    by ticks of the sequence that was already running."""
+    from deeplearning4j_tpu.telemetry import Tracer, install_tracer
+
+    reg, model, sched = served
+    with pytest.raises(TypeError):
+        GenerationScheduler(reg, "gen", mode="static")
+    log = Tracer()
+    prev = install_tracer(log)
+    try:
+        long_res = {}
+        t = threading.Thread(target=lambda: long_res.update(
+            sched.submit([5, 9], max_tokens=28, timeout=300)))
+        t.start()
+        deadline = time.monotonic() + 300
+        while sched.pool.used_blocks() == 0:    # until it is admitted
+            assert time.monotonic() < deadline and t.is_alive()
+            time.sleep(0.001)
+        # one token: sampled at the prefill, so it never joins a tick
+        short = sched.submit([7, 3, 1], max_tokens=1, timeout=300)
+        t.join()
+        events = log.snapshot()
+    finally:
+        install_tracer(prev)
+    assert short["generated_tokens"] == 1
+    assert long_res["tokens"] == eager_greedy(model, [5, 9], 28)
+    admits = [e for e in events if e["name"] == "dl4j/sched/admit"]
+    ticks = [e for e in events if e["name"] == "dl4j/sched/tick"]
+    assert [a["attrs"]["prompt_len"] for a in admits] == [2, 3]
+    assert any(k["t0"] > admits[1]["t1"] for k in ticks)
 
 
 def test_non_transformer_stack_rejected():

@@ -229,7 +229,7 @@ def test_pp_stage_saved_bytes_policy_ordering():
     # None == jax's save-nothing default == the "nothing" policy
     assert col[None] == 0 and col["nothing"] == 0
     # the selective policy must cut the blanket (un-checkpointed)
-    # residual set — the reduction the bench gate measures
+    # residual set
     assert 0 < col["dots"] < col["everything"]
 
 

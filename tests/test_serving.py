@@ -185,7 +185,7 @@ def test_swap_bumps_version_and_reuses_executables():
         np.testing.assert_allclose(out_b, np.asarray(net_b.output(rows(2))),
                                    rtol=1e-5, atol=1e-6)
         # same architecture -> executables reused: ONE compile per bucket
-        # across register + swap (the serving-bench acceptance invariant)
+        # across register + swap
         rep = sess.compiles.report()
         for b in (1, 4):
             assert rep[f"serving/m:b{b}"]["count"] == 1, rep
@@ -730,18 +730,3 @@ def test_legacy_output_accepts_shape_varying_sequences():
                                rtol=1e-4, atol=1e-5)
 
 
-# ---------------------------------------------------------------------------
-# Bench plumbing (tiny smoke — full numbers come from serving/bench.py)
-# ---------------------------------------------------------------------------
-def test_serving_bench_closed_loop_helper():
-    from deeplearning4j_tpu.serving.bench import _closed_loop
-
-    reg = ModelRegistry(buckets=(1, 4))
-    reg.register("m", tiny_net())
-    srv = InferenceServer(reg, max_wait_us=500)
-    res = _closed_loop(
-        lambda x: srv.predict("m", x), 4, 10,
-        lambda i: rows(1, seed=i))
-    srv.stop()
-    assert res["req_s"] > 0 and res["p99_ms"] >= res["p50_ms"]
-    assert "errors" not in res

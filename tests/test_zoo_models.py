@@ -1,6 +1,6 @@
 """Model-zoo topology tests (AlexNet / VGG-19 / GoogLeNet Inception-v1 +
-char sampling). Small image sizes keep the CPU mesh fast; the full-size
-variants are exercised on the TPU by the benches.
+char sampling). Small image sizes keep the CPU mesh fast; no test builds the
+full-size variants.
 """
 import numpy as np
 import pytest
