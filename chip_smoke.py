@@ -446,8 +446,7 @@ def phase_lm(sizes: Sizes = FULL, interpret: bool = False) -> Dict:
     return {"impl": {"train_attention":
                      "pallas flash (Mosaic, heads folded into batch)" if found
                      else "attention_reference (einsum)",
-                     "decode_attention": "attention_reference over the "
-                                         "paged cache view"},
+                     "decode_attention": serve.pop("decode_attention")},
             "batch": sizes.lm_batch, "losses": losses,
             "first_step_s": round(first_s, 2), "step_s": round(step_s, 4),
             "hbm": hbm, **serve}
@@ -539,8 +538,9 @@ def _serve_lm(model, sizes: Sizes) -> Dict:
             got.append(eng.run_tick(v, pool, [int(seq[n + i])], [n + i],
                                     [blocks], bucket=1)[0])
         # and once more by a fresh prefill of the same tokens: the last
-        # tick read its keys through _gather and the block table, the
-        # prefill uses its local projections
+        # tick read its keys through the block table (the paged kernel on
+        # the TPU, _gather's view elsewhere), the prefill uses its local
+        # projections
         blocks2 = pool.alloc(eng.spec.blocks_for(n + ticks))
         again = eng.run_prefill(v, pool, seq[:n + ticks - 1].tolist(),
                                 blocks2)
@@ -580,6 +580,7 @@ def _serve_lm(model, sizes: Sizes) -> Dict:
         raise SmokeFailure(f"server threads alive after stop(): {leaked}")
     return {"requests": 5, "failed": 0, "context": n + gen,
             "serve_first_s": round(first_s, 2),
+            "decode_attention": eng.attention,
             "decode_max_dlogp": float(f"{diff:.3g}")}
 
 

@@ -1,0 +1,248 @@
+"""Paged decode attention — one new token a row against the paged KV arena,
+read through the block table inside a Pallas TPU kernel.
+
+The decode plane's tick (`serving/decode/engine.py`) holds every sequence's
+keys and values in one arena `[2L, num_blocks, block_len, H*Dh]`
+(`serving/decode/cache.py`); a row owns the blocks its table names. The
+plain path gathers each row's whole table into a `[B, W*block_len, H, Dh]`
+view and attends over it: at the served size that is a 64 MiB gather and a
+128 MiB relayout a layer and channel, for rows that hold a few hundred live
+tokens. This kernel makes no view. The arena stays in HBM (`pl.ANY`); the
+tables, the lengths and the layer's channel are scalar-prefetched; a grid
+step is one row, and inside it a loop walks the row's live chunks of
+`pages_a_chunk` table columns. A chunk's K and V pages are copied to VMEM by
+one DMA a page (a page is `[block_len, H*Dh]`: contiguous, whole (8, 128)
+tiles) into one of two slots, the next chunk's — or the next row's first —
+in flight while this one is computed.
+
+- A chunk covers `_CHUNK_TOKENS` cache slots (8 pages of 16): 1 MiB of K and
+  V, 1.3 us of HBM time, against a loop iteration's fixed cost. A grid of one
+  step a chunk (the arena handed over once a page, the ordinary pipeline
+  fetching through index maps) measured twice the time on the v5e: 60 of its
+  128 steps were dead, and a dead step still costs, and hides nothing.
+- Dead pages are never read: the loop ends with the row's last live chunk,
+  and in that chunk the columns past the row's last live page name that page
+  again. What a dead table slot points at (the trash block, a block since
+  given to another sequence) never reaches VMEM. The ragged tail is masked
+  by the length, exactly the `kv_length` mask of `attention_reference`; in a
+  tick the query is the newest token, so the causal mask adds nothing.
+- All heads at once, on the merged `H*Dh` lanes: the row's query becomes the
+  block-diagonal `[H, H*Dh]` (row h holds head h's `Dh` lanes, zero
+  elsewhere), a chunk's scores are `Qbd @ K_chunk^T -> [H, slots]` on the
+  MXU, the running max and sum are `[H, 1]`, and `acc [H, H*Dh] += p @
+  V_chunk`, of which row h's own `Dh` lanes are the answer. No `[.., H, Dh]`
+  array exists anywhere. The MXU columns this wastes do not show: the kernel
+  is bound by HBM.
+- The arena stays as it is (float32). Products take float32 operands at the
+  default precision (on the TPU one bfloat16 pass, as XLA's matrix products
+  of this model) and accumulate in float32; the softmax statistics, `exp`
+  and the accumulator are float32.
+- Rows are independent: a row's chunks depend on its own length and table
+  only, so its result is the same whoever else is in the batch.
+
+`dl4j/kernels/paged_attention` in the span log says, once per call shape,
+what a call is made of (rows, table width, pages a chunk, grid steps, VMEM
+estimated).
+"""
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .attention import _MASK, _NEG_INF, _NN, _NT, _round_up
+
+__all__ = ["paged_decode_attention", "paged_attention_supported",
+           "paged_plan", "PagedPlan"]
+
+_CHUNK_TOKENS = 128     # cache slots one iteration of a row's loop covers
+_SUBLANES = 8           # float32 rows of a tile: heads are padded up to it
+
+
+class PagedPlan(NamedTuple):
+    """What one call is made of."""
+    pages_a_chunk: int
+    chunks_a_row: int    # at most: table columns / pages a chunk
+    steps_a_call: int    # grid steps: one a row
+    heads_padded: int
+    vmem_bytes: int      # estimated: the page slots, the rows, the values
+
+
+def paged_attention_supported(width: int, block_len: int) -> bool:
+    """Whether the compiled kernel takes a (float32) arena of `width` = H*Dh
+    lanes and `block_len`-slot pages: whole (8, 128) tiles a page."""
+    return width % 128 == 0 and block_len % _SUBLANES == 0
+
+
+def paged_plan(rows: int, table_width: int, block_len: int, n_heads: int,
+               width: int) -> PagedPlan:
+    """Pages a chunk from the shape: `_CHUNK_TOKENS` cache slots' worth, no
+    more than the table is wide."""
+    pages = max(1, min(_CHUNK_TOKENS // block_len, table_width))
+    hp = _round_up(n_heads, _SUBLANES)
+    chunk = pages * block_len * width * 4
+    vmem = (2 * 2 * chunk                   # two slots each of K and V pages
+            + 2 * 2 * rows * width * 4      # the queries and the output
+            + 2 * chunk                     # a chunk's K and V as values
+            + 2 * hp * width * 4)           # the block-diagonal query; acc
+    return PagedPlan(pages, pl.cdiv(table_width, pages), rows, hp, vmem)
+
+
+@functools.lru_cache(maxsize=64)
+def _planned(rows, table_width, block_len, n_heads, width,
+             num_blocks) -> PagedPlan:
+    """`paged_plan` for one call shape, worked out once a process; working
+    it out leaves the record `dl4j/kernels/paged_attention` in the span
+    log: written while a kernel is built, never while one runs."""
+    from ..telemetry import tracer
+
+    plan = paged_plan(rows, table_width, block_len, n_heads, width)
+    tracer().instant("dl4j/kernels/paged_attention", rows=rows,
+                     table_width=table_width, block_len=block_len,
+                     n_heads=n_heads, width=width, num_blocks=num_blocks,
+                     **plan._asdict())
+    return plan
+
+
+def _make_kernel(n_heads: int, d_head: int, plan: PagedPlan, block_len: int,
+                 sm_scale: float):
+    """Grid (rows,). A row's loop holds `pages_a_chunk` pages of K and of V
+    at a time; the scores are `[heads, slots]`, the statistics `[heads, 1]`."""
+    pages, hp, n_rows = plan.pages_a_chunk, plan.heads_padded, plan.steps_a_call
+    width, chunk = n_heads * d_head, plan.pages_a_chunk * block_len
+
+    def kernel(tables_ref, lengths_ref, channel_ref, q_ref, kv_ref, o_ref,
+               k_buf, v_buf, sems, slot_ref):
+        b = pl.program_id(0)
+        channel = channel_ref[0]
+
+        def copies(row, c, slot):
+            """The DMAs of chunk `c` of `row` into `slot`: a page of K and a
+            page of V a table column; past the row's last live page, that
+            page again."""
+            last = jnp.maximum(lengths_ref[row] - 1, 0) // block_len
+            out = []
+            for i in range(pages):
+                blk = tables_ref[row, jnp.minimum(c * pages + i, last)]
+                dst = pl.ds(i * block_len, block_len)
+                out.append(pltpu.make_async_copy(
+                    kv_ref.at[channel, blk], k_buf.at[slot, dst, :],
+                    sems.at[slot, 0]))
+                out.append(pltpu.make_async_copy(
+                    kv_ref.at[channel + 1, blk], v_buf.at[slot, dst, :],
+                    sems.at[slot, 1]))
+            return out
+
+        def start(row, c, slot):
+            for copy in copies(row, c, slot):
+                copy.start()
+
+        @pl.when(b == 0)
+        def _():
+            slot_ref[0] = 0
+            start(0, 0, 0)
+
+        length = lengths_ref[b]
+        n_chunks = jnp.maximum(length - 1, 0) // chunk + 1
+        first = slot_ref[0]              # the slot this row's chunk 0 is in
+        head = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
+        own = (lane >= head * d_head) & (lane < (head + 1) * d_head)
+        qbd = jnp.where(own, q_ref[pl.ds(b, 1), :], 0.0)        # [hp, width]
+
+        def body(c, carry):
+            m_prev, l_prev, acc = carry
+            slot = (first + c) % 2
+
+            @pl.when(c + 1 < n_chunks)
+            def _():
+                start(b, c + 1, 1 - slot)
+
+            @pl.when((c + 1 == n_chunks) & (b + 1 < n_rows))
+            def _():
+                start(b + 1, 0, 1 - slot)
+
+            for copy in copies(b, c, slot):
+                copy.wait()
+            s = jax.lax.dot_general(
+                qbd, k_buf[slot], _NT,
+                preferred_element_type=jnp.float32) * sm_scale
+            at = c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (hp, chunk), 1)
+            # chunk 0 holds slot 0, which every row may see: after it the
+            # running max is a real score and a masked one weighs exactly 0
+            s = jnp.where(at < length, s, _MASK)                # [hp, chunk]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * corr + jax.lax.dot_general(
+                p, v_buf[slot], _NN,
+                preferred_element_type=jnp.float32)             # [hp, width]
+            return m_new, l_new, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, n_chunks, body,
+            (jnp.full((hp, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((hp, 1), jnp.float32),
+             jnp.zeros((hp, width), jnp.float32)))
+        slot_ref[0] = (first + n_chunks) % 2
+        mine = jnp.where(own, acc / l, 0.0)
+        o_ref[pl.ds(b, 1), :] = jnp.sum(mine, axis=0, keepdims=True)
+
+    return kernel
+
+
+def paged_decode_attention(q, kv, channel, tables, lengths, *, n_heads: int,
+                           sm_scale: Optional[float] = None,
+                           interpret: Optional[bool] = None):
+    """Attention of one query a row over its paged cache.
+
+    q [B, H*Dh] float32 (heads merged, as the arena holds them); kv the arena
+    `[2L, num_blocks, block_len, H*Dh]`, read in place; `channel` (int32
+    scalar, may be traced) the layer's key channel, its values are channel +
+    1; tables [B, W] int32 block ids; lengths [B] int32 live cache slots a
+    row (>= 1; slot `lengths - 1` is the query's own). Returns [B, H*Dh]:
+    `attention_reference` over the gathered view with `kv_length=lengths`,
+    head by head. Compiled Pallas on the TPU; `interpret=True` (automatic off
+    it) runs the same kernel through the interpreter."""
+    B, width = q.shape
+    _, num_blocks, block_len, kv_width = kv.shape
+    if kv_width != width or width % n_heads:
+        raise ValueError(f"q {q.shape} and arena {kv.shape} disagree on "
+                         f"H*Dh, or {n_heads} heads do not divide it")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    d_head = width // n_heads
+    if sm_scale is None:
+        sm_scale = 1.0 / (d_head ** 0.5)
+    plan = _planned(B, tables.shape[1], block_len, n_heads, width, num_blocks)
+    slots = (2, plan.pages_a_chunk * block_len, width)
+    rows = pl.BlockSpec((B, width), lambda b, *_: (0, 0))
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))}
+    return pl.pallas_call(
+        _make_kernel(n_heads, d_head, plan, block_len, float(sm_scale)),
+        out_shape=jax.ShapeDtypeStruct((B, width), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[rows, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=rows,
+            scratch_shapes=[
+                pltpu.VMEM(slots, jnp.float32),             # K pages
+                pltpu.VMEM(slots, jnp.float32),             # V pages
+                pltpu.SemaphoreType.DMA((2, 2)),            # [slot, K | V]
+                pltpu.SMEM((1,), jnp.int32),    # the slot of the next chunk 0
+            ]),
+        interpret=interpret,
+        name="paged_decode_attention",
+        **params,
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.reshape(channel, (1,)).astype(jnp.int32),
+      q.astype(jnp.float32), kv)

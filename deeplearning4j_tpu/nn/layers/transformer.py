@@ -226,6 +226,8 @@ class TransformerBlock(LayerConf):
     #   q, k, v = blk.decode_qkv(p, x)        # LN1 + projections
     #   <engine scatters k/v into its arena, gathers the cache view>
     #   a = blk.decode_attend(q, k_all, v_all, positions, lengths)
+    #     (a tick on the TPU: kernels.paged_attention reads the arena's
+    #      pages through the block table instead of the two lines above)
     #   y = blk.decode_finish(p, x, a)        # out-proj + FFN residuals
     # Chaining the three over a full causal prompt (k_all = k, v_all = v,
     # positions = arange) is mathematically `apply` — the prefill+decode

@@ -19,31 +19,39 @@ padded up), and every executable converts the whole arena to row-major
 on entry and back on exit: two arena-sized transposing copies a call.
 With ``H*Dh`` last the entry layout is row-major and the donated arena
 is updated in place. The channel comes first so that a layer's keys (or
-values) are one contiguous slab, not a strided slice of every block: the
-gather through the block tables, ``kv[c, tables]``, then reads the rows'
-own blocks only (written ``kv[c][tables]`` the slab was copied out
-first, 64 MiB a channel at the served size).
+values) are one contiguous slab, not a strided slice of every block, and
+a block of one channel — a PAGE, ``[block_len, H*Dh]`` — is contiguous
+and whole (8, 128) tiles: what the tick's paged attention kernel
+(`kernels/paged_attention.py`) copies to VMEM, one DMA a page, for the
+pages a row's length spans and no other. The plain path's gather through
+the block tables, ``kv[c, tables]``, reads the rows' own blocks only
+(written ``kv[c][tables]`` the slab was copied out first, 64 MiB a
+channel at the served size).
 `tests/test_flash_compile_tpu.py` compiles both steps for a described
 v5e and holds them to it.
 
-The compiled steps scatter new K/V by block index and gather a
-sequence's whole cache view through its table — HBM is shared at block
-granularity, so thousands of sequences with wildly different lengths
-pack the arena with at most ``block_len - 1`` wasted slots each, instead
-of every sequence reserving a max-context rectangle.
+The compiled steps scatter new K/V by block index and read a sequence's
+cache through its table: the tick's kernel page by page where it can run
+(`engine.tick_attention`), else as a gathered view of the whole table —
+HBM is shared at block granularity, so thousands of sequences with
+wildly different lengths pack the arena with at most ``block_len - 1``
+wasted slots each, instead of every sequence reserving a max-context
+rectangle.
 
 Block 0 is RESERVED (the "trash" block): padded batch slots and
 overflow prompt positions write there and their reads are always masked
-by the per-row valid length, so the compiled step needs no branches for
-dead rows. Allocation never hands out block 0.
+by the per-row valid length (the paged kernel does not read it at all
+but for a pad row's one slot), so the compiled step needs no branches
+for dead rows. Allocation never hands out block 0.
 
 int8 KV (``kv_dtype="int8"``): the arena stores int8 plus a per-slot
 scale arena ``[2*L, num_blocks, block_len]`` — `serving/quantize.py`'s
 per-tensor symmetric scheme (scale = absmax / 127) applied per cached
 (layer, K|V, position) vector of ``H*Dh``, quantized at scatter time and
-dequantized inside the gather. Halves-of-halves memory for the cache at
-~1e-2-level logit drift; the equivalence/bit-exactness contracts are
-asserted on the fp32 cache only.
+dequantized inside the gather (the paged kernel takes the float32 arena
+only: an int8 cache ticks on the gathered view). Halves-of-halves memory
+for the cache at ~1e-2-level logit drift; the equivalence/bit-exactness
+contracts are asserted on the fp32 cache only.
 """
 from __future__ import annotations
 

@@ -19,13 +19,19 @@ same-architecture hot-swap reuses every decode executable.
   decode(data, cache, tokens [B], positions [B], tables [B, W])
       -> (cache', logits [B, V])
     One token per row: embed at its absolute position, scatter its K/V
-    into the arena, gather the row's whole cache view through its block
-    table, attend with causal offsets + per-row valid length
-    (`kernels.attention` kv_length path), project logits.
+    into the arena, attend over the row's live cache slots, project
+    logits. The attention reads the arena through the block table inside
+    one Pallas kernel (`kernels.paged_attention`: live pages only, all
+    heads on the merged lanes, no view of the cache made) where
+    `tick_attention` finds the TPU, a float32 arena and pages of whole
+    tiles. Elsewhere — the CPU, the int8 arena, odd widths — it gathers
+    the row's whole table into a view and attends with causal offsets +
+    per-row valid length (`kernels.attention` kv_length path): the
+    kernel's oracle.
 
 The cache pytree is DONATED and laid out `[2L, num_blocks, block_len,
 H*Dh]` (`cache.py` says why), so the arena updates in place on device: a
-tick costs one [B,*] pass plus the table gathers, never an arena copy.
+tick costs one [B,*] pass plus the rows' live pages, never an arena copy.
 That holds on the chip and is kept by a test:
 `tests/test_flash_compile_tpu.py::test_decode_steps_update_the_arena_in_place_on_v5e`
 compiles both steps for a described v5e and refuses an arena-sized
@@ -45,6 +51,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...kernels import pallas_supported
+from ...kernels.paged_attention import (paged_attention_supported,
+                                         paged_decode_attention)
 from ...telemetry.compile_watch import watch_compiles
 from ...telemetry.runtime import span as _span
 from ...telemetry.tracing import named_step, tracer as _tracer
@@ -52,7 +61,7 @@ from ..registry import ServingError, _abstract_sig
 from .cache import BlockPool, KvCacheSpec, make_cache, pack_kv, unpack_kv
 
 __all__ = ["DecodeEngine", "build_prefill_fn", "build_decode_fn",
-           "split_decode_layers"]
+           "split_decode_layers", "tick_attention"]
 
 
 def split_decode_layers(model):
@@ -169,19 +178,45 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec):
     return named_step("prefill", prefill)
 
 
-def build_decode_fn(model, snapshot, spec: KvCacheSpec):
-    """Pure one-token decode tick (see module docstring)."""
+def tick_attention(spec: KvCacheSpec) -> str:
+    """How a tick attends over this cache, from what the code can see:
+    "paged_kernel" where the backend is the TPU (`pallas_supported`: and
+    the kernels are not switched off) and the arena is float32 in pages
+    of whole (8, 128) tiles, else "gather" (the view through the tables
+    and `decode_attend`)."""
+    if (pallas_supported() and spec.kv_dtype == "fp32"
+            and paged_attention_supported(spec.n_heads * spec.d_head,
+                                          spec.block_len)):
+        return "paged_kernel"
+    return "gather"
+
+
+def build_decode_fn(model, snapshot, spec: KvCacheSpec,
+                    attention: Optional[str] = None):
+    """Pure one-token decode tick (see module docstring). `attention` is
+    `tick_attention(spec)` unless given: "paged_kernel" is the compiled
+    kernel, whatever the process's default backend (a test compiles it
+    for a described chip)."""
     emb, blocks, head = split_decode_layers(model)
+    attention = attention or tick_attention(spec)
+    if attention not in ("paged_kernel", "gather"):
+        raise ValueError(f"attention must be paged_kernel|gather, got "
+                         f"{attention!r}")
 
     def layer_step(layer):
         def step(p, x, kv, sc, channel, blk, off, tables, positions, lengths):
             q, k, v = layer.decode_qkv(p, x)
             kv, sc = _scatter(spec, kv, sc, k[:, 0], blk, off, channel)
             kv, sc = _scatter(spec, kv, sc, v[:, 0], blk, off, channel + 1)
-            k_all = _gather(spec, kv, sc, tables, channel)
-            v_all = _gather(spec, kv, sc, tables, channel + 1)
-            a = layer.decode_attend(q, k_all, v_all, positions[:, None],
-                                    lengths)
+            if attention == "paged_kernel":
+                a = paged_decode_attention(
+                    q.reshape(q.shape[0], -1), kv, channel, tables, lengths,
+                    n_heads=spec.n_heads, interpret=False)
+            else:
+                k_all = _gather(spec, kv, sc, tables, channel)
+                v_all = _gather(spec, kv, sc, tables, channel + 1)
+                a = layer.decode_attend(q, k_all, v_all, positions[:, None],
+                                        lengths)
             return layer.decode_finish(p, x, a), kv, sc
         return step
 
@@ -253,6 +288,7 @@ class DecodeEngine:
             n_layers=len(blocks), n_heads=heads, d_head=d // heads,
             block_len=int(block_len), num_blocks=int(num_blocks),
             max_context=max_context, kv_dtype=kv_dtype)
+        self.attention = tick_attention(self.spec)
         self.prompt_buckets = (tuple(sorted(int(b) for b in prompt_buckets))
                                if prompt_buckets else
                                _pow2_buckets(min(8, max_context),
@@ -299,16 +335,19 @@ class DecodeEngine:
                 "generation cache geometry; re-enable generation")
         return v
 
-    def _compile(self, v, build_fn, phase: str, bucket: int, *arg_specs):
+    def _compile(self, v, build_fn, phase: str, bucket: int, *arg_specs,
+                 **options):
         """Lower and compile one step over the abstract donated cache, and
         leave the record that says whether the arena is updated in place:
         the span-log instant `dl4j/engine/executable`, once per
         executable built (`temp_bytes` beside `arena_bytes`: a program
         that converts or copies the arena holds a temporary of its size;
-        `alias_bytes` is what the donation gave back)."""
+        `alias_bytes` is what the donation gave back). `options` go to
+        the builder and into the record: a tick's `attention`."""
         spec = self.spec
         step = watch_compiles(
-            jax.jit(build_fn(v.model, v.snapshot, spec), donate_argnums=(1,)),
+            jax.jit(build_fn(v.model, v.snapshot, spec, **options),
+                    donate_argnums=(1,)),
             f"serving/decode:{self.name}/{phase}-{bucket}").__wrapped__
         compiled = step.lower(v.snapshot.data, _cache_arg_specs(spec),
                               *arg_specs).compile()
@@ -317,7 +356,7 @@ class DecodeEngine:
             "dl4j/engine/executable", model=self.name, phase=phase,
             bucket=bucket, arena_bytes=spec.arena_nbytes(),
             temp_bytes=getattr(mem, "temp_size_in_bytes", None),
-            alias_bytes=getattr(mem, "alias_size_in_bytes", None))
+            alias_bytes=getattr(mem, "alias_size_in_bytes", None), **options)
         return compiled
 
     def prefill_exec(self, v, t_bucket: int):
@@ -335,7 +374,8 @@ class DecodeEngine:
         return self.registry.compile_cached(
             self.name, ("decode", sig, "tick", bucket),
             lambda: self._compile(v, build_decode_fn, "tick", bucket,
-                                  _i32(bucket), _i32(bucket), _i32(bucket, w)),
+                                  _i32(bucket), _i32(bucket), _i32(bucket, w),
+                                  attention=self.attention),
             f"decode-b{bucket}")
 
     # -- host-facing phases ----------------------------------------------
@@ -393,6 +433,11 @@ class DecodeEngine:
             pos[:rows] = np.asarray(positions, np.int32)
             for i, t in enumerate(tables):
                 tab[i] = self._pad_table(t)
+            # the pages the rows' lengths span, of those the tables name:
+            # what the paged kernel reads, of what the gather path reads
+            prepare.set(
+                pages_live=int((pos // self.spec.block_len + 1).sum()),
+                pages_table=tab.size)
             exec_ = self.decode_exec(v, bucket)
         with _span("dl4j/engine/tick.dispatch") as dispatch:
             pool.cache, logits = exec_(

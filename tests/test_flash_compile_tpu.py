@@ -1,10 +1,12 @@
 """The flash kernels compiled for a TPU v5e that is described, not attached:
 the chip's own compiler (Mosaic, libtpu) takes each tiling the chooser
 derives at real widths, so a slice off the (8, 128) tiling or a step over
-the VMEM limit fails here and not on the chip. The decode plane's two
-steps are compiled the same way over an abstract, donated paged cache, and
-held to updating it in place. Nothing runs: no result and no time comes
-from this file.
+the VMEM limit fails here and not on the chip. So does the tick's paged
+attention kernel at the served cell's shape, in every tick bucket. The
+decode plane's two steps are compiled the same way over an abstract,
+donated paged cache, and held to updating it in place: the tick on both of
+its attention paths. Nothing runs: no result and no time comes from this
+file.
 
 All of these tests live in this one file, and the topology is described
 inside a fixture, because only one process at a time may load the TPU's
@@ -59,6 +61,30 @@ def test_flash_kernels_compile_for_v5e(one_chip, B, T, S, Dh, dtype, causal,
         assert lowered.compile() is not None
 
 
+@pytest.mark.parametrize("rows", [1, 2, 4, 8, 16])
+@pytest.mark.parametrize("heads", [16, 12], ids=["HDh1024", "HDh768"])
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, rows, heads):
+    """The tick's kernel at the served cell's cache geometry (1,025 blocks
+    of 16 slots, tables 64 wide, 24 layers) for each tick bucket's row
+    count; the arena is abstract and is not copied: the program holds no
+    temporary."""
+    from deeplearning4j_tpu.kernels.paged_attention import (
+        paged_decode_attention)
+
+    arg = lambda dtype, *s: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    width = heads * 64
+    step = jax.jit(lambda q, kv, c, tables, lengths: paged_decode_attention(
+        q, kv, c, tables, lengths, n_heads=heads, interpret=False))
+    with jax.enable_x64(False):
+        lowered = step.lower(
+            arg(jnp.float32, rows, width),
+            arg(jnp.float32, 48, 1025, 16, width), arg(jnp.int32),
+            arg(jnp.int32, rows, 64), arg(jnp.int32, rows))
+        assert "paged_decode_attention" in lowered.as_text()
+        compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.fixture(scope="module")
 def decode_stack():
     """(model, snapshot, spec) at the served cell's cache geometry: width
@@ -86,9 +112,12 @@ def decode_stack():
     return model, _snapshot_params(model, "fp32"), spec
 
 
-@pytest.mark.parametrize("phase,bucket", [("tick", 16), ("prefill", 512)])
+@pytest.mark.parametrize("phase,bucket,attention", [
+    ("tick", 16, "gather"), ("tick", 16, "paged_kernel"),
+    ("prefill", 512, None)])
 def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
-                                                       phase, bucket):
+                                                       phase, bucket,
+                                                       attention):
     """The paged cache is donated and written by scatters, so a step may
     hold no temporary of the arena's size: the device keeps the arena
     row-major as it arrives (a last dimension of H*Dh is whole lane tiles),
@@ -97,9 +126,15 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
     replaced, the device kept `num_blocks` minor and both steps converted
     the arena on entry and back on exit: the tick held 1.94 times the arena
     in temporaries at the served model's 24 blocks (6.55 GiB beside 3.375).
-    What stays, whatever the depth, is the tick's gathered view of one
-    layer (16 rows x 1,024 slots x 1,024 floats, 64 MiB, four of them
-    live): a twelfth of a 24-deep arena, a sixth of this one."""
+    What stays on the tick's gather path, whatever the depth, is the
+    gathered view of one layer (16 rows x 1,024 slots x 1,024 floats,
+    64 MiB, four of them live): a twelfth of a 24-deep arena, a sixth of
+    this one. The paged kernel reads the arena where it lies: no view, no
+    head-split relayout, under 32 MiB of temporaries, and the kernel's 16
+    page operands do not make XLA copy the arena it is about to scatter
+    into again."""
+    import functools
+
     from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
                                                           build_decode_fn,
                                                           build_prefill_fn)
@@ -111,7 +146,8 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
     w = spec.table_width
     if phase == "tick":
-        fn, args = build_decode_fn, (i32(bucket), i32(bucket), i32(bucket, w))
+        fn = functools.partial(build_decode_fn, attention=attention)
+        args = (i32(bucket), i32(bucket), i32(bucket, w))
     else:
         fn, args = build_prefill_fn, (i32(1, bucket), i32(1), i32(1, w))
     with jax.enable_x64(False):
@@ -134,6 +170,15 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
         r"= (\w+)\[([\d,]*)\]\S* copy\(", text)
         if _nbytes(m.group(1), m.group(2)) >= arena / (2 * spec.n_layers)]
     assert not big, [m.group(0) for m in big]
+    kernels = re.findall(r"%paged_decode_attention[.\d]* = \S+ custom-call\(",
+                         text)
+    assert len(kernels) == (spec.n_layers if attention == "paged_kernel"
+                            else 0)
+    if attention == "paged_kernel":
+        assert mem.temp_size_in_bytes < 32 << 20
+        # neither the gathered view nor its heads split out
+        assert "f32[1024,16,1024]" not in text
+        assert "[16,1024,16,64]" not in text
 
 
 def _nbytes(dtype: str, dims: str) -> int:

@@ -1,0 +1,297 @@
+"""Paged decode attention (ISSUE 33): the tick's kernel against its oracle.
+
+The kernel (`kernels/paged_attention.py`) reads K/V pages from the arena
+through the block table; the oracle is the decode plane's plain path, the
+view `_gather` makes through the same table and `TransformerBlock.
+decode_attend` (`attention_reference` head by head) over it. Here the kernel
+runs through the Pallas interpreter; `tests/test_flash_compile_tpu.py`
+compiles it for a described v5e.
+
+  * allclose to the oracle over ragged lengths, the boundaries of a page
+    and of a grid step's chunk, a pad row, full context, one row and
+    sixteen, 12 and 16 heads of 64, and a table whose dead slots name a
+    block of NaN (a dead page must never be read), any pages a chunk;
+  * BIT-exact: a row's result is the same alone and among fifteen others;
+  * the engine picks the kernel only where it can run (`tick_attention`),
+    says which path an executable took, and its tick through the kernel
+    agrees with its tick through the view.
+"""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu import (Adam, EmbeddingSequenceLayer, InputType,
+                                MultiLayerNetwork, NeuralNetConfiguration,
+                                RnnOutputLayer, TransformerBlock, telemetry)
+from deeplearning4j_tpu.kernels import paged_attention as paged_mod
+from deeplearning4j_tpu.kernels.paged_attention import (
+    paged_attention_supported, paged_decode_attention, paged_plan)
+from deeplearning4j_tpu.serving.decode import engine as engine_mod
+from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
+from deeplearning4j_tpu.serving.decode.engine import (DecodeEngine, _gather,
+                                                      tick_attention)
+from deeplearning4j_tpu.serving.registry import ModelRegistry
+
+BL, W = 16, 64          # the served cell's pages and table width
+
+
+def _paged(lengths, heads, seed=0, dead_block=0, layers=2):
+    """(q, arena, tables, lengths) for rows of the given lengths: every
+    row's live pages are blocks of its own, drawn in a shuffled order; the
+    dead table slots name `dead_block`. Block 0 is the trash block."""
+    r = np.random.default_rng(seed)
+    width = heads * 64
+    pages = [-(-n // BL) for n in lengths]
+    num_blocks = 2 + sum(pages)
+    kv = r.normal(size=(2 * layers, num_blocks, BL, width)).astype(np.float32)
+    tables = np.full((len(lengths), W), dead_block, np.int32)
+    ids = 2 + r.permutation(sum(pages))         # block 1 is kept for NaN
+    for row, n in enumerate(pages):
+        tables[row, :n], ids = ids[:n], ids[n:]
+    q = r.normal(size=(len(lengths), width)).astype(np.float32)
+    return (jnp.asarray(q), jnp.asarray(kv), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _oracle(q, kv, channel, tables, lengths, heads):
+    """The decode plane's plain path: the gathered view, heads split out,
+    `attention_reference` with the tick's causal offsets and lengths."""
+    width = q.shape[1]
+    spec = KvCacheSpec(n_layers=kv.shape[0] // 2, n_heads=heads,
+                       d_head=width // heads, block_len=kv.shape[2],
+                       num_blocks=kv.shape[1],
+                       max_context=tables.shape[1] * kv.shape[2])
+    k_all = _gather(spec, kv, None, tables, channel)
+    v_all = _gather(spec, kv, None, tables, channel + 1)
+    out = TransformerBlock(n_heads=heads).decode_attend(
+        q.reshape(q.shape[0], 1, heads, -1), k_all, v_all,
+        (lengths - 1)[:, None], lengths)
+    return np.asarray(out).reshape(q.shape)
+
+
+RAGGED = [167, 880, 1, 422, 512, 513, 96, 1008, 300, 33, 640, 255, 129, 784,
+          16, 471]
+
+
+@pytest.mark.parametrize("lengths,heads,channel", [
+    (RAGGED, 16, 0),                     # ragged over the 16 rows of a tick
+    (RAGGED[:8], 12, 2),                 # H*Dh 768
+    ([1], 16, 0),                        # bucket 1: a pad row at the trash block
+    ([1, 1, 5, 1], 16, 2),
+    ([32, 33], 16, 0),                   # on a page boundary, and one past it
+    ([16, 17, 15], 12, 2),
+    ([128, 129, 127], 16, 0),            # on a chunk's boundary (8 pages)
+    ([256, 384, 257], 12, 0),
+    ([1024], 16, 2),                     # full context, one row
+    ([1024, 1023, 1009], 12, 2),         # full context, H*Dh 768
+    ([700], 12, 0),                      # bucket 1, H*Dh 768
+    ([64, 200], 8, 0),                   # 8 heads: no head is padded
+], ids=lambda v: None if isinstance(v, int) else
+    "x".join(map(str, v[:3])) + ("+" if len(v) > 3 else ""))
+def test_paged_kernel_matches_the_gathered_view(lengths, heads, channel):
+    q, kv, tables, lens = _paged(lengths, heads, layers=1 + channel // 2)
+    got = np.asarray(paged_decode_attention(
+        q, kv, jnp.int32(channel), tables, lens, n_heads=heads,
+        interpret=True))
+    want = _oracle(q, kv, channel, tables, lens, heads)
+    assert got.shape == want.shape == (len(lengths), heads * 64)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("lengths,heads", [
+    ([167, 1, 512, 513, 129, 1008], 16), ([40, 128, 1], 12)],
+    ids=["HDh1024", "HDh768"])
+def test_dead_pages_are_never_read(lengths, heads):
+    """Dead table slots name a block full of NaN, and so does every slot of
+    a row past its last live page: were one page of it read, its NaN would
+    reach the result through the weights-times-values product (0 x NaN).
+    The oracle reads the trash block there, and masks it."""
+    q, kv, tables, lens = _paged(lengths, heads, seed=1, dead_block=1)
+    kv = kv.at[:, 1].set(jnp.nan)
+    got = np.asarray(paged_decode_attention(
+        q, kv, jnp.int32(0), tables, lens, n_heads=heads, interpret=True))
+    assert np.isfinite(got).all()
+    live = jnp.arange(W)[None, :] * BL < lens[:, None]
+    want = _oracle(q, kv, 0, jnp.where(live, tables, 0), lens, heads)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+def test_a_row_is_bit_identical_alone_and_among_fifteen():
+    """Rows are independent: a row's chunks depend on its own length and
+    table only (the join/leave contract of the decode plane)."""
+    q, kv, tables, lens = _paged(RAGGED, 16, seed=2, layers=1)
+    run = functools.partial(paged_decode_attention, n_heads=16,
+                            interpret=True)
+    among = np.asarray(run(q, kv, jnp.int32(0), tables, lens))
+    for row in (0, 7, 15):
+        alone = np.asarray(run(q[row:row + 1], kv, jnp.int32(0),
+                               tables[row:row + 1], lens[row:row + 1]))
+        np.testing.assert_array_equal(alone[0], among[row])
+    # and whatever the neighbours hold: other lengths, other queries
+    q2 = q.at[1:].set(q[1:] * 3.0)
+    lens2 = lens.at[1:].set(jnp.minimum(lens[1:], 40))
+    np.testing.assert_array_equal(
+        np.asarray(run(q2, kv, jnp.int32(0), tables, lens2))[0], among[0])
+
+
+def test_plan_walks_a_row_in_chunks_of_128_slots():
+    plan = paged_plan(16, 64, 16, 16, 1024)
+    assert (plan.pages_a_chunk, plan.chunks_a_row, plan.steps_a_call) == (
+        8, 8, 16)
+    assert plan.heads_padded == 16 and plan.vmem_bytes < 16 << 20
+    assert paged_plan(16, 64, 16, 12, 768).heads_padded == 16
+    # pages of 128 slots: one a chunk; a table narrower than a chunk's pages
+    assert paged_plan(4, 8, 128, 8, 512).pages_a_chunk == 1
+    assert paged_plan(2, 3, 8, 8, 512)[:2] == (3, 1)
+    assert paged_attention_supported(1024, 16)
+    assert paged_attention_supported(768, 8)
+    assert not paged_attention_supported(64, 16)
+    assert not paged_attention_supported(1024, 4)
+
+
+@pytest.mark.parametrize("pages", [1, 3, 16])
+def test_the_result_does_not_depend_on_the_chunking(monkeypatch, pages):
+    """Any pages a chunk: one (a loop of 64), one that does not divide the
+    table (the last chunk's columns run past it), a quarter of the table."""
+    monkeypatch.setattr(paged_mod, "_CHUNK_TOKENS", pages * BL)
+    paged_mod._planned.cache_clear()
+    try:
+        q, kv, tables, lens = _paged([167, 1, 1024, 48], 16, layers=1)
+        assert paged_plan(4, W, BL, 16, 1024).pages_a_chunk == pages
+        got = np.asarray(paged_decode_attention(
+            q, kv, jnp.int32(0), tables, lens, n_heads=16, interpret=True))
+    finally:
+        paged_mod._planned.cache_clear()
+    np.testing.assert_allclose(got, _oracle(q, kv, 0, tables, lens, 16),
+                               rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# the call site: serving/decode/engine.py
+# ---------------------------------------------------------------------------
+
+def _spec(**kw):
+    base = dict(n_layers=2, n_heads=16, d_head=64, block_len=16,
+                num_blocks=9, max_context=64)
+    return KvCacheSpec(**{**base, **kw})
+
+
+@pytest.mark.parametrize("backend,kw,want", [
+    ("tpu", {}, "paged_kernel"),
+    ("tpu", {"n_heads": 12}, "paged_kernel"),           # H*Dh 768
+    ("tpu", {"kv_dtype": "int8"}, "gather"),
+    ("tpu", {"n_heads": 4, "d_head": 4}, "gather"),     # 16 lanes
+    ("tpu", {"n_heads": 3}, "gather"),                  # 192: no multiple of 128
+    ("tpu", {"block_len": 4}, "gather"),                # half a sublane tile
+    ("cpu", {}, "gather"),
+], ids=["fp32", "HDh768", "int8", "HDh16", "HDh192", "block4", "cpu"])
+def test_the_engine_picks_the_kernel_only_where_it_can_run(monkeypatch,
+                                                          backend, kw, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert tick_attention(_spec(**kw)) == want
+
+
+def test_the_kernels_kill_switch_takes_the_tick_to_the_view(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("DL4J_TPU_DISABLE_PALLAS", "1")
+    assert tick_attention(_spec()) == "gather"
+
+
+def _lm(width, heads, vocab=32, t=64, blocks=2, seed=5):
+    b = (NeuralNetConfiguration.builder().seed(seed).updater(Adam(1e-3))
+         .list().layer(EmbeddingSequenceLayer(n_in=vocab, n_out=width)))
+    for _ in range(blocks):
+        b = b.layer(TransformerBlock(n_heads=heads))
+    conf = (b.layer(RnnOutputLayer(n_out=vocab, activation="softmax",
+                                   loss="mcxent"))
+            .set_input_type(InputType.recurrent(1, t)).build())
+    return MultiLayerNetwork(conf).init()
+
+
+@pytest.mark.parametrize("width,kv_dtype", [(16, "fp32"), (128, "int8")],
+                         ids=["HDh16", "int8"])
+def test_executable_records_say_which_attention_a_tick_took(width, kv_dtype):
+    """`dl4j/engine/executable` of a tick carries `attention`; a prefill
+    attends over its local K/V and carries none."""
+    previous = telemetry.tracer()
+    telemetry.install_tracer(telemetry.Tracer())
+    try:
+        registry = ModelRegistry(buckets=(1,))
+        registry.register("gen", _lm(width, 2))
+        eng = DecodeEngine(registry, "gen", block_len=8, kv_dtype=kv_dtype,
+                           decode_buckets=(1,), prompt_buckets=(8,))
+        pool, v = eng.new_pool(), registry.get("gen")
+        blocks = pool.alloc(eng.spec.blocks_for(6))
+        eng.run_prefill(v, pool, [1, 2, 3], blocks)
+        eng.run_tick(v, pool, [4], [3], [blocks], bucket=1)
+        records = [e["attrs"] for e in telemetry.tracer().snapshot()
+                   if e["name"] == "dl4j/engine/executable"]
+    finally:
+        telemetry.install_tracer(previous)
+    assert [(r["phase"], r.get("attention")) for r in records] == [
+        ("prefill", None), ("tick", "gather")]
+    with pytest.raises(ValueError, match="paged_kernel|gather"):
+        engine_mod.build_decode_fn(v.model, v.snapshot, eng.spec,
+                                   attention="view")
+
+
+def test_the_tick_through_the_kernel_agrees_with_the_tick_through_the_view(
+        monkeypatch):
+    """The call site: the merged query, the layer's channel, the lengths and
+    the tables reach the kernel as the view's path reads them. Here the
+    kernel runs through the interpreter (the engine's own choice compiles
+    it, which only a TPU takes)."""
+    from deeplearning4j_tpu.serving.decode.cache import make_cache
+    from deeplearning4j_tpu.serving.decode.engine import (build_decode_fn,
+                                                          build_prefill_fn)
+    from deeplearning4j_tpu.serving.registry import _snapshot_params
+
+    monkeypatch.setattr(
+        engine_mod, "paged_decode_attention",
+        lambda *a, interpret, **kw: paged_decode_attention(
+            *a, interpret=True, **kw))
+    model = _lm(128, 2)
+    snapshot = _snapshot_params(model, "fp32")
+    spec = KvCacheSpec(n_layers=2, n_heads=2, d_head=64, block_len=8,
+                       num_blocks=17, max_context=64)
+    prefill = jax.jit(build_prefill_fn(model, snapshot, spec))
+    ticks = {a: jax.jit(build_decode_fn(model, snapshot, spec, attention=a))
+             for a in ("gather", "paged_kernel")}
+    r = np.random.default_rng(0)
+    prompts = [r.integers(0, 32, n).tolist() for n in (5, 16, 11)]
+    tables = np.zeros((4, spec.table_width), np.int32)     # row 3: a pad row
+    cache, free = make_cache(spec), iter(range(1, 17))
+    for row, prompt in enumerate(prompts):
+        n = spec.blocks_for(len(prompt) + 4)
+        tables[row, :n] = [next(free) for _ in range(n)]
+        tokens = np.zeros((1, 16), np.int32)
+        tokens[0, :len(prompt)] = prompt
+        cache, _ = prefill(snapshot.data, cache, jnp.asarray(tokens),
+                           jnp.asarray([len(prompt)], jnp.int32),
+                           jnp.asarray(tables[row:row + 1]))
+    caches = {a: cache for a in ticks}
+    positions = np.asarray([len(p) for p in prompts] + [0], np.int32)
+    tokens = np.asarray([3, 7, 11, 0], np.int32)
+    for _ in range(4):                       # row 1 crosses a page boundary
+        logits = {}
+        for a, tick in ticks.items():
+            caches[a], logits[a] = tick(
+                snapshot.data, caches[a], jnp.asarray(tokens),
+                jnp.asarray(positions), jnp.asarray(tables))
+        np.testing.assert_allclose(np.asarray(logits["paged_kernel"])[:3],
+                                   np.asarray(logits["gather"])[:3],
+                                   rtol=2e-5, atol=2e-6)
+        tokens = np.asarray(logits["gather"]).argmax(-1).astype(np.int32)
+        tokens[3] = 0
+        positions[:3] += 1
+    # what the ticks wrote: the first layer's K/V to the bit (they do not
+    # depend on any attention), the second's as close as the logits
+    written = {a: np.asarray(c["kv"]) for a, c in caches.items()}
+    np.testing.assert_array_equal(written["paged_kernel"][:2],
+                                  written["gather"][:2])
+    np.testing.assert_allclose(written["paged_kernel"], written["gather"],
+                               rtol=2e-5, atol=2e-6)

@@ -342,6 +342,63 @@ def test_every_executable_built_leaves_its_memory_record(log):
         assert r["temp_bytes"] >= 0 and r["alias_bytes"] >= 0
 
 
+def test_tick_prepare_counts_the_live_pages_beside_the_tables(log):
+    """`pages_live` / `pages_table` on `dl4j/engine/tick.prepare`: the pages
+    the rows' lengths span (what the paged kernel reads; a pad row spans
+    one) of the pages the tables name (what the gathered view reads)."""
+    registry = ModelRegistry(buckets=(1,))
+    registry.register("gen", _lm())
+    eng = DecodeEngine(registry, "gen", block_len=4, decode_buckets=(1, 4))
+    pool, v = eng.new_pool(), registry.get("gen")
+    tables = [pool.alloc(eng.spec.blocks_for(n)) for n in (10, 6)]
+    for prompt, table in zip(([1, 2, 3, 4, 5, 6, 7, 8, 9], [1, 2, 3, 4]),
+                             tables):
+        eng.run_prefill(v, pool, prompt, table)
+    eng.run_tick(v, pool, [4, 5], [9, 4], tables, bucket=4)
+    eng.run_tick(v, pool, [6], [5], tables[1:], bucket=1)
+    got = [s["attrs"] for s in _spans(log, "dl4j/engine/tick.prepare")]
+    width = eng.spec.table_width
+    assert width == 8
+    # positions 9 and 4 span 3 and 2 pages of 4 slots; two pad rows, 1 each
+    assert [(a["bucket"], a["pages_live"], a["pages_table"]) for a in got] == [
+        (4, 3 + 2 + 1 + 1, 4 * width), (1, 2, width)]
+
+
+def test_the_paged_kernel_leaves_its_record_once_per_call_shape(log):
+    """`dl4j/kernels/paged_attention`: written while a kernel is built,
+    never while one runs."""
+    import functools
+
+    from deeplearning4j_tpu.kernels import paged_attention as paged_mod
+
+    r = np.random.default_rng(0)
+    kv = jnp.asarray(r.normal(size=(4, 9, 8, 128)), jnp.float32)
+    q = jnp.asarray(r.normal(size=(2, 128)), jnp.float32)
+    tables = jnp.asarray([[3, 5, 7, 0, 0, 0], [2, 0, 0, 0, 0, 0]], jnp.int32)
+    lengths = jnp.asarray([20, 7], jnp.int32)
+    paged_mod._planned.cache_clear()
+    try:
+        run = jax.jit(functools.partial(paged_mod.paged_decode_attention,
+                                        n_heads=2, interpret=True))
+        for rows in (2, 2, 1):                  # a shape twice, then a new one
+            for channel in (0, 2):
+                run(q[:rows], kv, jnp.int32(channel), tables[:rows],
+                    lengths[:rows])
+        recs = [e for e in log.snapshot()
+                if e["name"] == "dl4j/kernels/paged_attention"]
+    finally:
+        paged_mod._planned.cache_clear()
+    assert [r["attrs"]["rows"] for r in recs] == [2, 1]
+    a = recs[0]["attrs"]
+    assert recs[0]["ph"] == "i"
+    assert (a["table_width"], a["block_len"], a["n_heads"], a["width"],
+            a["num_blocks"]) == (6, 8, 2, 128, 9)
+    plan = paged_mod.paged_plan(2, 6, 8, 2, 128)
+    assert (a["pages_a_chunk"], a["chunks_a_row"], a["steps_a_call"]) == (
+        6, 1, 2) == plan[:3]
+    assert a["vmem_bytes"] == plan.vmem_bytes
+
+
 # ---------------------------------------------------------------------------
 # fit
 # ---------------------------------------------------------------------------
