@@ -27,7 +27,8 @@ from .nn.layers import (ActivationLayer, AutoEncoder, BatchNormalization,
                         GlobalPoolingLayer, GravesBidirectionalLSTM,
                         GravesLSTM, LocalResponseNormalization,
                         LossFunctionWrapper, LossLayer, OutputLayer,
-                        PoolingType, RBM, RnnOutputLayer,
+                        PoolingType, RBM, RMSNormLayer, RnnOutputLayer,
+                        ShortcutMoEBlock, SparseExpertsLayer,
                         Subsampling1DLayer, SubsamplingLayer,
                         VariationalAutoencoder, ZeroPaddingLayer)
 from .nn.updaters import (AdaDelta, AdaGrad, Adam, AdaMax, Nesterovs, NoOp,
@@ -62,7 +63,8 @@ __all__ = [
     "GaussianReconstructionDistribution",
     "GlobalPoolingLayer", "GravesBidirectionalLSTM", "GravesLSTM",
     "LocalResponseNormalization", "LossFunctionWrapper", "LossLayer",
-    "OutputLayer", "PoolingType", "RBM", "RnnOutputLayer",
+    "OutputLayer", "PoolingType", "RBM", "RMSNormLayer", "RnnOutputLayer",
+    "ShortcutMoEBlock", "SparseExpertsLayer",
     "Subsampling1DLayer", "SubsamplingLayer", "VariationalAutoencoder",
     "ZeroPaddingLayer",
     "AdaDelta", "AdaGrad", "Adam", "AdaMax", "Nesterovs", "NoOp", "RmsProp",

@@ -19,6 +19,7 @@ from .generative import (AutoEncoder, RBM, VariationalAutoencoder,
                          LossFunctionWrapper)
 from .moe import MixtureOfExpertsLayer
 from .transformer import EmbeddingSequenceLayer, TransformerBlock
+from .shortcut_moe import RMSNormLayer, ShortcutMoEBlock, SparseExpertsLayer
 
 __all__ = [
     "DenseLayer", "OutputLayer", "LossLayer", "ActivationLayer",
@@ -34,4 +35,5 @@ __all__ = [
     "CompositeReconstructionDistribution", "LossFunctionWrapper",
     "MixtureOfExpertsLayer",
     "EmbeddingSequenceLayer", "TransformerBlock",
+    "RMSNormLayer", "ShortcutMoEBlock", "SparseExpertsLayer",
 ]

@@ -220,11 +220,15 @@ class TransformerBlock(LayerConf):
         return x, state
 
     # -- decode mode (KV-cache generation, serving/decode) -----------------
-    # The autoregressive serving plane splits the block into three traced
-    # pieces so the PAGED cache scatter/gather can happen between them
-    # (the layer owns the math, the decode engine owns the block tables):
+    # The decode plane takes its layers by contract (`serving/decode/
+    # engine.py` states it): `decode_cache` says what the block writes for
+    # a token, `decode_attention` which attention path a phase takes over
+    # a given cache, and `decode_prefill_step` / `decode_tick_step` build
+    # the traced steps. The steps are made of three pieces, so that the
+    # PAGED cache scatter/gather can happen between them (the layer owns
+    # the math, the engine's `CacheIO` the arena and the block tables):
     #   q, k, v = blk.decode_qkv(p, x)        # LN1 + projections
-    #   <engine scatters k/v into its arena, gathers the cache view>
+    #   <scatter k/v into the arena, gather the cache view>
     #   a = blk.decode_attend(q, k_all, v_all, positions, lengths)
     #     (a tick on the TPU: kernels.paged_attention reads the arena's
     #      pages through the block table instead of the two lines above)
@@ -232,6 +236,70 @@ class TransformerBlock(LayerConf):
     # Chaining the three over a full causal prompt (k_all = k, v_all = v,
     # positions = arange) is mathematically `apply` — the prefill+decode
     # equivalence suite asserts it against the full-sequence forward.
+    def decode_cache(self, width: int):
+        """(channels, width) written for a token: its keys and its values,
+        heads merged (`serving/decode/cache.py` says why merged)."""
+        return 2, self.n_model or int(width)
+
+    def decode_attention(self, phase: str, spec):
+        """How `phase` attends over a cache of `spec`, from what the code
+        can see. A prefill attends over its local K/V (None: nothing to
+        choose). A tick: "paged_kernel" where the backend is the TPU
+        (`pallas_supported`: and the kernels are not switched off) and the
+        arena is float32 in pages of whole (8, 128) tiles, else "gather"
+        (the view through the tables and `decode_attend`)."""
+        from ...kernels import pallas_supported
+        from ...kernels.paged_attention import paged_attention_supported
+
+        if phase != "tick":
+            return None
+        if (pallas_supported() and spec.kv_dtype == "fp32"
+                and paged_attention_supported(spec.width, spec.block_len)):
+            return "paged_kernel"
+        return "gather"
+
+    def decode_prefill_step(self, io, attention=None):
+        """The prefill step over the whole right-padded prompt. The prompt
+        attends over the LOCAL (exact) projections, so an int8 cache only
+        affects later ticks."""
+        def step(p, x, kv, sc, channel, blk, off, pos, lengths):
+            q, k, v = self.decode_qkv(p, x)
+            kv, sc = io.scatter(kv, sc, k, blk, off, channel)
+            kv, sc = io.scatter(kv, sc, v, blk, off, channel + 1)
+            a = self.decode_attend(q, k, v, pos, lengths)
+            return self.decode_finish(p, x, a), kv, sc, None
+        return step
+
+    def decode_tick_step(self, io, attention: str):
+        """The one-token tick; `attention` as `decode_attention` names it
+        ("paged_kernel" is always the COMPILED kernel)."""
+        from ...kernels import paged_attention as paged
+
+        if attention not in ("paged_kernel", "gather"):
+            raise ValueError(f"attention must be paged_kernel|gather, got "
+                             f"{attention!r}")
+
+        def view(kv, sc, tables, channel):
+            return io.gather(kv, sc, tables, channel).reshape(
+                tables.shape[0], -1, self.n_heads,
+                io.spec.width // self.n_heads)
+
+        def step(p, x, kv, sc, channel, blk, off, tables, positions, lengths):
+            q, k, v = self.decode_qkv(p, x)
+            kv, sc = io.scatter(kv, sc, k[:, 0], blk, off, channel)
+            kv, sc = io.scatter(kv, sc, v[:, 0], blk, off, channel + 1)
+            if attention == "paged_kernel":
+                a = paged.paged_decode_attention(
+                    q.reshape(q.shape[0], -1), kv, channel, tables, lengths,
+                    n_heads=self.n_heads, interpret=False)
+            else:
+                k_all = view(kv, sc, tables, channel)
+                v_all = view(kv, sc, tables, channel + 1)
+                a = self.decode_attend(q, k_all, v_all, positions[:, None],
+                                       lengths)
+            return self.decode_finish(p, x, a), kv, sc, None
+        return step
+
     def decode_qkv(self, params, x):
         """LN1 + QKV projection: x [B, T, D] -> q/k/v each [B, T, H, Dh]."""
         b, t, d = x.shape
@@ -288,6 +356,9 @@ class EmbeddingSequenceLayer(LayerConf):
     n_out: int = 0
     max_timesteps: Optional[int] = None   # positional table length
                                           # (default: input type timesteps)
+    positional: bool = True     # False: the token table alone (a stack
+                                # whose blocks rotate their own positions);
+                                # `max_timesteps` then STATES the context
 
     def output_type(self, it: InputType) -> InputType:
         return InputType.recurrent(self.n_out, it.timesteps)
@@ -308,12 +379,15 @@ class EmbeddingSequenceLayer(LayerConf):
             raise ValueError("EmbeddingSequenceLayer needs n_in (vocab) "
                              "and n_out (width)")
         tmax = self.max_timesteps or it.timesteps
-        if tmax is None:
+        if tmax is None and self.positional:
             raise ValueError(
                 "EmbeddingSequenceLayer needs max_timesteps (or an input "
                 "type with a fixed timestep count) for the positional "
                 "table")
         k1, k2 = jax.random.split(rng)
+        if not self.positional:
+            return {"W": self._winit(k1, (self.n_in, self.n_out),
+                                     self.n_in, self.n_out)}
         return {"W": self._winit(k1, (self.n_in, self.n_out),
                                  self.n_in, self.n_out),
                 "P": 0.02 * jax.random.normal(
@@ -325,8 +399,18 @@ class EmbeddingSequenceLayer(LayerConf):
             idx = idx[..., 0]
         idx = idx.astype(jnp.int32)
         z = jnp.take(params["W"], idx, axis=0)
+        if not self.positional:
+            return z, state
         t = z.shape[1]
         return z + params["P"][:t][None], state
+
+    def decode_context(self, params):
+        """The positions a served sequence may hold: the positional
+        table's rows, or what `max_timesteps` states where there is no
+        table (None: the stack cannot be served)."""
+        if self.positional:
+            return int(params["P"].shape[0])
+        return self.max_timesteps
 
     def decode_embed(self, params, idx, positions):
         """Decode-mode lookup: token + position embedding at ARBITRARY
@@ -335,5 +419,7 @@ class EmbeddingSequenceLayer(LayerConf):
         [B, T, n_out]. `positions` must stay below the positional table
         length — the table bounds the decode plane's context window."""
         z = jnp.take(params["W"], idx.astype(jnp.int32), axis=0)
+        if not self.positional:
+            return z
         return z + jnp.take(params["P"], positions.astype(jnp.int32),
                             axis=0)

@@ -87,6 +87,19 @@ def _rescale_bias_updates(updates, scale):
             for k, v in updates.items()}
 
 
+def _fitting(layer, rng, it, given, i):
+    """`given` if its leaves have the shapes `layer.init_params` would
+    make (nothing is made to learn them), else ValueError."""
+    shapes = lambda tree: jax.tree_util.tree_map(lambda a: tuple(a.shape),
+                                                 tree)
+    want = shapes(jax.eval_shape(lambda r: layer.init_params(r, it), rng))
+    if shapes(given) != want:
+        raise ValueError(
+            f"init(params=...): layer {i} ({type(layer).__name__}) takes "
+            f"{want}, got {shapes(given)}")
+    return given
+
+
 class MultiLayerNetwork:
     # attrs a TrainingGuard snapshot/restore covers (fault/guard.py):
     # everything a training step mutates, so a restored snapshot is
@@ -118,8 +131,18 @@ class MultiLayerNetwork:
     # ------------------------------------------------------------------
     # Initialization
     # ------------------------------------------------------------------
-    def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
+    def init(self, seed: Optional[int] = None,
+             params=None) -> "MultiLayerNetwork":
+        """Make the parameters from the seed, or take `params` (one dict a
+        layer, as `init` would make them) where the caller has them: no
+        second set is then made, which a model that fills most of a chip
+        has no room for. Their shapes are held to what the layers would
+        have made; their dtypes are the caller's."""
         from . import activations as _acts
+        if params is not None and len(params) != len(self.layers):
+            raise ValueError(f"init(params=...) got {len(params)} entries "
+                             f"for {len(self.layers)} layers")
+        given = params
         for layer in self.layers:
             if layer.activation is not None:  # fail fast on bad names
                 _acts.get(layer.activation)
@@ -144,7 +167,10 @@ class MultiLayerNetwork:
                 from .conf.input_type import InputType
                 it = InputType.feed_forward(n_in or 0)
             self._input_types.append(it)
-            params.append(layer.init_params(layer_rngs[i], it))
+            if given is None:
+                params.append(layer.init_params(layer_rngs[i], it))
+            else:
+                params.append(_fitting(layer, layer_rngs[i], it, given[i], i))
             state.append(layer.init_state(it))
             it = layer.output_type(it)
 

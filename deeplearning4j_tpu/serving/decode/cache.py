@@ -6,7 +6,8 @@ keys and values:
 
     arena  [2*L, num_blocks, block_len, H*Dh]
 
-where channel ``2l`` is layer l's keys and ``2l+1`` its values. A
+where channel ``2l`` is layer l's keys and ``2l+1`` its values (the GPT
+shape; in general ``[channels, num_blocks, block_len, width]``, below). A
 sequence owns an ordered list of block ids (its BLOCK TABLE); cache slot
 ``t`` of a sequence lives at ``(table[t // block_len], t % block_len)``
 of every channel.
@@ -32,7 +33,7 @@ v5e and holds them to it.
 
 The compiled steps scatter new K/V by block index and read a sequence's
 cache through its table: the tick's kernel page by page where it can run
-(`engine.tick_attention`), else as a gathered view of the whole table —
+(`TransformerBlock.decode_attention`), else as a gathered view of the whole table —
 HBM is shared at block granularity, so thousands of sequences with
 wildly different lengths pack the arena with at most ``block_len - 1``
 wasted slots each, instead of every sequence reserving a max-context
@@ -43,6 +44,17 @@ overflow prompt positions write there and their reads are always masked
 by the per-row valid length (the paged kernel does not read it at all
 but for a pad row's one slot), so the compiled step needs no branches
 for dead rows. Allocation never hands out block 0.
+
+The arena is not bound to keys and values of heads: a layer says how many
+CHANNELS of what WIDTH it writes for a token (`engine.py`, the layers'
+contract), and the arena is ``[channels, num_blocks, block_len, width]``.
+A GPT block writes two (keys, values) of ``H*Dh``; a latent-attention
+block writes one a attention, the compressed latent beside the rotated
+shared key, zero-padded to whole 128-lane tiles (576 -> 640) so that the
+layout argument above holds for it too.
+
+``kv_dtype="bf16"`` stores what a bfloat16 model computes as it computes
+it: half the float32 arena, no scales.
 
 int8 KV (``kv_dtype="int8"``): the arena stores int8 plus a per-slot
 scale arena ``[2*L, num_blocks, block_len]`` — `serving/quantize.py`'s
@@ -61,7 +73,10 @@ from typing import Dict, List
 
 import jax.numpy as jnp
 
-__all__ = ["KvCacheSpec", "BlockPool", "OutOfBlocksError"]
+__all__ = ["KvCacheSpec", "CacheIO", "BlockPool", "OutOfBlocksError"]
+
+
+KV_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
 
 class OutOfBlocksError(RuntimeError):
@@ -74,18 +89,19 @@ class KvCacheSpec:
     """Static shape contract of one servable's paged cache. Part of the
     compiled signature: every decode executable is specialized to it."""
 
-    n_layers: int          # transformer blocks L (arena channels = 2L)
-    n_heads: int
-    d_head: int
+    channels: int          # what the stack's layers write for a token,
+    width: int             # summed: 2L of H*Dh for L GPT blocks
     block_len: int         # cache slots per block
     num_blocks: int        # arena height, INCLUDING the reserved block 0
-    max_context: int       # hard cap (the positional table length)
-    kv_dtype: str = "fp32"   # "fp32" | "int8"
+    max_context: int       # hard cap (what the embedding layer states)
+    kv_dtype: str = "fp32"   # "fp32" | "bf16" | "int8"
 
     def __post_init__(self):
-        if self.kv_dtype not in ("fp32", "int8"):
-            raise ValueError(f"kv_dtype must be fp32|int8, got "
+        if self.kv_dtype not in KV_DTYPES:
+            raise ValueError(f"kv_dtype must be fp32|bf16|int8, got "
                              f"{self.kv_dtype!r}")
+        if self.channels < 1 or self.width < 1:
+            raise ValueError("a paged cache needs channels and width >= 1")
         if self.num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved trash block)")
@@ -106,28 +122,28 @@ class KvCacheSpec:
         return -(-max(1, n_tokens) // self.block_len)
 
     def arena_nbytes(self) -> int:
-        slots = self.num_blocks * self.block_len * 2 * self.n_layers
-        per = self.n_heads * self.d_head
+        slots = self.num_blocks * self.block_len * self.channels
         if self.kv_dtype == "int8":
-            return slots * per + slots * 4      # int8 data + f32 scales
-        return slots * per * 4
+            return slots * self.width + slots * 4   # int8 data + f32 scales
+        return slots * self.width * jnp.dtype(KV_DTYPES[self.kv_dtype]).itemsize
 
 
 def make_cache(spec: KvCacheSpec) -> Dict[str, jnp.ndarray]:
     """Fresh zeroed cache pytree — ONE donated argument of the compiled
     steps. fp32: {"kv": arena}; int8 adds the per-slot scale arena."""
-    shape = (2 * spec.n_layers, spec.num_blocks, spec.block_len,
-             spec.n_heads * spec.d_head)
+    shape = (spec.channels, spec.num_blocks, spec.block_len, spec.width)
     if spec.kv_dtype == "int8":
         return {"kv": jnp.zeros(shape, jnp.int8),
                 "scale": jnp.ones(shape[:3], jnp.float32)}
-    return {"kv": jnp.zeros(shape, jnp.float32)}
+    return {"kv": jnp.zeros(shape, KV_DTYPES[spec.kv_dtype])}
 
 
 def pack_kv(spec: KvCacheSpec, x):
-    """Prepare K or V vectors [..., H*Dh] for a cache scatter. Returns
-    (values, scales_or_None): int8 quantizes per leading-index vector
-    (per-tensor symmetric over the trailing H*Dh)."""
+    """Prepare a channel's vectors [..., width] for a cache scatter.
+    Returns (values, scales_or_None): int8 quantizes per leading-index
+    vector (per-tensor symmetric over the trailing width), bf16 rounds."""
+    if spec.kv_dtype == "bf16":
+        return x.astype(jnp.bfloat16), None
     if spec.kv_dtype != "int8":
         return x, None
     absmax = jnp.max(jnp.abs(x), axis=-1)
@@ -137,10 +153,41 @@ def pack_kv(spec: KvCacheSpec, x):
 
 
 def unpack_kv(spec: KvCacheSpec, q, scale):
-    """Dequantize a gathered cache view (inverse of `pack_kv`)."""
+    """Dequantize a gathered cache view (inverse of `pack_kv`; a bf16
+    view stays bfloat16 and the layer that reads it decides)."""
     if spec.kv_dtype != "int8":
         return q
     return q.astype(jnp.float32) * scale[..., None]
+
+
+class CacheIO:
+    """What a layer's traced decode step is handed to reach the arena: it
+    owns the math, this the layout and the quantization. `kv`/`sc` are
+    the arena and its scale arena (None but for int8), threaded through
+    the step."""
+
+    def __init__(self, spec: KvCacheSpec):
+        self.spec = spec
+
+    def scatter(self, kv, sc, values, blk, off, channel):
+        """Write `values` (leading index shape == blk/off; what follows
+        it, a [H, Dh] or a [width], merged into the width) into the arena
+        at (channel, blk, off), quantizing for int8 caches."""
+        vals, scales = pack_kv(self.spec, values.reshape(*blk.shape, -1))
+        kv = kv.at[channel, blk, off].set(vals)
+        if scales is not None:
+            sc = sc.at[channel, blk, off].set(scales)
+        return kv, sc
+
+    def gather(self, kv, sc, tables, channel):
+        """One channel's view [B, W, block_len, width] through the rows'
+        tables, dequantized: every row reads its own blocks (dead table
+        slots point at the trash block; always length-masked). ONE gather
+        from the arena; only the view is reshaped, never the arena."""
+        view = kv[channel, tables]
+        if sc is not None:
+            view = unpack_kv(self.spec, view, sc[channel, tables])
+        return view
 
 
 class BlockPool:

@@ -23,11 +23,46 @@ same-architecture hot-swap reuses every decode executable.
     logits. The attention reads the arena through the block table inside
     one Pallas kernel (`kernels.paged_attention`: live pages only, all
     heads on the merged lanes, no view of the cache made) where
-    `tick_attention` finds the TPU, a float32 arena and pages of whole
+    the block's `decode_attention` finds the TPU, a float32 arena and pages of whole
     tiles. Elsewhere — the CPU, the int8 arena, odd widths — it gathers
     the row's whole table into a view and attends with causal offsets +
     per-row valid length (`kernels.attention` kv_length path): the
     kernel's oracle.
+
+  greedy(logits [B, V]) -> tokens [B]
+    The argmax of each row, on the device, compiled beside the bucket's
+    tick (`run_tick(..., greedy=True)`: a tick whose rows are all sampled
+    at temperature 0). The host then fetches B ids in place of `[B, V]`
+    float32 (2 to 3 MB), which the scheduler thread would read once,
+    row by row, for the same argmax: that read ran at one of two speeds
+    from tick to tick and from run to run (PERF.md section 6, PR 34).
+
+The layers' contract. The engine names no model: a stack can be served
+if its first layer embeds (`decode_embed(params, tokens, positions)`)
+and states its context (`decode_context(params)`: a positional table's
+rows, or what a table-free embedding states), its last layer has
+`preout` (logits before the activation), and every layer between
+answers
+    decode_cache(width)              -> (channels, width) it writes for
+                                        a token; (0, 0) for a layer that
+                                        keeps none (a norm)
+    decode_attention(phase, spec)    -> the name of the attention path
+                                        that phase takes over that cache,
+                                        or None where there is no choice
+    decode_prefill_step(io, attention), decode_tick_step(io, attention)
+                                     -> the traced step
+A step is `step(p, x, kv, sc, channel, blk, off, ...) -> (x, kv, sc,
+counts)`: `channel` is the first of the layer's channels, `blk`/`off`
+where each token is written (`io.scatter`; block 0 is the trash block,
+so a tick row whose `blk` is 0 is a pad row), `io.gather` the view of a
+channel through the tables; a prefill gets `pos, lengths` after them, a
+tick `tables, positions, lengths`. `counts` is None or a few int32 (an
+expert layer's picks): the executable returns them stacked, beside the
+logits, and the engine adds them to counters and to its `*.fetch`
+spans. The GPT block (`nn/layers/transformer.py`) writes K and V of
+`H*Dh`; the latent-attention block (`nn/layers/shortcut_moe.py`) one
+latent of 640 an attention, expanded into heads over a prompt and
+attended as it lies, with the up-projection absorbed, in a tick.
 
 The cache pytree is DONATED and laid out `[2L, num_blocks, block_len,
 H*Dh]` (`cache.py` says why), so the arena updates in place on device: a
@@ -51,36 +86,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...kernels import pallas_supported
-from ...kernels.paged_attention import (paged_attention_supported,
-                                         paged_decode_attention)
 from ...telemetry.compile_watch import watch_compiles
 from ...telemetry.runtime import span as _span
 from ...telemetry.tracing import named_step, tracer as _tracer
 from ..registry import ServingError, _abstract_sig
-from .cache import BlockPool, KvCacheSpec, make_cache, pack_kv, unpack_kv
+from .cache import BlockPool, CacheIO, KvCacheSpec, make_cache
 
 __all__ = ["DecodeEngine", "build_prefill_fn", "build_decode_fn",
-           "split_decode_layers", "tick_attention"]
+           "split_decode_layers", "cache_geometry"]
+
+
+_STEP_CONTRACT = ("decode_cache", "decode_attention", "decode_prefill_step",
+                  "decode_tick_step")
 
 
 def split_decode_layers(model):
     """(embedding, [blocks...], head) of a generate-capable stack, or
-    ServingError. The decode plane supports exactly the GPT shape:
-    EmbeddingSequenceLayer -> TransformerBlock* -> an output layer with
-    `preout` (logits before the softmax activation)."""
-    from ...nn.layers.transformer import (EmbeddingSequenceLayer,
-                                          TransformerBlock)
-
+    ServingError: a first layer that embeds and states its context,
+    layers that answer the step contract (module docstring), an output
+    layer with `preout`."""
     layers = getattr(model, "layers", None)
     if not layers or len(layers) < 3 \
-            or not isinstance(layers[0], EmbeddingSequenceLayer) \
-            or not all(isinstance(b, TransformerBlock)
-                       for b in layers[1:-1]) \
+            or not hasattr(layers[0], "decode_embed") \
+            or not all(hasattr(b, name) for b in layers[1:-1]
+                       for name in _STEP_CONTRACT) \
             or not hasattr(layers[-1], "preout"):
         raise ServingError(
-            "generation needs an EmbeddingSequenceLayer -> "
-            "TransformerBlock* -> output-layer stack; got "
+            "generation needs an embedding layer (decode_embed) -> layers "
+            "with decode steps (TransformerBlock, ShortcutMoEBlock, "
+            "RMSNormLayer) -> an output layer; got "
             f"{[type(l).__name__ for l in (layers or [])]}")
     if getattr(model.conf, "preprocessors", None):
         raise ServingError(
@@ -89,31 +123,53 @@ def split_decode_layers(model):
     return layers[0], list(layers[1:-1]), layers[-1]
 
 
+def cache_geometry(model):
+    """(channels, width, context) of a generate-capable stack, as its
+    layers state them, or ServingError: the channels summed over the
+    layers, one width for all that write, the embedding's context."""
+    emb, blocks, _ = split_decode_layers(model)
+    wrote = [blk.decode_cache(emb.n_out) for blk in blocks]
+    widths = {int(w) for c, w in wrote if c}
+    if len(widths) != 1:
+        raise ServingError(
+            f"the stack's layers write cache widths {sorted(widths)}: the "
+            "paged arena holds one")
+    context = emb.decode_context(model.params[0])
+    if not context:
+        raise ServingError(
+            "the embedding layer states no context (no positional table "
+            "and no max_timesteps)")
+    return sum(int(c) for c, _ in wrote), widths.pop(), int(context)
+
+
+def _layer_confs(model):
+    """The stack's layer configurations, names left out: what the compiled
+    steps close over beside the shapes (heads, top-k, rotary base, ...)."""
+    return [dataclasses.replace(layer, name=None) for layer in model.layers]
+
+
+def _first_channels(blocks, width):
+    """Each block's first cache channel: the channels before it."""
+    out, at = [], 0
+    for blk in blocks:
+        out.append(at)
+        at += blk.decode_cache(width)[0]
+    return out
+
+
+def _attention_of(blocks, phase, spec):
+    """The attention path of the stack's `phase`: what its caching layers
+    answer (they must agree; None where none has a choice)."""
+    names = {blk.decode_attention(phase, spec) for blk in blocks} - {None}
+    if len(names) > 1:
+        raise ServingError(f"layers disagree on the {phase}'s attention: "
+                           f"{sorted(names)}")
+    return names.pop() if names else None
+
+
 def _cache_arg_specs(spec: KvCacheSpec):
     """The cache pytree's shapes, with no arena made to learn them."""
     return jax.eval_shape(lambda: make_cache(spec))
-
-
-def _scatter(spec, kv, sc, values, blk, off, channel):
-    """Write K or V `values` [..., H, Dh] (leading index shape ==
-    blk/off) into the arena at (channel, blk, off), heads merged,
-    quantizing for int8 caches."""
-    vals, scales = pack_kv(spec, values.reshape(*values.shape[:-2], -1))
-    kv = kv.at[channel, blk, off].set(vals)
-    if scales is not None:
-        sc = sc.at[channel, blk, off].set(scales)
-    return kv, sc
-
-
-def _gather(spec, kv, sc, tables, channel):
-    """Sequence-major cache view [B, W*block_len, H, Dh] of one channel,
-    dequantized: every row reads its own blocks through its table (dead
-    table slots point at the trash block; always length-masked). Only
-    the gathered view is reshaped, never the arena."""
-    view = kv[channel, tables]                   # [B, W, bl, H*Dh]
-    if sc is not None:
-        view = unpack_kv(spec, view, sc[channel, tables])
-    return view.reshape(tables.shape[0], -1, spec.n_heads, spec.d_head)
 
 
 def _repack(cache, kv, sc):
@@ -136,23 +192,26 @@ def _shared_steps(blocks, make):
     return [steps[i] for i in first]
 
 
-def build_prefill_fn(model, snapshot, spec: KvCacheSpec):
+def _stack_counts(counts):
+    """The layers' counts that are not None, stacked [layers, n] (an
+    empty tuple where no layer counts: the executable then returns the
+    cache and the logits alone, as it always did)."""
+    counts = [c for c in counts if c is not None]
+    return (jnp.stack(counts).astype(jnp.int32),) if counts else ()
+
+
+def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
+                     attention: Optional[str] = None):
     """Pure prefill step (see module docstring). Closed over the layer
     configs and the snapshot's dequantization structure only — the flat
     `data` tuple stays a runtime argument, so re-quantized checkpoints
     share the executable (the stateless plane's convention)."""
     emb, blocks, head = split_decode_layers(model)
-
-    def layer_step(layer):
-        def step(p, x, kv, sc, channel, blk, off, pos, lengths):
-            q, k, v = layer.decode_qkv(p, x)
-            kv, sc = _scatter(spec, kv, sc, k, blk, off, channel)
-            kv, sc = _scatter(spec, kv, sc, v, blk, off, channel + 1)
-            a = layer.decode_attend(q, k, v, pos, lengths)
-            return layer.decode_finish(p, x, a), kv, sc
-        return step
-
-    steps = _shared_steps(blocks, layer_step)
+    attention = attention or _attention_of(blocks, "prefill", spec)
+    io = CacheIO(spec)
+    steps = _shared_steps(
+        blocks, lambda layer: layer.decode_prefill_step(io, attention))
+    first = _first_channels(blocks, emb.n_out)
 
     def prefill(data, cache, tokens, lengths, tables):
         params = snapshot.rebuild(data)
@@ -167,60 +226,33 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec):
         # overwritten wholesale — reuse is bit-identical to fresh
         blk = tables[:, tidx // spec.block_len]
         off = jnp.broadcast_to(tidx % spec.block_len, (b, tp))
+        counts = []
         for i, step in enumerate(steps):
-            x, kv, sc = step(params[1 + i], x, kv, sc, jnp.int32(2 * i),
-                             blk, off, pos, lengths)
+            x, kv, sc, n = step(params[1 + i], x, kv, sc,
+                                jnp.int32(first[i]), blk, off, pos, lengths)
+            counts.append(n)
         logits = head.preout(params[-1], {}, x)          # [B, Tp, V]
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return _repack(cache, kv, sc), last.astype(jnp.float32)
+        return (_repack(cache, kv, sc), last.astype(jnp.float32),
+                *_stack_counts(counts))
 
     return named_step("prefill", prefill)
-
-
-def tick_attention(spec: KvCacheSpec) -> str:
-    """How a tick attends over this cache, from what the code can see:
-    "paged_kernel" where the backend is the TPU (`pallas_supported`: and
-    the kernels are not switched off) and the arena is float32 in pages
-    of whole (8, 128) tiles, else "gather" (the view through the tables
-    and `decode_attend`)."""
-    if (pallas_supported() and spec.kv_dtype == "fp32"
-            and paged_attention_supported(spec.n_heads * spec.d_head,
-                                          spec.block_len)):
-        return "paged_kernel"
-    return "gather"
 
 
 def build_decode_fn(model, snapshot, spec: KvCacheSpec,
                     attention: Optional[str] = None):
     """Pure one-token decode tick (see module docstring). `attention` is
-    `tick_attention(spec)` unless given: "paged_kernel" is the compiled
-    kernel, whatever the process's default backend (a test compiles it
-    for a described chip)."""
+    what the stack's layers answer for a tick over `spec` unless given:
+    a GPT block's "paged_kernel" is the compiled kernel, whatever the
+    process's default backend (a test compiles it for a described
+    chip)."""
     emb, blocks, head = split_decode_layers(model)
-    attention = attention or tick_attention(spec)
-    if attention not in ("paged_kernel", "gather"):
-        raise ValueError(f"attention must be paged_kernel|gather, got "
-                         f"{attention!r}")
-
-    def layer_step(layer):
-        def step(p, x, kv, sc, channel, blk, off, tables, positions, lengths):
-            q, k, v = layer.decode_qkv(p, x)
-            kv, sc = _scatter(spec, kv, sc, k[:, 0], blk, off, channel)
-            kv, sc = _scatter(spec, kv, sc, v[:, 0], blk, off, channel + 1)
-            if attention == "paged_kernel":
-                a = paged_decode_attention(
-                    q.reshape(q.shape[0], -1), kv, channel, tables, lengths,
-                    n_heads=spec.n_heads, interpret=False)
-            else:
-                k_all = _gather(spec, kv, sc, tables, channel)
-                v_all = _gather(spec, kv, sc, tables, channel + 1)
-                a = layer.decode_attend(q, k_all, v_all, positions[:, None],
-                                        lengths)
-            return layer.decode_finish(p, x, a), kv, sc
-        return step
-
-    steps = _shared_steps(blocks, layer_step)
+    attention = attention or _attention_of(blocks, "tick", spec)
+    io = CacheIO(spec)
+    steps = _shared_steps(
+        blocks, lambda layer: layer.decode_tick_step(io, attention))
+    first = _first_channels(blocks, emb.n_out)
 
     def decode(data, cache, tokens, positions, tables):
         params = snapshot.rebuild(data)
@@ -230,17 +262,25 @@ def build_decode_fn(model, snapshot, spec: KvCacheSpec,
         kv, sc = cache["kv"], cache.get("scale")
         blk = tables[jnp.arange(b), positions // spec.block_len]
         off = positions % spec.block_len
+        counts = []
         for i, step in enumerate(steps):
-            x, kv, sc = step(params[1 + i], x, kv, sc, jnp.int32(2 * i),
-                             blk, off, tables, positions, lengths)
+            x, kv, sc, n = step(params[1 + i], x, kv, sc,
+                                jnp.int32(first[i]), blk, off, tables,
+                                positions, lengths)
+            counts.append(n)
         logits = head.preout(params[-1], {}, x)[:, 0]
-        return _repack(cache, kv, sc), logits.astype(jnp.float32)
+        return (_repack(cache, kv, sc), logits.astype(jnp.float32),
+                *_stack_counts(counts))
 
     return named_step("tick", decode)
 
 
 def _i32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _greedy_tokens(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _pow2_buckets(lo: int, hi: int) -> Tuple[int, ...]:
@@ -272,12 +312,7 @@ class DecodeEngine:
             raise ServingError(
                 f"{name}: servable holds no live model object — "
                 "generation needs the layer stack")
-        emb, blocks, head = split_decode_layers(v.model)
-        d = emb.n_out
-        heads = blocks[0].n_heads
-        if any(blk.n_heads != heads for blk in blocks):
-            raise ServingError(f"{name}: blocks disagree on n_heads")
-        max_context = int(np.asarray(v.model.params[0]["P"]).shape[0])
+        channels, width, max_context = cache_geometry(v.model)
         self.decode_buckets = tuple(sorted(int(b) for b in decode_buckets))
         if num_blocks is None:
             # default: full residency for a max-bucket batch of
@@ -285,10 +320,15 @@ class DecodeEngine:
             per_seq = -(-max_context // block_len)
             num_blocks = 1 + per_seq * self.decode_buckets[-1]
         self.spec = KvCacheSpec(
-            n_layers=len(blocks), n_heads=heads, d_head=d // heads,
+            channels=channels, width=width,
             block_len=int(block_len), num_blocks=int(num_blocks),
             max_context=max_context, kv_dtype=kv_dtype)
-        self.attention = tick_attention(self.spec)
+        blocks = split_decode_layers(v.model)[1]
+        self.attention = _attention_of(blocks, "tick", self.spec)
+        self.prefill_attention = _attention_of(blocks, "prefill", self.spec)
+        self._moe_picks = self._moe_pairs = None    # made with the first counts
+        self._greedy = {}       # (decode bucket, precision) -> compiled argmax
+        self._layers, self._checked = _layer_confs(v.model), None
         self.prompt_buckets = (tuple(sorted(int(b) for b in prompt_buckets))
                                if prompt_buckets else
                                _pow2_buckets(min(8, max_context),
@@ -296,7 +336,7 @@ class DecodeEngine:
         if self.prompt_buckets[-1] > max_context:
             raise ServingError(
                 f"{name}: prompt bucket {self.prompt_buckets[-1]} exceeds "
-                f"the positional table ({max_context})")
+                f"the context the model states ({max_context})")
 
     # -- geometry --------------------------------------------------------
     @property
@@ -325,14 +365,21 @@ class DecodeEngine:
     # -- AOT executables -------------------------------------------------
     def _check_version(self, v):
         # a hot-swap to a different architecture would silently change
-        # the cache geometry under live sequences — fail loudly instead
-        emb, blocks, _ = split_decode_layers(v.model)
-        if (len(blocks) != self.spec.n_layers
-                or blocks[0].n_heads != self.spec.n_heads
-                or emb.n_out != self.spec.n_heads * self.spec.d_head):
+        # the cache geometry under live sequences, and one of the same
+        # shapes but other layer options (heads, top-k) would run
+        # executables closed over the old ones: they are keyed by shapes
+        # and dtypes alone — fail loudly instead
+        if v is self._checked:          # the version the last call held
+            return v
+        spec = self.spec
+        if cache_geometry(v.model) != (spec.channels, spec.width,
+                                       spec.max_context) \
+                or _layer_confs(v.model) != self._layers:
             raise ServingError(
                 f"{self.name}: swapped architecture no longer matches the "
-                "generation cache geometry; re-enable generation")
+                "generation cache geometry and the layers its executables "
+                "were built for; re-enable generation")
+        self._checked = v
         return v
 
     def _compile(self, v, build_fn, phase: str, bucket: int, *arg_specs,
@@ -342,9 +389,12 @@ class DecodeEngine:
         the span-log instant `dl4j/engine/executable`, once per
         executable built (`temp_bytes` beside `arena_bytes`: a program
         that converts or copies the arena holds a temporary of its size;
-        `alias_bytes` is what the donation gave back). `options` go to
-        the builder and into the record: a tick's `attention`."""
+        `alias_bytes` is what the donation gave back), with the cache's
+        `channels` and `width`. `options` go to the builder and into the
+        record: the phase's `attention`, where its layers have a choice
+        (`paged_kernel` / `gather`, `mla_absorbed` / `mla_expanded`)."""
         spec = self.spec
+        options = {k: o for k, o in options.items() if o is not None}
         step = watch_compiles(
             jax.jit(build_fn(v.model, v.snapshot, spec, **options),
                     donate_argnums=(1,)),
@@ -354,7 +404,8 @@ class DecodeEngine:
         mem = compiled.memory_analysis()
         _tracer().instant(
             "dl4j/engine/executable", model=self.name, phase=phase,
-            bucket=bucket, arena_bytes=spec.arena_nbytes(),
+            bucket=bucket, channels=spec.channels, width=spec.width,
+            arena_bytes=spec.arena_nbytes(),
             temp_bytes=getattr(mem, "temp_size_in_bytes", None),
             alias_bytes=getattr(mem, "alias_size_in_bytes", None), **options)
         return compiled
@@ -365,20 +416,56 @@ class DecodeEngine:
         return self.registry.compile_cached(
             self.name, ("decode", sig, "prefill", t_bucket),
             lambda: self._compile(v, build_prefill_fn, "prefill", t_bucket,
-                                  _i32(1, t_bucket), _i32(1), _i32(1, w)),
+                                  _i32(1, t_bucket), _i32(1), _i32(1, w),
+                                  attention=self.prefill_attention),
             f"prefill-t{t_bucket}")
 
     def decode_exec(self, v, bucket: int):
         sig = _abstract_sig(v.snapshot, v.state, v.precision)
         w = self.spec.table_width
-        return self.registry.compile_cached(
+        tick = self.registry.compile_cached(
             self.name, ("decode", sig, "tick", bucket),
             lambda: self._compile(v, build_decode_fn, "tick", bucket,
                                   _i32(bucket), _i32(bucket), _i32(bucket, w),
                                   attention=self.attention),
             f"decode-b{bucket}")
+        if (bucket, v.precision) not in self._greedy:
+            # the rows' argmax over the tick's logits, made with the
+            # bucket's tick so that no greedy tick meets a compile
+            logits = tick.out_info[1]
+            self._greedy[bucket, v.precision] = watch_compiles(
+                jax.jit(_greedy_tokens),
+                f"serving/decode:{self.name}/greedy-{bucket}"
+            ).__wrapped__.lower(
+                jax.ShapeDtypeStruct(logits.shape, logits.dtype)).compile()
+        return tick
 
     # -- host-facing phases ----------------------------------------------
+    def _count_picks(self, fetch, phase: str, counts):
+        """An expert layer's counts, `[layers, 5]` int32 (picks of live
+        tokens, of them on identity experts, on experts held here, held
+        experts hit, the largest load of a held expert): onto the fetch
+        span (summed over the layers) and into the counters."""
+        n = np.asarray(counts[0], np.int64)
+        picks, identity, held, hit, load = (int(c) for c in n.sum(axis=0))
+        fetch.set(moe_layers=len(n), moe_picks=picks, moe_identity=identity,
+                  moe_held=held, moe_held_hit=hit, moe_held_load_max=load)
+        if self._moe_picks is None:
+            metrics = self.registry.metrics
+            self._moe_picks = metrics.counter(
+                "dl4j_moe_picks_total",
+                "router picks of live tokens, by where the expert is: an "
+                "identity expert, an expert held here, one absent (another "
+                "chip's)", labels=("model", "phase", "kind"))
+            self._moe_pairs = metrics.counter(
+                "dl4j_moe_held_pairs_total",
+                "(token, expert) pairs computed by the experts held here",
+                labels=("model", "phase"))
+        for kind, k in (("identity", identity), ("held", held),
+                        ("absent", picks - identity - held)):
+            self._moe_picks.inc(k, model=self.name, phase=phase, kind=kind)
+        self._moe_pairs.inc(held, model=self.name, phase=phase)
+
     def _pad_table(self, table: Sequence[int]) -> List[int]:
         w = self.spec.table_width
         if len(table) > w:
@@ -403,12 +490,14 @@ class DecodeEngine:
             tab = np.asarray([self._pad_table(table)], np.int32)
             exec_ = self.prefill_exec(v, tb)
         with _span("dl4j/engine/prefill.dispatch") as dispatch:
-            pool.cache, logits = exec_(
+            pool.cache, logits, *counts = exec_(
                 v.snapshot.data, pool.cache, jnp.asarray(tokens),
                 jnp.asarray([n], jnp.int32), jnp.asarray(tab))
         with _span("dl4j/engine/prefill.fetch") as fetch:
             out = np.asarray(logits)[0]
             fetch.set(bytes=out.nbytes)
+            if counts:
+                self._count_picks(fetch, "prefill", counts)
         if observe is not None:
             for sp in (prepare, dispatch, fetch):
                 observe(sp)
@@ -416,10 +505,12 @@ class DecodeEngine:
 
     def run_tick(self, v, pool: BlockPool, tokens: Sequence[int],
                  positions: Sequence[int], tables: Sequence[Sequence[int]],
-                 bucket: int, observe=None) -> np.ndarray:
+                 bucket: int, observe=None, greedy: bool = False
+                 ) -> np.ndarray:
         """One decode tick over `len(tokens)` live rows padded up to
         `bucket` (pad rows park at the trash block, length 1, and their
-        logits are discarded by the caller). Returns logits [rows, V].
+        logits are discarded by the caller). Returns logits [rows, V], or
+        with `greedy` the rows' argmax [rows] int32, taken on the device.
         Spans and `observe` as in `run_prefill`."""
         with _span("dl4j/engine/tick.prepare", bucket=bucket) as prepare:
             self._check_version(v)
@@ -440,12 +531,16 @@ class DecodeEngine:
                 pages_table=tab.size)
             exec_ = self.decode_exec(v, bucket)
         with _span("dl4j/engine/tick.dispatch") as dispatch:
-            pool.cache, logits = exec_(
+            pool.cache, logits, *counts = exec_(
                 v.snapshot.data, pool.cache, jnp.asarray(tok),
                 jnp.asarray(pos), jnp.asarray(tab))
+            if greedy:
+                logits = self._greedy[bucket, v.precision](logits)
         with _span("dl4j/engine/tick.fetch") as fetch:
             full = np.asarray(logits)
             fetch.set(bytes=full.nbytes)
+            if counts:
+                self._count_picks(fetch, "tick", counts)
         if observe is not None:
             for sp in (prepare, dispatch, fetch):
                 observe(sp)

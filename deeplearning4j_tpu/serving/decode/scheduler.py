@@ -216,8 +216,11 @@ class GenerationScheduler:
         seq.event.set()
 
     def _sample(self, seq: _Seq, logits: np.ndarray) -> int:
+        """The next token from the row's logits `[V]`; a tick whose rows
+        are all greedy hands in the token itself, a scalar: the engine
+        took the argmax on the device."""
         if seq.temperature <= 0.0:
-            return int(np.argmax(logits))
+            return int(logits if logits.ndim == 0 else np.argmax(logits))
         z = logits.astype(np.float64) / seq.temperature
         z -= z.max()
         p = np.exp(z)
@@ -413,7 +416,8 @@ class GenerationScheduler:
         logits = self.engine.run_tick(
             v, self.pool, [s.ctx[s.cached] for s in batch],
             [s.cached for s in batch], [s.blocks for s in batch], bucket,
-            observe=self._observe)
+            observe=self._observe,
+            greedy=all(s.temperature <= 0.0 for s in batch))
         dt = time.perf_counter() - t0
         self._ema.observe(bucket, dt)
         if self._phase_h is not None:

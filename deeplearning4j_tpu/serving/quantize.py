@@ -61,13 +61,9 @@ class QuantizedTree:
         return sum(1 for s in self.scales if s is not None)
 
     def nbytes(self) -> int:
-        total = 0
-        for d, s in zip(self.data, self.scales):
-            if s is not None:
-                total += np.asarray(d[0]).nbytes + np.asarray(d[1]).nbytes
-            else:
-                total += np.asarray(d).nbytes
-        return int(total)
+        """From the leaves' shapes: nothing is copied off the device."""
+        return int(sum(leaf.size * leaf.dtype.itemsize
+                       for leaf in jax.tree_util.tree_leaves(self.data)))
 
     def rebuild(self, data):
         """Dequantize a flat `data` tuple back into the original pytree —

@@ -221,6 +221,51 @@ def test_temperature_sampling_seeded(served):
     assert all(0 <= t < VOCAB for t in a["tokens"])
 
 
+@pytest.mark.parametrize("rows", [1, 2])
+def test_greedy_tick_returns_the_argmax_of_the_logits_tick(served, rows):
+    """`run_tick(greedy=True)` hands back the rows' argmax, taken on the
+    device by the executable built beside the bucket's tick: the same
+    tokens as np.argmax over the logits of the same tick (exact: the
+    comparison of float32 values rounds nothing)."""
+    reg, model, sched = served
+    eng, v = sched.engine, reg.get("gen")
+    prompts = [[5, 11, 2, 29, 7], [1, 2, 3]][:rows]
+
+    def run(greedy):
+        pool = eng.new_pool()
+        tables = [pool.alloc(eng.spec.blocks_for(len(p) + 1))
+                  for p in prompts]
+        first = [int(np.argmax(eng.run_prefill(v, pool, p, t)))
+                 for p, t in zip(prompts, tables)]
+        return eng.run_tick(v, pool, first, [len(p) for p in prompts],
+                            tables, bucket=2, greedy=greedy)
+
+    logits, tokens = run(False), run(True)
+    assert logits.shape == (rows, VOCAB)
+    assert tokens.shape == (rows,) and tokens.dtype == np.int32
+    np.testing.assert_array_equal(tokens, logits.argmax(axis=1))
+
+
+def test_greedy_row_beside_a_sampled_row_stays_exact(served):
+    """A tick with one row at a temperature goes by the logits for every
+    row: the greedy neighbour still gets its single-sequence answer."""
+    reg, model, sched = served
+    prompt, got = [3, 7, 1, 4, 9, 2], {}
+
+    def client(name, **kw):
+        got[name] = sched.submit(prompt, max_tokens=8, timeout=300, **kw)
+
+    threads = [threading.Thread(target=client, args=("greedy",)),
+               threading.Thread(target=client, args=("sampled",),
+                                kwargs=dict(temperature=0.9, seed=3))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert got["greedy"]["tokens"] == eager_greedy(model, prompt, 8)
+    assert all(0 <= t < VOCAB for t in got["sampled"]["tokens"])
+
+
 # ---------------------------------------------------------------------------
 # bit-exactness: isolation + block reuse
 # ---------------------------------------------------------------------------
@@ -288,7 +333,7 @@ def test_kv_block_reuse_after_release_bitexact(served):
         np.testing.assert_array_equal(a, b)
     kv1 = np.asarray(pool1.cache["kv"])[:, reused]    # [2L, blocks, bl, H*Dh]
     kv2 = np.asarray(pool2.cache["kv"])[:, fresh]
-    assert kv1.shape[:2] == (2 * eng.spec.n_layers, len(reused))
+    assert kv1.shape[:2] == (eng.spec.channels, len(reused))
     assert np.abs(kv1).max() > 0
     np.testing.assert_array_equal(kv1, kv2)
 
@@ -297,18 +342,19 @@ def test_kv_block_reuse_after_release_bitexact(served):
                          ids=["HDh32", "HDh128"])
 @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
 def test_cache_scatter_gather_round_trip(kv_dtype, heads, d_head):
-    """What `_scatter` writes through a block table, `_gather` reads back
+    """What `CacheIO.scatter` writes through a block table, `gather` reads back
     through it — heads merged on the way in and split on the way out —
     and nothing else in the arena moves: other channels and unowned
     blocks stay zero, and every dead table slot (a prompt's overflow, a
     pad row) lands in and reads from the trash block."""
-    from deeplearning4j_tpu.serving.decode.cache import (KvCacheSpec,
+    from deeplearning4j_tpu.serving.decode.cache import (CacheIO,
+                                                         KvCacheSpec,
                                                          make_cache,
                                                          pack_kv, unpack_kv)
-    from deeplearning4j_tpu.serving.decode.engine import _gather, _scatter
 
-    spec = KvCacheSpec(n_layers=2, n_heads=heads, d_head=d_head, block_len=4,
+    spec = KvCacheSpec(channels=4, width=heads * d_head, block_len=4,
                        num_blocks=6, max_context=12, kv_dtype=kv_dtype)
+    io = CacheIO(spec)
     cache = make_cache(spec)
     assert cache["kv"].shape == (4, 6, 4, heads * d_head)
     # row 0 owns blocks 3 and 5 (8 slots), its third table slot is dead;
@@ -319,8 +365,9 @@ def test_cache_scatter_gather_round_trip(kv_dtype, heads, d_head):
     off = jnp.broadcast_to(tidx % spec.block_len, (2, 12))
     x = jnp.asarray(np.random.default_rng(0).normal(
         size=(2, 12, heads, d_head)), jnp.float32)
-    kv, sc = _scatter(spec, cache["kv"], cache.get("scale"), x, blk, off, 1)
-    view = np.asarray(_gather(spec, kv, sc, tables, 1))
+    kv, sc = io.scatter(cache["kv"], cache.get("scale"), x, blk, off, 1)
+    view = np.asarray(io.gather(kv, sc, tables, 1)).reshape(
+        2, 12, heads, d_head)
     assert view.shape == (2, 12, heads, d_head)
 
     q, scale = pack_kv(spec, x.reshape(2, 12, -1))
@@ -424,7 +471,7 @@ def test_identical_blocks_are_traced_and_lowered_once(phase):
             .set_input_type(InputType.recurrent(1, TMAX)).build())
     model = MultiLayerNetwork(conf).init()
     snapshot = _snapshot_params(model, "fp32")
-    spec = KvCacheSpec(n_layers=4, n_heads=4, d_head=WIDTH // 4, block_len=4,
+    spec = KvCacheSpec(channels=8, width=WIDTH, block_len=4,
                        num_blocks=9, max_context=TMAX)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     w = spec.table_width
@@ -454,8 +501,8 @@ def test_int8_kv_cache_generates():
         spec = sched.engine.spec
         kv, scale = sched.pool.cache["kv"], sched.pool.cache["scale"]
         assert kv.dtype == jnp.int8
-        assert kv.shape == (2 * spec.n_layers, spec.num_blocks,
-                            spec.block_len, spec.n_heads * spec.d_head)
+        assert kv.shape == (spec.channels, spec.num_blocks,
+                            spec.block_len, spec.width)
         assert scale.shape == kv.shape[:3]
         res = sched.submit([3, 7, 1, 4], max_tokens=6, timeout=300)
         assert res["generated_tokens"] == 6

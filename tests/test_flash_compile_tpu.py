@@ -107,7 +107,7 @@ def decode_stack():
                                    loss="mcxent"))
             .set_input_type(InputType.recurrent(1, 1024)).build())
     model = MultiLayerNetwork(conf).init()
-    spec = KvCacheSpec(n_layers=depth, n_heads=16, d_head=64, block_len=16,
+    spec = KvCacheSpec(channels=2 * depth, width=1024, block_len=16,
                        num_blocks=1025, max_context=1024)
     return model, _snapshot_params(model, "fp32"), spec
 
@@ -159,8 +159,7 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
     assert mem.temp_size_in_bytes < arena / 4
     assert mem.alias_size_in_bytes >= arena
     text = compiled.as_text()
-    dims = (2 * spec.n_layers, spec.num_blocks, spec.block_len,
-            spec.n_heads * spec.d_head)
+    dims = (spec.channels, spec.num_blocks, spec.block_len, spec.width)
     shape = "f32[%d,%d,%d,%d]" % dims
     # row-major on entry (and so, aliased, on exit): {3,2,1,0}
     layout = text[text.index("entry_computation_layout="):].split("\n")[0]
@@ -168,11 +167,11 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
     assert shape + "{" not in layout.replace(shape + "{3,2,1,0:", "")
     big = [m for m in re.finditer(
         r"= (\w+)\[([\d,]*)\]\S* copy\(", text)
-        if _nbytes(m.group(1), m.group(2)) >= arena / (2 * spec.n_layers)]
+        if _nbytes(m.group(1), m.group(2)) >= arena / spec.channels]
     assert not big, [m.group(0) for m in big]
     kernels = re.findall(r"%paged_decode_attention[.\d]* = \S+ custom-call\(",
                          text)
-    assert len(kernels) == (spec.n_layers if attention == "paged_kernel"
+    assert len(kernels) == (spec.channels // 2 if attention == "paged_kernel"
                             else 0)
     if attention == "paged_kernel":
         assert mem.temp_size_in_bytes < 32 << 20

@@ -2,7 +2,7 @@
 
 The kernel (`kernels/paged_attention.py`) reads K/V pages from the arena
 through the block table; the oracle is the decode plane's plain path, the
-view `_gather` makes through the same table and `TransformerBlock.
+view `CacheIO.gather` makes through the same table and `TransformerBlock.
 decode_attend` (`attention_reference` head by head) over it. Here the kernel
 runs through the Pallas interpreter; `tests/test_flash_compile_tpu.py`
 compiles it for a described v5e.
@@ -12,7 +12,7 @@ compiles it for a described v5e.
     sixteen, 12 and 16 heads of 64, and a table whose dead slots name a
     block of NaN (a dead page must never be read), any pages a chunk;
   * BIT-exact: a row's result is the same alone and among fifteen others;
-  * the engine picks the kernel only where it can run (`tick_attention`),
+  * the engine picks the kernel only where it can run (`TransformerBlock.decode_attention`),
     says which path an executable took, and its tick through the kernel
     agrees with its tick through the view.
 """
@@ -31,9 +31,8 @@ from deeplearning4j_tpu.kernels import paged_attention as paged_mod
 from deeplearning4j_tpu.kernels.paged_attention import (
     paged_attention_supported, paged_decode_attention, paged_plan)
 from deeplearning4j_tpu.serving.decode import engine as engine_mod
-from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
-from deeplearning4j_tpu.serving.decode.engine import (DecodeEngine, _gather,
-                                                      tick_attention)
+from deeplearning4j_tpu.serving.decode.cache import CacheIO, KvCacheSpec
+from deeplearning4j_tpu.serving.decode.engine import DecodeEngine
 from deeplearning4j_tpu.serving.registry import ModelRegistry
 
 BL, W = 16, 64          # the served cell's pages and table width
@@ -61,12 +60,12 @@ def _oracle(q, kv, channel, tables, lengths, heads):
     """The decode plane's plain path: the gathered view, heads split out,
     `attention_reference` with the tick's causal offsets and lengths."""
     width = q.shape[1]
-    spec = KvCacheSpec(n_layers=kv.shape[0] // 2, n_heads=heads,
-                       d_head=width // heads, block_len=kv.shape[2],
-                       num_blocks=kv.shape[1],
+    spec = KvCacheSpec(channels=kv.shape[0], width=width,
+                       block_len=kv.shape[2], num_blocks=kv.shape[1],
                        max_context=tables.shape[1] * kv.shape[2])
-    k_all = _gather(spec, kv, None, tables, channel)
-    v_all = _gather(spec, kv, None, tables, channel + 1)
+    view = lambda c: CacheIO(spec).gather(kv, None, tables, c).reshape(
+        q.shape[0], -1, heads, width // heads)
+    k_all, v_all = view(channel), view(channel + 1)
     out = TransformerBlock(n_heads=heads).decode_attend(
         q.reshape(q.shape[0], 1, heads, -1), k_all, v_all,
         (lengths - 1)[:, None], lengths)
@@ -175,17 +174,21 @@ def test_the_result_does_not_depend_on_the_chunking(monkeypatch, pages):
 # ---------------------------------------------------------------------------
 
 def _spec(**kw):
-    base = dict(n_layers=2, n_heads=16, d_head=64, block_len=16,
-                num_blocks=9, max_context=64)
+    base = dict(channels=4, width=1024, block_len=16, num_blocks=9,
+                max_context=64)
     return KvCacheSpec(**{**base, **kw})
+
+
+def tick_attention(spec):
+    return TransformerBlock(n_heads=2).decode_attention("tick", spec)
 
 
 @pytest.mark.parametrize("backend,kw,want", [
     ("tpu", {}, "paged_kernel"),
-    ("tpu", {"n_heads": 12}, "paged_kernel"),           # H*Dh 768
+    ("tpu", {"width": 768}, "paged_kernel"),            # H*Dh 768
     ("tpu", {"kv_dtype": "int8"}, "gather"),
-    ("tpu", {"n_heads": 4, "d_head": 4}, "gather"),     # 16 lanes
-    ("tpu", {"n_heads": 3}, "gather"),                  # 192: no multiple of 128
+    ("tpu", {"width": 16}, "gather"),                   # 16 lanes
+    ("tpu", {"width": 192}, "gather"),                  # 192: no multiple of 128
     ("tpu", {"block_len": 4}, "gather"),                # half a sublane tile
     ("cpu", {}, "gather"),
 ], ids=["fp32", "HDh768", "int8", "HDh16", "HDh192", "block4", "cpu"])
@@ -251,12 +254,12 @@ def test_the_tick_through_the_kernel_agrees_with_the_tick_through_the_view(
     from deeplearning4j_tpu.serving.registry import _snapshot_params
 
     monkeypatch.setattr(
-        engine_mod, "paged_decode_attention",
+        paged_mod, "paged_decode_attention",
         lambda *a, interpret, **kw: paged_decode_attention(
             *a, interpret=True, **kw))
     model = _lm(128, 2)
     snapshot = _snapshot_params(model, "fp32")
-    spec = KvCacheSpec(n_layers=2, n_heads=2, d_head=64, block_len=8,
+    spec = KvCacheSpec(channels=4, width=128, block_len=8,
                        num_blocks=17, max_context=64)
     prefill = jax.jit(build_prefill_fn(model, snapshot, spec))
     ticks = {a: jax.jit(build_decode_fn(model, snapshot, spec, attention=a))

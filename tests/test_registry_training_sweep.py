@@ -56,7 +56,8 @@ def _case(name):
         DropoutLayer, EmbeddingLayer, EmbeddingSequenceLayer,
         GlobalPoolingLayer, GravesBidirectionalLSTM, GravesLSTM,
         LastTimeStep, LocalResponseNormalization, LossLayer,
-        MixtureOfExpertsLayer, RnnOutputLayer, Subsampling1DLayer,
+        MixtureOfExpertsLayer, RMSNormLayer, RnnOutputLayer,
+        ShortcutMoEBlock, SparseExpertsLayer, Subsampling1DLayer,
         SubsamplingLayer, TransformerBlock, VariationalAutoencoder,
         ZeroPaddingLayer)
     from deeplearning4j_tpu.nn.layers import RBM
@@ -137,6 +138,19 @@ def _case(name):
              GlobalPoolingLayer(), head], conv, cx),
         "TransformerBlock": lambda: (
             [TransformerBlock(n_heads=2), rnn_head],
+            InputType.recurrent(8, 6), _rnn_data(f=8)),
+        "RMSNormLayer": lambda: (
+            [DenseLayer(n_out=8, activation="tanh"), RMSNormLayer(), head],
+            ff, fx),
+        "SparseExpertsLayer": lambda: (
+            [SparseExpertsLayer(n_experts=4, n_identity=2, top_k=2,
+                                expert_hidden=8, held_experts=[0, 2]),
+             rnn_head], InputType.recurrent(8, 6), _rnn_data(f=8)),
+        "ShortcutMoEBlock": lambda: (
+            [ShortcutMoEBlock(n_heads=2, q_rank=8, kv_rank=4, qk_nope=4,
+                              qk_rope=2, v_head=4, ffn_hidden=16,
+                              n_experts=4, n_identity=2, top_k=2,
+                              expert_hidden=8), rnn_head],
             InputType.recurrent(8, 6), _rnn_data(f=8)),
         "EmbeddingSequenceLayer": lambda: (
             [EmbeddingSequenceLayer(n_in=20, n_out=8), rnn_head],
