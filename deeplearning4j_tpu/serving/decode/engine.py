@@ -6,7 +6,11 @@ registry's shared executable cache (`ModelRegistry.compile_cached`, keys
 namespaced ("decode", sig, phase, bucket)) so the server-lifetime
 invariant of the stateless plane extends to generation: ONE XLA compile
 per (model, bucket, phase), no cold compile on any request path, and a
-same-architecture hot-swap reuses every decode executable.
+same-architecture hot-swap reuses every decode executable. The `sig` of
+those keys is the version's own (`ServableVersion.sig`, which the
+registry computed when it built the version): the per-call path walks
+neither the model nor the weights, it meets a version once
+(`DecodeEngine._check_version`) and knows it by identity afterwards.
 
   prefill(data, cache, tokens [1, Tp], lengths [1], tables [1, W])
       -> (cache', next_logits [1, V])
@@ -80,6 +84,7 @@ stay — the continuous-batching isolation contract the tests assert.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -299,7 +304,13 @@ class DecodeEngine:
     executables live in the registry's per-model cache so swaps and the
     compile accounting behave exactly like the stateless runners. The
     scheduler calls `run_prefill` / `run_tick` with host data; both only
-    ever invoke finished executables."""
+    ever invoke finished executables.
+
+    What depends on the version alone is resolved once a version, in
+    `_check_version`: that its layers are the ones the executables were
+    built for, and the signature that keys them, which the version
+    states (`ServableVersion.sig`). A call then keeps what depends on
+    its rows: host arrays, two dictionary hits, three uploads."""
 
     def __init__(self, registry, name: str, *, block_len: int = 16,
                  num_blocks: Optional[int] = None, kv_dtype: str = "fp32",
@@ -328,7 +339,8 @@ class DecodeEngine:
         self.prefill_attention = _attention_of(blocks, "prefill", self.spec)
         self._moe_picks = self._moe_pairs = None    # made with the first counts
         self._greedy = {}       # (decode bucket, precision) -> compiled argmax
-        self._layers, self._checked = _layer_confs(v.model), None
+        self._layers = _layer_confs(v.model)
+        self._checked = self._sig = None    # the last version met, its sig
         self.prompt_buckets = (tuple(sorted(int(b) for b in prompt_buckets))
                                if prompt_buckets else
                                _pow2_buckets(min(8, max_context),
@@ -364,12 +376,18 @@ class DecodeEngine:
 
     # -- AOT executables -------------------------------------------------
     def _check_version(self, v):
+        """`v`, once it is known to fit this engine's executables; the
+        version the last call held is known by identity. A new one is met
+        once: its layers are compared, its signature taken (the version's
+        own; computed here, once, for a version that states none), and
+        the span log gets the instant `dl4j/engine/version` (`leaves`,
+        `computed`, and `ms` where it was)."""
         # a hot-swap to a different architecture would silently change
         # the cache geometry under live sequences, and one of the same
         # shapes but other layer options (heads, top-k) would run
         # executables closed over the old ones: they are keyed by shapes
         # and dtypes alone — fail loudly instead
-        if v is self._checked:          # the version the last call held
+        if v is self._checked:
             return v
         spec = self.spec
         if cache_geometry(v.model) != (spec.channels, spec.width,
@@ -379,7 +397,17 @@ class DecodeEngine:
                 f"{self.name}: swapped architecture no longer matches the "
                 "generation cache geometry and the layers its executables "
                 "were built for; re-enable generation")
-        self._checked = v
+        sig = getattr(v, "sig", None)
+        record = {"computed": int(sig is None)}
+        if sig is None:
+            t0 = time.perf_counter()
+            sig = _abstract_sig(v.snapshot, v.state, v.precision)
+            record["ms"] = round((time.perf_counter() - t0) * 1e3, 3)
+        _tracer().instant(
+            "dl4j/engine/version", model=self.name,
+            version=getattr(v, "version", None), leaves=len(v.snapshot.data),
+            **record)
+        self._sig, self._checked = sig, v
         return v
 
     def _compile(self, v, build_fn, phase: str, bucket: int, *arg_specs,
@@ -411,20 +439,28 @@ class DecodeEngine:
         return compiled
 
     def prefill_exec(self, v, t_bucket: int):
-        sig = _abstract_sig(v.snapshot, v.state, v.precision)
+        """The prompt bucket's executable for `v`: a dictionary hit in the
+        registry's cache, keyed by the signature `v` states (`v.sig`, as
+        `_check_version` took it the one time it met `v`), a compile the
+        first time a signature asks. Nothing here walks the model or the
+        weights: this runs in every prefill."""
+        self._check_version(v)
         w = self.spec.table_width
         return self.registry.compile_cached(
-            self.name, ("decode", sig, "prefill", t_bucket),
+            self.name, ("decode", self._sig, "prefill", t_bucket),
             lambda: self._compile(v, build_prefill_fn, "prefill", t_bucket,
                                   _i32(1, t_bucket), _i32(1), _i32(1, w),
                                   attention=self.prefill_attention),
             f"prefill-t{t_bucket}")
 
     def decode_exec(self, v, bucket: int):
-        sig = _abstract_sig(v.snapshot, v.state, v.precision)
+        """The decode bucket's tick for `v`, found as `prefill_exec` finds
+        a prefill (it runs in every tick: two dictionary hits, no walk);
+        the bucket's argmax program is made beside it."""
+        self._check_version(v)
         w = self.spec.table_width
         tick = self.registry.compile_cached(
-            self.name, ("decode", sig, "tick", bucket),
+            self.name, ("decode", self._sig, "tick", bucket),
             lambda: self._compile(v, build_decode_fn, "tick", bucket,
                                   _i32(bucket), _i32(bucket), _i32(bucket, w),
                                   attention=self.attention),
@@ -481,7 +517,6 @@ class DecodeEngine:
         dispatch (uploads + enqueue), fetch (the wait for the device and
         the copy down); `observe(span)` sees each once it has closed."""
         with _span("dl4j/engine/prefill.prepare") as prepare:
-            self._check_version(v)
             n = len(prompt)
             tb = self.prompt_bucket_for(n)
             prepare.set(bucket=tb, tokens=n)
@@ -513,7 +548,6 @@ class DecodeEngine:
         with `greedy` the rows' argmax [rows] int32, taken on the device.
         Spans and `observe` as in `run_prefill`."""
         with _span("dl4j/engine/tick.prepare", bucket=bucket) as prepare:
-            self._check_version(v)
             rows = len(tokens)
             if rows > bucket:
                 raise ServingError(f"{rows} rows > decode bucket {bucket}")

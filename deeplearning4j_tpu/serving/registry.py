@@ -157,11 +157,11 @@ class ServableVersion:
     concurrent swap can never tear outputs or free buffers under them."""
 
     __slots__ = ("name", "version", "precision", "buckets", "example_shape",
-                 "snapshot", "state", "runners", "model_kind", "source",
-                 "created_at", "param_bytes", "model")
+                 "snapshot", "state", "sig", "runners", "model_kind",
+                 "source", "created_at", "param_bytes", "model")
 
     def __init__(self, name, precision, buckets, example_shape, snapshot,
-                 state, runners, model_kind, source, model=None):
+                 state, runners, model_kind, source, model=None, sig=None):
         self.name = name
         self.version = 0            # assigned at the atomic flip
         self.precision = precision
@@ -169,6 +169,11 @@ class ServableVersion:
         self.example_shape = example_shape
         self.snapshot = snapshot
         self.state = state
+        # `_abstract_sig` of the three above, as the registry computed it
+        # to key this version's executables: stated here so that nobody
+        # walks the leaves again for it (the decode plane keys a lookup
+        # with it on every tick); None on a version built without one
+        self.sig = sig
         self.runners = runners      # {bucket: compiled XLA executable}
         self.model_kind = model_kind
         self.source = source
@@ -638,7 +643,7 @@ class ModelRegistry:
                 del entry.compiled[key]
         return ServableVersion(name, precision, buckets, shape, snapshot,
                                state, runners, type(model).__name__, src,
-                               model=model)
+                               model=model, sig=sig)
 
     def compile_cached(self, name: str, key: tuple, build, label: str):
         """AOT-compile through `name`'s shared executable cache: return the

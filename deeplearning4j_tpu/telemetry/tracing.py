@@ -5,7 +5,11 @@ fit and scheduler threads, `TraceContext.emit` for per-request spans with
 explicit timestamps, instants (`xla/compile`, epoch marks) and counter
 samples. The ring holds `capacity` events and overwrites the OLDEST, so
 a server that has run for an hour still shows its last minutes;
-`dropped_events` counts what was overwritten.
+`dropped_events` counts what was overwritten. The default, 262,144, is
+what a traced benchmark run of a serving cell needs with room to spare:
+a scheduler at 100 loops a second writes some 1,000 events a second,
+83,000 from the first request to the readers' turn (chip run, PR 35: a
+ring of 65,536 had lost the window's start by then).
 
 A record is (seq, ph, name, t0, t1, tid, thread, id, parent, trace_id,
 attrs): `t0`/`t1` in `time.perf_counter_ns()`, `id` the span's own id,
@@ -97,7 +101,7 @@ class _Span:
 
 
 class Tracer:
-    def __init__(self, capacity: int = 65_536, enabled: bool = True,
+    def __init__(self, capacity: int = 262_144, enabled: bool = True,
                  process_name: str = "deeplearning4j_tpu"):
         self.capacity = max(1, int(capacity))
         self.enabled = bool(enabled)

@@ -49,11 +49,11 @@ pytestmark = pytest.mark.sanitize(
 VOCAB, WIDTH, TMAX = 32, 16, 32
 
 
-def lm(seed=0, vocab=VOCAB, width=WIDTH, t=TMAX, blocks=2):
+def lm(seed=0, vocab=VOCAB, width=WIDTH, t=TMAX, blocks=2, heads=4):
     b = (NeuralNetConfiguration.builder().seed(seed).updater(Adam(1e-3))
          .list().layer(EmbeddingSequenceLayer(n_in=vocab, n_out=width)))
     for _ in range(blocks):
-        b = b.layer(TransformerBlock(n_heads=4))
+        b = b.layer(TransformerBlock(n_heads=heads))
     conf = (b.layer(RnnOutputLayer(n_out=vocab, activation="softmax",
                                    loss="mcxent"))
             .set_input_type(InputType.recurrent(1, t)).build())
@@ -580,6 +580,154 @@ def test_registry_decode_and_fwd_cache_keys_disjoint():
     # only fwd keys — the planes can never evict each other
     reg.register("twin", lm(seed=9), buckets=(1,))
     assert all(k[0] == "fwd" for k in reg._entries["twin"].compiled)
+
+
+# ---------------------------------------------------------------------------
+# a version is resolved once (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def span_log():
+    """A fresh process-wide span log for one test."""
+    from deeplearning4j_tpu.telemetry import Tracer, install_tracer
+
+    log = Tracer()
+    prev = install_tracer(log)
+    yield log
+    install_tracer(prev)
+
+
+@pytest.fixture
+def sig_calls(monkeypatch):
+    """`_abstract_sig` counted, under both names it is called by."""
+    from deeplearning4j_tpu.serving import registry as registry_mod
+    from deeplearning4j_tpu.serving.decode import engine as engine_mod
+
+    calls, real = [], registry_mod._abstract_sig
+
+    def counting(snapshot, state, precision):
+        calls.append(len(snapshot.data))
+        return real(snapshot, state, precision)
+
+    monkeypatch.setattr(registry_mod, "_abstract_sig", counting)
+    monkeypatch.setattr(engine_mod, "_abstract_sig", counting)
+    return calls
+
+
+def _version_instants(log):
+    return [e["attrs"] for e in log.snapshot()
+            if e["ph"] == "i" and e["name"] == "dl4j/engine/version"]
+
+
+def _drive(eng, v, prompts=([5, 11, 2], [7, 3], [9, 1, 4, 6]), ticks=5):
+    """Three prefills and `ticks` ticks of all three rows on a fresh
+    arena, greedy on the host: the ticks' logits [ticks, 3, V]."""
+    pool = eng.new_pool()
+    tables = [pool.alloc(eng.spec.blocks_for(len(p) + ticks))
+              for p in prompts]
+    last = [int(np.argmax(eng.run_prefill(v, pool, p, t)))
+            for p, t in zip(prompts, tables)]
+    out = []
+    for i in range(ticks):
+        out.append(eng.run_tick(v, pool, last, [len(p) + i for p in prompts],
+                                tables, bucket=4))
+        last = [int(t) for t in np.argmax(out[-1], axis=-1)]
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("stated", [True, False],
+                         ids=["the-version-states-it", "a-stand-in-without"])
+def test_engine_walks_the_leaves_at_most_once_a_version(
+        sig_calls, span_log, stated):
+    """Five ticks and three prefills on one version: the signature that
+    keys their executables is the version's own, so the engine never
+    computes one; a version object built by hand, with no `sig`, is
+    served all the same, and walked once."""
+    import types
+
+    reg = ModelRegistry()
+    reg.register("gen", lm(seed=20), buckets=(1,))
+    real = reg.get("gen")
+    assert real.sig is not None and sig_calls    # the registry's own walk
+    v = real if stated else types.SimpleNamespace(
+        snapshot=real.snapshot, state=real.state, precision=real.precision,
+        model=real.model)
+    eng = DecodeEngine(reg, "gen", block_len=4, decode_buckets=(4,))
+    del sig_calls[:]
+    got = _drive(eng, v)
+    assert len(sig_calls) == (0 if stated else 1)
+    (record,) = _version_instants(span_log)
+    assert record["model"] == "gen"
+    assert record["leaves"] == len(real.snapshot.data) == 4 + 2 * 16
+    assert record["computed"] == (0 if stated else 1)
+    assert ("ms" in record) == (not stated)
+    assert record["version"] == (1 if stated else None)
+    if not stated:      # the same executables, the same weights
+        assert eng._sig == real.sig
+        np.testing.assert_array_equal(got, _drive(eng, real))
+        assert len(sig_calls) == 1 and len(_version_instants(span_log)) == 2
+
+
+def test_a_swap_of_the_same_architecture_compiles_nothing_and_runs_its_weights(
+        span_log):
+    """New weights under the old signature: the executables are reused
+    with no compile, the engine meets the version once more, and what
+    comes back is the NEW weights' (they are call arguments)."""
+    from deeplearning4j_tpu import telemetry
+
+    new = lm(seed=22)
+    with telemetry.enabled() as sess:
+        reg = ModelRegistry(metrics=sess.registry)
+        reg.register("gen", lm(seed=21), buckets=(1,))
+        eng = DecodeEngine(reg, "gen", block_len=4, decode_buckets=(4,))
+        before = _drive(eng, reg.get("gen"))
+        compiles = reg.metrics.counter("dl4j_serving_compiles_total",
+                                       labels=("model", "bucket"))
+        n_compiles = sum(compiles.values().values())
+        aot = dict(sess.compiles.report())
+        marks = len(span_log.snapshot())
+        v2 = reg.swap("gen", new, buckets=(1,))
+        after = _drive(eng, v2)
+        assert sum(compiles.values().values()) == n_compiles
+        assert dict(sess.compiles.report()) == aot
+        assert not [e for e in span_log.snapshot()[marks:]
+                    if e["name"] in ("xla/compile", "dl4j/engine/executable")]
+    assert v2.sig == eng._sig and v2.version == 2
+    assert [(r["version"], r["computed"])
+            for r in _version_instants(span_log)] == [(1, 0), (2, 0)]
+    assert np.abs(after - before).max() > 1e-3
+    fresh = ModelRegistry()
+    fresh.register("gen", new, buckets=(1,))
+    np.testing.assert_array_equal(after, _drive(
+        DecodeEngine(fresh, "gen", block_len=4, decode_buckets=(4,)),
+        fresh.get("gen")))
+
+
+@pytest.mark.parametrize("other,why", [
+    (dict(width=32), "cache geometry"),
+    (dict(heads=2), "layers its executables"),
+], ids=["another-geometry", "other-layer-options"])
+def test_a_swap_the_executables_do_not_fit_fails_every_call(other, why):
+    """A version of another geometry, or of the same shapes under other
+    layer options (two heads where there were four: the same signature),
+    is refused by prefills, ticks and lookups alike, each time it is
+    offered; the version the engine knows is served on."""
+    reg = ModelRegistry()
+    reg.register("gen", lm(seed=23), buckets=(1,))
+    eng = DecodeEngine(reg, "gen", block_len=4, decode_buckets=(4,))
+    v1 = reg.get("gen")
+    before = _drive(eng, v1)
+    v2 = reg.swap("gen", lm(seed=23, **other), buckets=(1,))
+    assert (v2.sig == v1.sig) == ("heads" in other)
+    pool = eng.new_pool()
+    table = pool.alloc(2)
+    for call in (lambda: eng.run_prefill(v2, pool, [1, 2, 3], table),
+                 lambda: eng.run_tick(v2, pool, [4], [3], [table], bucket=4),
+                 lambda: eng.prefill_exec(v2, 8),
+                 lambda: eng.decode_exec(v2, 4)):
+        with pytest.raises(ServingError, match=why):
+            call()
+    np.testing.assert_array_equal(before, _drive(eng, v1))
 
 
 def test_flush_ema_bucket_extrapolation():

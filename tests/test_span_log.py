@@ -302,6 +302,36 @@ def test_loops_and_idle_tile_the_scheduler_thread(generated):
     assert rows.sum(model="gen") == sum(t["attrs"]["rows"] for t in ticks)
 
 
+def test_a_version_is_met_once_in_a_run_of_many_ticks(generated):
+    """`dl4j/engine/version`: once per version object the engine meets,
+    inside the first call that brings it, never per call; the ticks'
+    `prepare` spans carry what they carried."""
+    log, _, _ = generated
+    prepares = _spans(log, "dl4j/engine/tick.prepare")
+    prefills = _spans(log, "dl4j/engine/prefill.prepare")
+    assert len(prepares) >= 8 and len(prefills) == 4
+    (met,) = [e for e in log.snapshot()
+              if e["ph"] == "i" and e["name"] == "dl4j/engine/version"]
+    assert met["attrs"] == {"model": "gen", "version": 1, "computed": 0,
+                            "leaves": 4 + 2 * 16}
+    assert met["parent"] == min(prefills, key=lambda s: s["t0"])["id"]
+    for p in prepares:
+        assert {"bucket", "pages_live", "pages_table"} <= set(p["attrs"])
+        assert 1 <= p["attrs"]["pages_live"] <= p["attrs"]["pages_table"]
+
+
+def test_the_default_ring_holds_a_traced_serving_run(generated):
+    """What the scheduler writes a loop (admissions at this run's high
+    share included), at 100 loops a second for the 90 s between a
+    benchmark run's first request and its readers' turn, fits the
+    default ring: the readers need the window's first tick."""
+    log, _, _ = generated
+    events, loops = log.snapshot(), _spans(log, "dl4j/sched/loop")
+    per_loop = len(events) / len(loops)
+    assert 6 <= per_loop <= 25, per_loop
+    assert per_loop * 100 * 90 <= Tracer().capacity
+
+
 def test_engine_spans_outside_the_scheduler_have_no_tick_parent(log):
     registry = ModelRegistry(buckets=(1,))
     registry.register("gen", _lm())
