@@ -25,7 +25,8 @@ from .nn.layers import (ActivationLayer, AutoEncoder, BatchNormalization,
                         EmbeddingSequenceLayer, TransformerBlock,
                         GaussianReconstructionDistribution,
                         GlobalPoolingLayer, GravesBidirectionalLSTM,
-                        GravesLSTM, LocalResponseNormalization,
+                        GravesLSTM, HybridSSMBlock,
+                        LocalResponseNormalization,
                         LossFunctionWrapper, LossLayer, OutputLayer,
                         PoolingType, RBM, RMSNormLayer, RnnOutputLayer,
                         ShortcutMoEBlock, SparseExpertsLayer,
@@ -62,7 +63,7 @@ __all__ = [
     "EmbeddingLayer", "EmbeddingSequenceLayer", "TransformerBlock",
     "GaussianReconstructionDistribution",
     "GlobalPoolingLayer", "GravesBidirectionalLSTM", "GravesLSTM",
-    "LocalResponseNormalization", "LossFunctionWrapper", "LossLayer",
+    "HybridSSMBlock", "LocalResponseNormalization", "LossFunctionWrapper", "LossLayer",
     "OutputLayer", "PoolingType", "RBM", "RMSNormLayer", "RnnOutputLayer",
     "ShortcutMoEBlock", "SparseExpertsLayer",
     "Subsampling1DLayer", "SubsamplingLayer", "VariationalAutoencoder",

@@ -33,10 +33,18 @@ in flight while this one is computed.
   V_chunk`, of which row h's own `Dh` lanes are the answer. No `[.., H, Dh]`
   array exists anywhere. The MXU columns this wastes do not show: the kernel
   is bound by HBM.
-- The arena stays as it is (float32). Products take float32 operands at the
-  default precision (on the TPU one bfloat16 pass, as XLA's matrix products
-  of this model) and accumulate in float32; the softmax statistics, `exp`
-  and the accumulator are float32.
+- The arena stays as it is, float32 or bfloat16. Products take their
+  operands in the arena's dtype (float32 at the default precision: on the
+  TPU one bfloat16 pass, as XLA's matrix products of these models) and
+  accumulate in float32; the softmax statistics, `exp` and the accumulator
+  are float32.
+- Grouped queries (`n_kv_heads` < `n_heads`: the arena holds `Hkv*Dh` lanes,
+  `n_heads / n_kv_heads` query heads read each key/value head): the same
+  loop over the same pages, the block-diagonal query `[H, Hkv*Dh]` with row
+  h on the lanes of key/value head `h // group`. XLA lays it out before the
+  call and takes each head's own lanes after it (`[B, H, Hkv*Dh]`, some
+  130 kB a row beside the row's megabytes of pages), so the kernel never
+  reshapes lanes into sublanes.
 - Rows are independent: a row's chunks depend on its own length and table
   only, so its result is the same whoever else is in the batch.
 
@@ -72,19 +80,24 @@ class PagedPlan(NamedTuple):
     vmem_bytes: int      # estimated: the page slots, the rows, the values
 
 
-def paged_attention_supported(width: int, block_len: int) -> bool:
-    """Whether the compiled kernel takes a (float32) arena of `width` = H*Dh
-    lanes and `block_len`-slot pages: whole (8, 128) tiles a page."""
-    return width % 128 == 0 and block_len % _SUBLANES == 0
+def paged_attention_supported(width: int, block_len: int,
+                              dtype="float32") -> bool:
+    """Whether the compiled kernel takes an arena of `width` = Hkv*Dh lanes
+    and `block_len`-slot pages of `dtype`: whole tiles a page, (8, 128) of
+    float32, (16, 128) of bfloat16."""
+    rows = {"float32": _SUBLANES, "bfloat16": 2 * _SUBLANES}.get(
+        jnp.dtype(dtype).name)
+    return rows is not None and width % 128 == 0 and block_len % rows == 0
 
 
 def paged_plan(rows: int, table_width: int, block_len: int, n_heads: int,
-               width: int) -> PagedPlan:
+               width: int, itemsize: int = 4) -> PagedPlan:
     """Pages a chunk from the shape: `_CHUNK_TOKENS` cache slots' worth, no
-    more than the table is wide."""
+    more than the table is wide. `width` is the arena's, `itemsize` its
+    dtype's."""
     pages = max(1, min(_CHUNK_TOKENS // block_len, table_width))
     hp = _round_up(n_heads, _SUBLANES)
-    chunk = pages * block_len * width * 4
+    chunk = pages * block_len * width * itemsize
     vmem = (2 * 2 * chunk                   # two slots each of K and V pages
             + 2 * 2 * rows * width * 4      # the queries and the output
             + 2 * chunk                     # a chunk's K and V as values
@@ -93,27 +106,35 @@ def paged_plan(rows: int, table_width: int, block_len: int, n_heads: int,
 
 
 @functools.lru_cache(maxsize=64)
-def _planned(rows, table_width, block_len, n_heads, width,
-             num_blocks) -> PagedPlan:
+def _planned(rows, table_width, block_len, n_heads, width, num_blocks,
+             n_kv_heads=None, dtype="float32") -> PagedPlan:
     """`paged_plan` for one call shape, worked out once a process; working
     it out leaves the record `dl4j/kernels/paged_attention` in the span
     log: written while a kernel is built, never while one runs."""
     from ..telemetry import tracer
 
-    plan = paged_plan(rows, table_width, block_len, n_heads, width)
+    plan = paged_plan(rows, table_width, block_len, n_heads, width,
+                      jnp.dtype(dtype).itemsize)
+    grouped = {} if n_kv_heads is None else {"n_kv_heads": n_kv_heads,
+                                             "dtype": dtype}
     tracer().instant("dl4j/kernels/paged_attention", rows=rows,
                      table_width=table_width, block_len=block_len,
                      n_heads=n_heads, width=width, num_blocks=num_blocks,
-                     **plan._asdict())
+                     **grouped, **plan._asdict())
     return plan
 
 
 def _make_kernel(n_heads: int, d_head: int, plan: PagedPlan, block_len: int,
-                 sm_scale: float):
+                 sm_scale: float, n_kv_heads: Optional[int] = None,
+                 dtype=jnp.float32):
     """Grid (rows,). A row's loop holds `pages_a_chunk` pages of K and of V
-    at a time; the scores are `[heads, slots]`, the statistics `[heads, 1]`."""
+    at a time; the scores are `[heads, slots]`, the statistics `[heads, 1]`.
+    With `n_kv_heads` the query arrives block-diagonal, `[1, heads, width]`
+    a grid step, and the output leaves so (module docstring)."""
     pages, hp, n_rows = plan.pages_a_chunk, plan.heads_padded, plan.steps_a_call
-    width, chunk = n_heads * d_head, plan.pages_a_chunk * block_len
+    grouped = n_kv_heads is not None
+    width = (n_kv_heads if grouped else n_heads) * d_head
+    chunk = plan.pages_a_chunk * block_len
 
     def kernel(tables_ref, lengths_ref, channel_ref, q_ref, kv_ref, o_ref,
                k_buf, v_buf, sems, slot_ref):
@@ -149,10 +170,13 @@ def _make_kernel(n_heads: int, d_head: int, plan: PagedPlan, block_len: int,
         length = lengths_ref[b]
         n_chunks = jnp.maximum(length - 1, 0) // chunk + 1
         first = slot_ref[0]              # the slot this row's chunk 0 is in
-        head = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 0)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
-        own = (lane >= head * d_head) & (lane < (head + 1) * d_head)
-        qbd = jnp.where(own, q_ref[pl.ds(b, 1), :], 0.0)        # [hp, width]
+        if grouped:
+            qbd = q_ref[0].astype(dtype)                        # [hp, width]
+        else:
+            head = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (hp, width), 1)
+            own = (lane >= head * d_head) & (lane < (head + 1) * d_head)
+            qbd = jnp.where(own, q_ref[pl.ds(b, 1), :], 0.0).astype(dtype)
 
         def body(c, carry):
             m_prev, l_prev, acc = carry
@@ -181,7 +205,7 @@ def _make_kernel(n_heads: int, d_head: int, plan: PagedPlan, block_len: int,
             corr = jnp.exp(m_prev - m_new)
             l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
             acc = acc * corr + jax.lax.dot_general(
-                p, v_buf[slot], _NN,
+                p.astype(dtype), v_buf[slot], _NN,
                 preferred_element_type=jnp.float32)             # [hp, width]
             return m_new, l_new, acc
 
@@ -191,52 +215,82 @@ def _make_kernel(n_heads: int, d_head: int, plan: PagedPlan, block_len: int,
              jnp.zeros((hp, 1), jnp.float32),
              jnp.zeros((hp, width), jnp.float32)))
         slot_ref[0] = (first + n_chunks) % 2
-        mine = jnp.where(own, acc / l, 0.0)
-        o_ref[pl.ds(b, 1), :] = jnp.sum(mine, axis=0, keepdims=True)
+        if grouped:
+            o_ref[0] = acc / l
+        else:
+            mine = jnp.where(own, acc / l, 0.0)
+            o_ref[pl.ds(b, 1), :] = jnp.sum(mine, axis=0, keepdims=True)
 
     return kernel
 
 
 def paged_decode_attention(q, kv, channel, tables, lengths, *, n_heads: int,
+                           n_kv_heads: Optional[int] = None,
                            sm_scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
     """Attention of one query a row over its paged cache.
 
     q [B, H*Dh] float32 (heads merged, as the arena holds them); kv the arena
-    `[2L, num_blocks, block_len, H*Dh]`, read in place; `channel` (int32
-    scalar, may be traced) the layer's key channel, its values are channel +
-    1; tables [B, W] int32 block ids; lengths [B] int32 live cache slots a
-    row (>= 1; slot `lengths - 1` is the query's own). Returns [B, H*Dh]:
-    `attention_reference` over the gathered view with `kv_length=lengths`,
-    head by head. Compiled Pallas on the TPU; `interpret=True` (automatic off
-    it) runs the same kernel through the interpreter."""
-    B, width = q.shape
-    _, num_blocks, block_len, kv_width = kv.shape
-    if kv_width != width or width % n_heads:
-        raise ValueError(f"q {q.shape} and arena {kv.shape} disagree on "
-                         f"H*Dh, or {n_heads} heads do not divide it")
+    `[2L, num_blocks, block_len, Hkv*Dh]`, float32 or bfloat16, read in
+    place; `channel` (int32 scalar, may be traced) the layer's key channel,
+    its values are channel + 1; tables [B, W] int32 block ids; lengths [B]
+    int32 live cache slots a row (>= 1; slot `lengths - 1` is the query's
+    own). `n_kv_heads` (None: as many as `n_heads`) says how many key/value
+    heads the arena's lanes hold. Returns [B, H*Dh]: `attention_reference`
+    over the gathered view with `kv_length=lengths`, head by head, each
+    query head on its key/value head. Compiled Pallas on the TPU;
+    `interpret=True` (automatic off it) runs the same kernel through the
+    interpreter."""
+    B, q_width = q.shape
+    _, num_blocks, block_len, width = kv.shape
+    grouped = n_kv_heads is not None and n_kv_heads != n_heads
+    if q_width % n_heads or n_heads % (n_kv_heads or n_heads) \
+            or width != (q_width // n_heads) * (n_kv_heads or n_heads):
+        raise ValueError(f"q {q.shape} in {n_heads} heads and arena "
+                         f"{kv.shape} in {n_kv_heads or n_heads} disagree "
+                         "on H*Dh")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    d_head = width // n_heads
+    d_head = q_width // n_heads
     if sm_scale is None:
         sm_scale = 1.0 / (d_head ** 0.5)
-    plan = _planned(B, tables.shape[1], block_len, n_heads, width, num_blocks)
+    if grouped or kv.dtype != jnp.float32:
+        plan = _planned(B, tables.shape[1], block_len, n_heads, width,
+                        num_blocks, n_kv_heads or n_heads, kv.dtype.name)
+    else:
+        plan = _planned(B, tables.shape[1], block_len, n_heads, width,
+                        num_blocks)
     slots = (2, plan.pages_a_chunk * block_len, width)
-    rows = pl.BlockSpec((B, width), lambda b, *_: (0, 0))
+    q = q.astype(jnp.float32)
+    if grouped:
+        # row h of the block-diagonal query lies on its key/value head's
+        # lanes; padded heads are zero rows (their output is not taken)
+        hp, group = plan.heads_padded, n_heads // n_kv_heads
+        on = jnp.arange(n_heads)[:, None] // group == jnp.arange(n_kv_heads)
+        q = jnp.where(on[None, :, :, None],
+                      q.reshape(B, n_heads, 1, d_head), 0.0)
+        q = jnp.pad(q.reshape(B, n_heads, width),
+                    ((0, 0), (0, hp - n_heads), (0, 0)))
+        rows = pl.BlockSpec((1, hp, width), lambda b, *_: (b, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((B, hp, width), jnp.float32)
+    else:
+        rows = pl.BlockSpec((B, width), lambda b, *_: (0, 0))
+        out_shape = jax.ShapeDtypeStruct((B, width), jnp.float32)
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))}
-    return pl.pallas_call(
-        _make_kernel(n_heads, d_head, plan, block_len, float(sm_scale)),
-        out_shape=jax.ShapeDtypeStruct((B, width), jnp.float32),
+    out = pl.pallas_call(
+        _make_kernel(n_heads, d_head, plan, block_len, float(sm_scale),
+                     n_kv_heads if grouped else None, kv.dtype),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[rows, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=rows,
             scratch_shapes=[
-                pltpu.VMEM(slots, jnp.float32),             # K pages
-                pltpu.VMEM(slots, jnp.float32),             # V pages
+                pltpu.VMEM(slots, kv.dtype),                # K pages
+                pltpu.VMEM(slots, kv.dtype),                # V pages
                 pltpu.SemaphoreType.DMA((2, 2)),            # [slot, K | V]
                 pltpu.SMEM((1,), jnp.int32),    # the slot of the next chunk 0
             ]),
@@ -244,5 +298,10 @@ def paged_decode_attention(q, kv, channel, tables, lengths, *, n_heads: int,
         name="paged_decode_attention",
         **params,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.reshape(channel, (1,)).astype(jnp.int32),
-      q.astype(jnp.float32), kv)
+      jnp.reshape(channel, (1,)).astype(jnp.int32), q, kv)
+    if not grouped:
+        return out
+    # head h's answer lies on its key/value head's lanes of row h
+    out = out[:, :n_heads].reshape(B, n_heads, n_kv_heads, d_head)
+    return jnp.sum(jnp.where(on[None, :, :, None], out, 0.0),
+                   axis=2).reshape(B, q_width)

@@ -20,6 +20,7 @@ from .generative import (AutoEncoder, RBM, VariationalAutoencoder,
 from .moe import MixtureOfExpertsLayer
 from .transformer import EmbeddingSequenceLayer, TransformerBlock
 from .shortcut_moe import RMSNormLayer, ShortcutMoEBlock, SparseExpertsLayer
+from .hybrid_ssm import HybridSSMBlock
 
 __all__ = [
     "DenseLayer", "OutputLayer", "LossLayer", "ActivationLayer",
@@ -36,4 +37,5 @@ __all__ = [
     "MixtureOfExpertsLayer",
     "EmbeddingSequenceLayer", "TransformerBlock",
     "RMSNormLayer", "ShortcutMoEBlock", "SparseExpertsLayer",
+    "HybridSSMBlock",
 ]

@@ -23,6 +23,12 @@ output skips the second attention and FFN (the shortcut):
              m = sum over picked routed e of w_e * Expert_e(u)
                + sum over picked identity e of w_e * u
 
+`SparseExpertsLayer` knows a second scoring rule, `scoring=
+"softmax_picked"` (Granite 4.0): the picks are the top-k router LOGITS,
+no correction bias, and `w_e = scale * softmax over the picked logits`;
+and a SHARED expert (`shared_hidden`), the same gated unit at its own
+width, that every token takes with weight 1.
+
 No biases. `s_q = sqrt(d / q_rank)` and `s_kv = sqrt(d / kv_rank)` where
 `mla_scale` is set. Matrix products take their operands in the weights'
 dtype and sum in float32; norms, softmaxes, rotations and the residual
@@ -32,18 +38,23 @@ and hands on float32).
 Expert parallelism is a share, not a runtime: `SparseExpertsLayer` is
 TOLD which routed experts it holds (`held_experts`, a range), routes
 over all of them, and computes its own experts' part of `m` plus the
-identity experts' part for its own tokens. The parts the shares give,
-with the identity part counted once, add up to the whole layer
+identity experts' and the shared expert's part for its own tokens. The
+parts the shares give, with the identity and the shared part counted
+once, add up to the whole layer
 (`tests/test_shortcut_moe.py`); what absent experts would have added is
 another chip's to add.
 
 The held experts' products are grouped: each expert runs over the rows
-that picked it and no other, gathered into `rows_per_expert(n)` slots,
-and not at all where no row did (its weights are then not read). Where
-some expert's load overflows its slots the layer computes every held
-expert over every token under its mask instead: no pick is ever
-dropped. A batch no larger than the slots (a decode tick) needs no
-gather.
+that picked it and no other, gathered into `rows_per_expert(n)` slots:
+among hundreds of tokens (a prefill) one batched product over all the
+held experts' slots. Where an expert's load passes its slots, the rows
+left over are worked off in further passes of a quarter of the slots, by
+the experts that have rows left and no other, each under a conditional: no
+pick is ever dropped, and an uneven routing costs the experts it loads,
+not every expert over every token. A batch of at most twice the slots (a
+decode tick) is not gathered: every held expert runs over its rows under
+its mask, and not at all where no row picked it (its weights are then
+not read).
 
 Serving (`serving/decode/engine.py` states the layers' contract): the
 block caches, for a token and an attention, the latent `c` (after norm
@@ -55,6 +66,7 @@ never building heads of K/V for every cached token.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import List, Optional
@@ -70,9 +82,18 @@ __all__ = ["RMSNormLayer", "SparseExpertsLayer", "ShortcutMoEBlock"]
 _F32 = jnp.float32
 _NEG = -1e30
 PICK_COUNTS = ("picks", "identity", "held", "held_hit", "held_load_max")
-# slots a held expert gets = this x its mean load under even routing; a
-# batch that overflows them falls back to every expert over every token
+# slots a held expert gets in a pass = this x its mean load under even
+# routing, and never more than a quarter of the tokens; an expert whose load
+# passes them takes further passes. Where a token picks under 1 route in 32
+# (LongCat-Flash: 12 of 768, slots for an eighth of the tokens) the factor
+# decides; where it picks more (Granite: 10 of 72, a mean load of 0.139 n)
+# the quarter does, 1.8 times the mean load there: the products of the first
+# pass, every held expert's slots, stay under twice the (token, held expert)
+# pairs picked, and the few experts loaded beyond that take a second.
+# Grouping engages for every density of picks at batches over twice the
+# slots (`_held_sum`).
 _SLOT_FACTOR = 8
+SCORINGS = ("softmax_all", "softmax_picked")
 
 
 def _rms_norm(x, g, eps):
@@ -114,13 +135,16 @@ def _pad128(n: int) -> int:
 @register_layer
 @dataclass
 class RMSNormLayer(LayerConf):
-    """x * rsqrt(mean(x^2) + eps) * g over the last axis, in float32 (the
-    final norm before a head). Keeps no cache: in a served stack it is
-    applied to the tokens of the step, as they come."""
+    """x * rsqrt(mean(x^2) + eps) * g * scale over the last axis, in
+    float32 (the final norm before a head; `scale` is what a model divides
+    its logits by, inverted: Granite's 1 / `logits_scaling`). Keeps no
+    cache: in a served stack it is applied to the tokens of the step, as
+    they come."""
 
     input_kind = "any"
 
     eps: float = 1e-5
+    scale: float = 1.0
 
     @property
     def has_params(self) -> bool:
@@ -132,18 +156,25 @@ class RMSNormLayer(LayerConf):
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         x = self.maybe_dropout_input(x, train, rng)
-        return _rms_norm(x, params["g"], self.eps), state
+        return self._norm(params, x), state
+
+    def _norm(self, p, x):
+        y = _rms_norm(x, p["g"], self.eps)
+        return y if self.scale == 1.0 else y * self.scale
 
     # -- the decode plane's contract --------------------------------------
     def decode_cache(self, width: int):
         return 0, 0
+
+    def decode_state(self, width: int):
+        return None
 
     def decode_attention(self, phase: str, spec):
         return None
 
     def decode_prefill_step(self, io, attention=None):
         def step(p, x, kv, sc, *_):
-            return _rms_norm(x, p["g"], self.eps), kv, sc, None
+            return self._norm(p, x), kv, sc, None
         return step
 
     decode_tick_step = decode_prefill_step
@@ -165,6 +196,8 @@ class SparseExpertsLayer(LayerConf):
     expert_hidden: int = 0          # default: 4 * width
     routed_scaling: float = 1.0
     held_experts: Optional[List[int]] = None
+    scoring: str = "softmax_all"    # or "softmax_picked" (module docstring)
+    shared_hidden: int = 0          # the shared expert's width; 0: none
 
     def output_type(self, it: InputType) -> InputType:
         return InputType.recurrent(it.size, it.timesteps)
@@ -172,6 +205,11 @@ class SparseExpertsLayer(LayerConf):
     @property
     def has_params(self) -> bool:
         return True
+
+    def __post_init__(self):
+        if self.scoring not in SCORINGS:
+            raise ValueError(f"scoring must be one of {SCORINGS}, got "
+                             f"{self.scoring!r}")
 
     def held(self) -> range:
         lo, hi = self.held_experts or (0, self.n_experts)
@@ -181,25 +219,34 @@ class SparseExpertsLayer(LayerConf):
         return range(int(lo), int(hi))
 
     def rows_per_expert(self, n: int) -> int:
-        """Slots a held expert has among `n` tokens: `_SLOT_FACTOR` times
-        its mean load under even routing, at least 32, in whole
-        sublane tiles; never more than the tokens."""
+        """Slots a held expert has in a pass over `n` tokens:
+        `_SLOT_FACTOR` times its mean load under even routing or a quarter
+        of the tokens, whichever is less; at least 32, in whole sublane
+        tiles; never more than the tokens."""
         mean = n * self.top_k / (self.n_experts + self.n_identity)
-        return min(n, max(32, 8 * math.ceil(_SLOT_FACTOR * mean / 8)))
+        share = min(_SLOT_FACTOR * mean, n / 4)
+        return min(n, max(32, 8 * math.ceil(share / 8)))
 
     def init_params(self, rng, it: InputType, width: Optional[int] = None):
         d = width or it.size
         h = self.expert_hidden or 4 * d
         e, routes = len(self.held()), self.n_experts + self.n_identity
-        k = jax.random.split(rng, 4)
-        return {
+        k = jax.random.split(rng, 7)
+        p = {
             "router_W": self._winit(k[0], (d, routes), d, routes),
-            "router_bias": self._binit((routes,)),
             # expert_-prefixed tensors shard on axis 0 (expert parallelism)
             "expert_W_g": self._winit(k[1], (e, d, h), d, h),
             "expert_W_u": self._winit(k[2], (e, d, h), d, h),
             "expert_W_d": self._winit(k[3], (e, h, d), h, d),
         }
+        if self.scoring == "softmax_all":       # the correction bias
+            p["router_bias"] = self._binit((routes,))
+        if self.shared_hidden:
+            s = self.shared_hidden
+            p.update(shared_W_g=self._winit(k[4], (d, s), d, s),
+                     shared_W_u=self._winit(k[5], (d, s), d, s),
+                     shared_W_d=self._winit(k[6], (s, d), s, d))
+        return p
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         x = self.maybe_dropout_input(x, train, rng)
@@ -208,20 +255,26 @@ class SparseExpertsLayer(LayerConf):
 
     # -- routing -----------------------------------------------------------
     def route(self, p, u):
-        """(ids [N, k] of the picked experts, w [N, k] their weights):
-        softmax over all routes in float32, picks by score + correction
-        bias, weights the scaled scores themselves (not renormalised)."""
+        """(ids [N, k] of the picked experts, w [N, k] their weights), in
+        float32. "softmax_all": softmax over all routes, picks by score +
+        correction bias, weights the scaled scores themselves (not
+        renormalised). "softmax_picked": picks by logit, weights the
+        scaled softmax over the picked logits."""
         with jax.default_matmul_precision("highest"):
-            s = jax.nn.softmax(
-                jnp.dot(u.astype(_F32), p["router_W"].astype(_F32)), axis=-1)
+            s = jnp.dot(u.astype(_F32), p["router_W"].astype(_F32))
         k = min(self.top_k, s.shape[-1])
+        if self.scoring == "softmax_picked":
+            top, ids = jax.lax.top_k(s, k)
+            return ids, self.routed_scaling * jax.nn.softmax(top, axis=-1)
+        s = jax.nn.softmax(s, axis=-1)
         _, ids = jax.lax.top_k(s + p["router_bias"].astype(_F32), k)
         return ids, self.routed_scaling * jnp.take_along_axis(s, ids, axis=-1)
 
     def mix(self, p, x, live=None):
         """(m [B, T, d] float32, counts [5] int32 as `PICK_COUNTS`): the
-        held experts' part and the identity experts' part of the layer's
-        output for `x`; `live` [B, T] leaves pad tokens out of both."""
+        held experts' part, the identity experts' part and the shared
+        expert's of the layer's output for `x`; `live` [B, T] leaves pad
+        tokens out of the first two and of the counts."""
         shape = x.shape
         u = x.reshape(-1, shape[-1])
         ids, w = self.route(p, u)
@@ -237,8 +290,12 @@ class SparseExpertsLayer(LayerConf):
         w_held = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
         loads = jnp.sum(took, axis=0)
         m = self._held_sum(p, u, w_held, took, loads)
-        m = m + jnp.sum(jnp.where(on_identity, w, 0.0), axis=-1,
-                        keepdims=True) * u.astype(_F32)
+        if self.n_identity:
+            m = m + jnp.sum(jnp.where(on_identity, w, 0.0), axis=-1,
+                            keepdims=True) * u.astype(_F32)
+        if self.shared_hidden:
+            m = m + _swiglu(u, p["shared_W_g"], p["shared_W_u"],
+                            p["shared_W_d"])
         counts = jnp.stack([jnp.sum(picked), jnp.sum(on_identity),
                             jnp.sum(loads), jnp.sum(loads > 0),
                             jnp.max(loads)]).astype(jnp.int32)
@@ -252,40 +309,73 @@ class SparseExpertsLayer(LayerConf):
         slots = self.rows_per_expert(n)
         u = u.astype(p["expert_W_g"].dtype)     # what the products take
 
-        def expert(e, rows, w_rows):
-            """w_rows * Expert_e(rows), or nothing where no row took e."""
+        def expert(e, rows, w_rows, left):
+            """w_rows * Expert_e(rows), or nothing where no row is `left`
+            for e (its weights are then not read)."""
             def run(_):
                 y = _swiglu(rows, p["expert_W_g"][e], p["expert_W_u"][e],
                             p["expert_W_d"][e])
                 return y * w_rows[:, None]
             return jax.lax.cond(
-                loads[e] > 0, run,
+                left > 0, run,
                 lambda _: jnp.zeros((rows.shape[0], d), _F32), None)
 
-        def every_row(_):
-            return sum(expert(e, u, w_held[:, e]) for e in range(e_held))
+        if n <= 2 * slots:      # not worth a gather and a scatter
+            return sum(expert(e, u, w_held[:, e], loads[e])
+                       for e in range(e_held))
 
-        if n <= slots:
-            return every_row(None)
+        # rank of token n in expert e's group: how many before it took e
+        rank = jnp.cumsum(took, axis=0) - 1                     # [N, E]
+        tokens = jnp.arange(n, dtype=jnp.int32)[:, None]
 
-        def gathered(_):
-            # slot of token n in expert e's group: how many before it took e
-            rank = jnp.cumsum(took, axis=0) - 1
-            slot = jnp.where(took, rank, slots)                 # [N, E]
-            idx = jnp.zeros((e_held, slots), jnp.int32).at[
-                jnp.arange(e_held)[None, :], slot].set(
-                    jnp.arange(n, dtype=jnp.int32)[:, None], mode="drop")
-            filled = jnp.arange(slots)[None, :] < loads[:, None]
-            rows = u[idx]                                       # [E, S, d]
+        def one_pass(out, first, size, products):
+            """The rows ranked first .. first + size - 1 of every expert's
+            group, gathered into `size` slots, through `products`, added
+            to their tokens."""
+            slot = jnp.where(took & (rank >= first), rank - first, size)
+            idx = jnp.zeros((e_held, size), jnp.int32).at[
+                jnp.arange(e_held)[None, :], slot].set(tokens, mode="drop")
+            left = loads - first
+            filled = jnp.arange(size)[None, :] < left[:, None]
             w_rows = jnp.where(
                 filled, jnp.take_along_axis(w_held.T, idx, axis=1), 0.0)
-            parts = jnp.stack([expert(e, rows[e], w_rows[e])
-                               for e in range(e_held)])
-            return jnp.zeros((n, d), _F32).at[idx.reshape(-1)].add(
-                parts.reshape(-1, d))
+            parts = products(u[idx], w_rows, left)              # [E, S, d]
+            return out.at[idx.reshape(-1)].add(parts.reshape(-1, d))
 
-        return jax.lax.cond(jnp.max(loads) <= slots, gathered, every_row,
-                            None)
+        def all_at_once(rows, w_rows, left):
+            """Every expert's slots in one batched product: among hundreds
+            of tokens every held expert has rows, and one product streams
+            the experts' weights where a conditional an expert would stop
+            and start 36 times a layer."""
+            dot = functools.partial(jnp.einsum, preferred_element_type=_F32)
+            hidden = jax.nn.silu(dot("esd,edh->esh", rows, p["expert_W_g"])) \
+                * dot("esd,edh->esh", rows, p["expert_W_u"])
+            return dot("esh,ehd->esd", hidden.astype(rows.dtype),
+                       p["expert_W_d"]) * w_rows[..., None]
+
+        def one_by_one(rows, w_rows, left):
+            return jnp.stack([expert(e, rows[e], w_rows[e], left[e])
+                              for e in range(e_held)])
+
+        # what passes the slots is a few rows of a few experts: a quarter
+        # of the slots a pass, so that a pass gathers and scatters little
+        tail = max(32, slots // 4)
+
+        def overflow(out, first):
+            """A further pass, where some load passes `first`: the experts
+            with rows left run, each under its conditional, the others do
+            not."""
+            return jax.lax.cond(
+                jnp.max(loads) > first,
+                lambda out: one_pass(out, first, tail, one_by_one),
+                lambda out: out, out), None
+
+        # one pass where no load passes the slots; a load that does is
+        # worked off `tail` rows a pass, by the experts it concerns alone
+        out = one_pass(jnp.zeros((n, d), _F32), 0, slots, all_at_once)
+        firsts = slots + tail * jnp.arange(-(-(n - slots) // tail),
+                                           dtype=loads.dtype)
+        return jax.lax.scan(overflow, out, firsts)[0]
 
 
 @register_layer
@@ -472,6 +562,9 @@ class ShortcutMoEBlock(LayerConf):
     def decode_cache(self, width: int):
         """One latent channel an attention."""
         return 2, self.latent_width()
+
+    def decode_state(self, width: int):
+        return None
 
     def decode_attention(self, phase: str, spec):
         return "mla_absorbed" if phase == "tick" else "mla_expanded"

@@ -241,6 +241,10 @@ class TransformerBlock(LayerConf):
         heads merged (`serving/decode/cache.py` says why merged)."""
         return 2, self.n_model or int(width)
 
+    def decode_state(self, width: int):
+        """No per-sequence state beside the pages."""
+        return None
+
     def decode_attention(self, phase: str, spec):
         """How `phase` attends over a cache of `spec`, from what the code
         can see. A prefill attends over its local K/V (None: nothing to
@@ -357,8 +361,11 @@ class EmbeddingSequenceLayer(LayerConf):
     max_timesteps: Optional[int] = None   # positional table length
                                           # (default: input type timesteps)
     positional: bool = True     # False: the token table alone (a stack
-                                # whose blocks rotate their own positions);
-                                # `max_timesteps` then STATES the context
+                                # whose blocks rotate their own positions,
+                                # or know none); `max_timesteps` then
+                                # STATES the context
+    multiplier: float = 1.0     # the token vectors' scale (Granite's
+                                # `embedding_multiplier`), applied in float32
 
     def output_type(self, it: InputType) -> InputType:
         return InputType.recurrent(self.n_out, it.timesteps)
@@ -398,11 +405,16 @@ class EmbeddingSequenceLayer(LayerConf):
         if idx.ndim == 3 and idx.shape[-1] == 1:
             idx = idx[..., 0]
         idx = idx.astype(jnp.int32)
-        z = jnp.take(params["W"], idx, axis=0)
+        z = self._scaled(jnp.take(params["W"], idx, axis=0))
         if not self.positional:
             return z, state
         t = z.shape[1]
         return z + params["P"][:t][None], state
+
+    def _scaled(self, z):
+        if self.multiplier == 1.0:
+            return z
+        return z.astype(jnp.float32) * self.multiplier
 
     def decode_context(self, params):
         """The positions a served sequence may hold: the positional
@@ -418,7 +430,7 @@ class EmbeddingSequenceLayer(LayerConf):
         `t`, not a [0..T) prefix slice). idx/positions [B, T] ->
         [B, T, n_out]. `positions` must stay below the positional table
         length — the table bounds the decode plane's context window."""
-        z = jnp.take(params["W"], idx.astype(jnp.int32), axis=0)
+        z = self._scaled(jnp.take(params["W"], idx.astype(jnp.int32), axis=0))
         if not self.positional:
             return z
         return z + jnp.take(params["P"], positions.astype(jnp.int32),

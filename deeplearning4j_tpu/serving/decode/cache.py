@@ -56,6 +56,22 @@ layout argument above holds for it too.
 ``kv_dtype="bf16"`` stores what a bfloat16 model computes as it computes
 it: half the float32 arena, no scales.
 
+Beside the pages the cache may hold PER-SEQUENCE STATE: what a layer keeps
+for a sequence whatever its length (a Mamba-2 layer of `nn/layers/
+hybrid_ssm.py`: the recurrent state `[H, P, N]` float32 and the last
+inputs of its convolution). A layer states the leaves (`decode_state`),
+the cache pytree holds them under ``"state"``, one dict a stateful layer,
+each leaf with an axis of `state_slots` SLOTS; they are donated with the
+arena and updated in place. Slot 0 is the trash slot, as block 0 is the
+trash block: pad rows read and write it. The `BlockPool` owns the slots
+too, and a caller never names one: a sequence is known by its FIRST block.
+A slot is taken when a prefill first meets that block (`slot_for`; the
+prefill overwrites the slot's state, which is the reset), found by the
+ticks (`slots_of`), and freed when `release` returns the block: finish,
+failure, eviction and a flush after a swap all go through `release`. A
+stack with no stateful layer has no such leaf, no slot and no argument
+for one.
+
 int8 KV (``kv_dtype="int8"``): the arena stores int8 plus a per-slot
 scale arena ``[2*L, num_blocks, block_len]`` — `serving/quantize.py`'s
 per-tensor symmetric scheme (scale = absmax / 127) applied per cached
@@ -67,6 +83,7 @@ contracts are asserted on the fp32 cache only.
 """
 from __future__ import annotations
 
+import math
 import threading
 from dataclasses import dataclass
 from typing import Dict, List
@@ -95,6 +112,11 @@ class KvCacheSpec:
     num_blocks: int        # arena height, INCLUDING the reserved block 0
     max_context: int       # hard cap (what the embedding layer states)
     kv_dtype: str = "fp32"   # "fp32" | "bf16" | "int8"
+    # per-sequence state: for each stateful layer its leaves ((name, shape
+    # with "slots" for the slot axis, dtype), ...), and the slots of each
+    # leaf, the trash slot 0 included
+    state: tuple = ()
+    state_slots: int = 0
 
     def __post_init__(self):
         if self.kv_dtype not in KV_DTYPES:
@@ -107,6 +129,9 @@ class KvCacheSpec:
                              "reserved trash block)")
         if self.block_len < 1 or self.max_context < 1:
             raise ValueError("block_len and max_context must be >= 1")
+        if self.state and self.state_slots < 2:
+            raise ValueError("state_slots must be >= 2 (slot 0 is the "
+                             "reserved trash slot)")
 
     @property
     def table_width(self) -> int:
@@ -127,15 +152,36 @@ class KvCacheSpec:
             return slots * self.width + slots * 4   # int8 data + f32 scales
         return slots * self.width * jnp.dtype(KV_DTYPES[self.kv_dtype]).itemsize
 
+    def state_shapes(self):
+        """The per-sequence leaves, one dict a stateful layer: {name:
+        (shape, dtype)} with the slots filled in."""
+        return tuple(
+            {name: (tuple(self.state_slots if n == "slots" else int(n)
+                          for n in shape), jnp.dtype(dtype))
+             for name, shape, dtype in layer} for layer in self.state)
+
+    def state_nbytes(self) -> int:
+        return sum(math.prod(shape) * dtype.itemsize
+                   for layer in self.state_shapes()
+                   for shape, dtype in layer.values())
+
 
 def make_cache(spec: KvCacheSpec) -> Dict[str, jnp.ndarray]:
     """Fresh zeroed cache pytree — ONE donated argument of the compiled
-    steps. fp32: {"kv": arena}; int8 adds the per-slot scale arena."""
+    steps. fp32: {"kv": arena}; int8 adds the per-slot scale arena; a
+    stack with stateful layers adds "state", their per-sequence leaves."""
     shape = (spec.channels, spec.num_blocks, spec.block_len, spec.width)
     if spec.kv_dtype == "int8":
-        return {"kv": jnp.zeros(shape, jnp.int8),
-                "scale": jnp.ones(shape[:3], jnp.float32)}
-    return {"kv": jnp.zeros(shape, KV_DTYPES[spec.kv_dtype])}
+        cache = {"kv": jnp.zeros(shape, jnp.int8),
+                 "scale": jnp.ones(shape[:3], jnp.float32)}
+    else:
+        cache = {"kv": jnp.zeros(shape, KV_DTYPES[spec.kv_dtype])}
+    if spec.state:
+        cache["state"] = tuple(
+            {name: jnp.zeros(shape, dtype)
+             for name, (shape, dtype) in layer.items()}
+            for layer in spec.state_shapes())
+    return cache
 
 
 def pack_kv(spec: KvCacheSpec, x):
@@ -191,7 +237,9 @@ class CacheIO:
 
 
 class BlockPool:
-    """Host-side free-list allocator over the arena's block ids.
+    """Host-side free-list allocator over the arena's block ids, and over
+    the slots of the per-sequence state where the stack keeps any (module
+    docstring: a sequence's slot hangs on its first block).
 
     The pool owns the DEVICE cache arrays too (`cache` — replaced after
     every compiled step with the donated step's output), so eviction,
@@ -207,12 +255,19 @@ class BlockPool:
         # block is reused first — also what makes the reuse-after-evict
         # bit-exactness test deterministic about WHICH blocks recycle
         self._free: List[int] = list(range(spec.num_blocks - 1, 0, -1))
-        self._blocks_g = None
+        self._free_slots: List[int] = list(range(spec.state_slots - 1, 0, -1))
+        self._slot_of: Dict[int, int] = {}      # first block -> slot
+        self._blocks_g = self._slots_g = None
         if metrics is not None:
             self._blocks_g = metrics.gauge(
                 "dl4j_decode_kv_blocks",
                 "paged KV arena blocks by state (block 0 reserved)",
                 labels=("model", "state"))
+            if spec.state:
+                self._slots_g = metrics.gauge(
+                    "dl4j_decode_state_slots",
+                    "slots of the per-sequence state by state (slot 0 "
+                    "reserved)", labels=("model", "state"))
             self._report()
 
     def _report(self):
@@ -221,6 +276,11 @@ class BlockPool:
             self._blocks_g.set(free, model=self.name, state="free")
             self._blocks_g.set(self.spec.usable_blocks - free,
                                model=self.name, state="used")
+        if self._slots_g is not None:
+            self._slots_g.set(len(self._free_slots), model=self.name,
+                              state="free")
+            self._slots_g.set(len(self._slot_of), model=self.name,
+                              state="used")
 
     def free_blocks(self) -> int:
         with self._lock:
@@ -248,4 +308,34 @@ class BlockPool:
                 if not 0 < b < self.spec.num_blocks:
                     raise ValueError(f"bad KV block id {b}")
             self._free.extend(reversed(blocks))
+            if self._slot_of:
+                # a sequence's slot goes with its first block
+                self._free_slots.extend(
+                    self._slot_of.pop(b) for b in blocks if b in self._slot_of)
             self._report()
+
+    def slot_for(self, first_block: int) -> int:
+        """The state slot of the sequence whose table starts with
+        `first_block`, taken now if it has none (a prefill's call: the
+        prefill then overwrites the slot's state)."""
+        with self._lock:
+            slot = self._slot_of.get(first_block)
+            if slot is None:
+                if not self._free_slots:
+                    raise OutOfBlocksError(
+                        f"{self.name}: no free state slot of "
+                        f"{self.spec.state_slots - 1}")
+                slot = self._slot_of[first_block] = self._free_slots.pop()
+                self._report()
+            return slot
+
+    def slots_of(self, first_blocks) -> List[int]:
+        """The slots of the sequences whose tables start with
+        `first_blocks` (a tick's call); KeyError for a sequence no prefill
+        has met."""
+        with self._lock:
+            return [self._slot_of[b] for b in first_blocks]
+
+    def used_slots(self) -> int:
+        with self._lock:
+            return len(self._slot_of)

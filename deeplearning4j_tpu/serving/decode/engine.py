@@ -12,7 +12,8 @@ registry computed when it built the version): the per-call path walks
 neither the model nor the weights, it meets a version once
 (`DecodeEngine._check_version`) and knows it by identity afterwards.
 
-  prefill(data, cache, tokens [1, Tp], lengths [1], tables [1, W])
+  prefill(data, cache, tokens [1, Tp], lengths [1], tables [1, W]
+          [, slot [1]])
       -> (cache', next_logits [1, V])
     The whole (right-padded) prompt runs as one causal forward — the
     standard full-sequence math, row-masked by `lengths` — while every
@@ -20,7 +21,8 @@ neither the model nor the weights, it meets a version once
     sequence's block table. Prompt attention uses the LOCAL (exact)
     projections, so int8 cache quantization only affects later ticks.
 
-  decode(data, cache, tokens [B], positions [B], tables [B, W])
+  decode(data, cache, tokens [B], positions [B], tables [B, W]
+         [, slots [B]])
       -> (cache', logits [B, V])
     One token per row: embed at its absolute position, scatter its K/V
     into the arena, attend over the row's live cache slots, project
@@ -50,6 +52,12 @@ answers
     decode_cache(width)              -> (channels, width) it writes for
                                         a token; (0, 0) for a layer that
                                         keeps none (a norm)
+    decode_state(width)              -> what it keeps for a SEQUENCE,
+                                        whatever its length: {name: (shape
+                                        with "slots" for the sequences'
+                                        axis, dtype)}; None for a layer
+                                        that keeps none (all but a
+                                        state-space layer)
     decode_attention(phase, spec)    -> the name of the attention path
                                         that phase takes over that cache,
                                         or None where there is no choice
@@ -63,14 +71,33 @@ channel through the tables; a prefill gets `pos, lengths` after them, a
 tick `tables, positions, lengths`. `counts` is None or a few int32 (an
 expert layer's picks): the executable returns them stacked, beside the
 logits, and the engine adds them to counters and to its `*.fetch`
-spans. The GPT block (`nn/layers/transformer.py`) writes K and V of
-`H*Dh`; the latent-attention block (`nn/layers/shortcut_moe.py`) one
-latent of 640 an attention, expanded into heads over a prompt and
-attended as it lies, with the up-projection absorbed, in a tick.
+spans. A layer that keeps state is handed two more arguments, `state`
+(its own leaves of the cache, as it stated them) and `slot` (`[B]`: each
+row's slot on the leaves' slot axis; slot 0 is the trash slot, a pad
+row's), and returns its new leaves as a fifth result: a prefill writes
+the state its prompt leaves after its last REAL token, a tick reads its
+rows' slots and writes them back. The GPT block
+(`nn/layers/transformer.py`) writes K and V of `H*Dh`; the
+latent-attention block (`nn/layers/shortcut_moe.py`) one latent of 640 an
+attention, expanded into heads over a prompt and attended as it lies,
+with the up-projection absorbed, in a tick; the hybrid block
+(`nn/layers/hybrid_ssm.py`) K and V of its `Hkv*Dh` key/value heads where
+its mixer is grouped-query attention, and where it is a Mamba-2 layer no
+page at all but a recurrent state `[H, P, N]` float32 and the last three
+inputs of its convolution for each sequence. Such a stack's executables
+take one argument more, the rows' slots (a fourth upload a tick); a stack
+without a stateful layer has no such leaf, argument or upload.
 
 The cache pytree is DONATED and laid out `[2L, num_blocks, block_len,
 H*Dh]` (`cache.py` says why), so the arena updates in place on device: a
 tick costs one [B,*] pass plus the rows' live pages, never an arena copy.
+The per-sequence state of a stateful stack (`cache["state"]`, one dict of
+leaves a stateful layer, `state_slots` slots each) rides the same
+donation: a tick reads and writes the rows' slots where they lie, a
+prefill writes one slot, and no executable may hold a temporary of the
+state's size (`test_decode_steps_update_the_state_in_place_on_v5e`; the
+record `dl4j/engine/executable` carries `state_bytes` beside
+`temp_bytes`).
 That holds on the chip and is kept by a test:
 `tests/test_flash_compile_tpu.py::test_decode_steps_update_the_arena_in_place_on_v5e`
 compiles both steps for a described v5e and refuses an arena-sized
@@ -101,8 +128,8 @@ __all__ = ["DecodeEngine", "build_prefill_fn", "build_decode_fn",
            "split_decode_layers", "cache_geometry"]
 
 
-_STEP_CONTRACT = ("decode_cache", "decode_attention", "decode_prefill_step",
-                  "decode_tick_step")
+_STEP_CONTRACT = ("decode_cache", "decode_state", "decode_attention",
+                  "decode_prefill_step", "decode_tick_step")
 
 
 def split_decode_layers(model):
@@ -118,8 +145,10 @@ def split_decode_layers(model):
             or not hasattr(layers[-1], "preout"):
         raise ServingError(
             "generation needs an embedding layer (decode_embed) -> layers "
-            "with decode steps (TransformerBlock, ShortcutMoEBlock, "
-            "RMSNormLayer) -> an output layer; got "
+            "with decode steps (TransformerBlock: pages of K and V; "
+            "ShortcutMoEBlock: pages of a latent; HybridSSMBlock: pages of "
+            "grouped K and V, or a state-space layer's per-sequence state; "
+            "RMSNormLayer: neither) -> an output layer; got "
             f"{[type(l).__name__ for l in (layers or [])]}")
     if getattr(model.conf, "preprocessors", None):
         raise ServingError(
@@ -129,22 +158,30 @@ def split_decode_layers(model):
 
 
 def cache_geometry(model):
-    """(channels, width, context) of a generate-capable stack, as its
-    layers state them, or ServingError: the channels summed over the
-    layers, one width for all that write, the embedding's context."""
+    """(channels, width, context, state) of a generate-capable stack, as
+    its layers state them, or ServingError: the channels summed over the
+    layers that page (some may not: a norm, a state-space layer), one
+    width for all that do, the embedding's context, and the per-sequence
+    leaves of the layers that keep state, as `KvCacheSpec.state` holds
+    them (empty where none does)."""
     emb, blocks, _ = split_decode_layers(model)
     wrote = [blk.decode_cache(emb.n_out) for blk in blocks]
     widths = {int(w) for c, w in wrote if c}
     if len(widths) != 1:
         raise ServingError(
             f"the stack's layers write cache widths {sorted(widths)}: the "
-            "paged arena holds one")
+            "paged arena holds one, and a sequence is known by its pages "
+            "(a stack needs a layer that pages)")
     context = emb.decode_context(model.params[0])
     if not context:
         raise ServingError(
             "the embedding layer states no context (no positional table "
             "and no max_timesteps)")
-    return sum(int(c) for c, _ in wrote), widths.pop(), int(context)
+    kept = (blk.decode_state(emb.n_out) for blk in blocks)
+    state = tuple(tuple((name, tuple(shape), str(dtype))
+                        for name, (shape, dtype) in sorted(leaves.items()))
+                  for leaves in kept if leaves)
+    return sum(int(c) for c, _ in wrote), widths.pop(), int(context), state
 
 
 def _layer_confs(model):
@@ -177,8 +214,26 @@ def _cache_arg_specs(spec: KvCacheSpec):
     return jax.eval_shape(lambda: make_cache(spec))
 
 
-def _repack(cache, kv, sc):
-    return {"kv": kv, "scale": sc} if "scale" in cache else {"kv": kv}
+def _through(blocks, steps, width, params, cache, x, slot, *where):
+    """`x` through the stack's traced `steps`, each handed its parameters,
+    the arena, its first channel and `where` (the step's own arguments); a
+    layer that keeps state also its leaves of `cache["state"]` and `slot`.
+    Returns (x, the new cache pytree, each layer's counts or None)."""
+    kv, sc = cache["kv"], cache.get("scale")
+    state, counts, at = list(cache.get("state", ())), [], 0
+    for block, step, p, channel in zip(blocks, steps, params,
+                                       _first_channels(blocks, width)):
+        args = (p, x, kv, sc, jnp.int32(channel), *where)
+        if block.decode_state(width) is None:
+            x, kv, sc, n = step(*args)
+        else:
+            x, kv, sc, n, state[at] = step(*args, state[at], *slot)
+            at += 1
+        counts.append(n)
+    out = {"kv": kv, "scale": sc} if "scale" in cache else {"kv": kv}
+    if state:
+        out["state"] = tuple(state)
+    return x, out, counts
 
 
 def _shared_steps(blocks, make):
@@ -216,14 +271,12 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
     io = CacheIO(spec)
     steps = _shared_steps(
         blocks, lambda layer: layer.decode_prefill_step(io, attention))
-    first = _first_channels(blocks, emb.n_out)
 
-    def prefill(data, cache, tokens, lengths, tables):
+    def prefill(data, cache, tokens, lengths, tables, *slot):
         params = snapshot.rebuild(data)
         b, tp = tokens.shape
         pos = jnp.broadcast_to(jnp.arange(tp, dtype=jnp.int32), (b, tp))
         x = emb.decode_embed(params[0], tokens, pos)
-        kv, sc = cache["kv"], cache.get("scale")
         tidx = jnp.arange(tp, dtype=jnp.int32)
         # right-padded prompt slots scatter too (their K/V derive
         # deterministically from the pad token, and table slots past the
@@ -231,16 +284,12 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
         # overwritten wholesale — reuse is bit-identical to fresh
         blk = tables[:, tidx // spec.block_len]
         off = jnp.broadcast_to(tidx % spec.block_len, (b, tp))
-        counts = []
-        for i, step in enumerate(steps):
-            x, kv, sc, n = step(params[1 + i], x, kv, sc,
-                                jnp.int32(first[i]), blk, off, pos, lengths)
-            counts.append(n)
+        x, cache, counts = _through(blocks, steps, emb.n_out, params[1:-1],
+                                    cache, x, slot, blk, off, pos, lengths)
         logits = head.preout(params[-1], {}, x)          # [B, Tp, V]
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        return (_repack(cache, kv, sc), last.astype(jnp.float32),
-                *_stack_counts(counts))
+        return cache, last.astype(jnp.float32), *_stack_counts(counts)
 
     return named_step("prefill", prefill)
 
@@ -257,25 +306,19 @@ def build_decode_fn(model, snapshot, spec: KvCacheSpec,
     io = CacheIO(spec)
     steps = _shared_steps(
         blocks, lambda layer: layer.decode_tick_step(io, attention))
-    first = _first_channels(blocks, emb.n_out)
 
-    def decode(data, cache, tokens, positions, tables):
+    def decode(data, cache, tokens, positions, tables, *slot):
         params = snapshot.rebuild(data)
         b = tokens.shape[0]
         lengths = positions + 1          # pad rows: position 0 -> length 1
         x = emb.decode_embed(params[0], tokens[:, None], positions[:, None])
-        kv, sc = cache["kv"], cache.get("scale")
         blk = tables[jnp.arange(b), positions // spec.block_len]
         off = positions % spec.block_len
-        counts = []
-        for i, step in enumerate(steps):
-            x, kv, sc, n = step(params[1 + i], x, kv, sc,
-                                jnp.int32(first[i]), blk, off, tables,
-                                positions, lengths)
-            counts.append(n)
+        x, cache, counts = _through(blocks, steps, emb.n_out, params[1:-1],
+                                    cache, x, slot, blk, off, tables,
+                                    positions, lengths)
         logits = head.preout(params[-1], {}, x)[:, 0]
-        return (_repack(cache, kv, sc), logits.astype(jnp.float32),
-                *_stack_counts(counts))
+        return cache, logits.astype(jnp.float32), *_stack_counts(counts)
 
     return named_step("tick", decode)
 
@@ -310,7 +353,8 @@ class DecodeEngine:
     `_check_version`: that its layers are the ones the executables were
     built for, and the signature that keys them, which the version
     states (`ServableVersion.sig`). A call then keeps what depends on
-    its rows: host arrays, two dictionary hits, three uploads."""
+    its rows: host arrays, two dictionary hits, three uploads (four
+    where the stack keeps per-sequence state: the rows' slots)."""
 
     def __init__(self, registry, name: str, *, block_len: int = 16,
                  num_blocks: Optional[int] = None, kv_dtype: str = "fp32",
@@ -323,7 +367,7 @@ class DecodeEngine:
             raise ServingError(
                 f"{name}: servable holds no live model object — "
                 "generation needs the layer stack")
-        channels, width, max_context = cache_geometry(v.model)
+        channels, width, max_context, state = cache_geometry(v.model)
         self.decode_buckets = tuple(sorted(int(b) for b in decode_buckets))
         if num_blocks is None:
             # default: full residency for a max-bucket batch of
@@ -333,7 +377,9 @@ class DecodeEngine:
         self.spec = KvCacheSpec(
             channels=channels, width=width,
             block_len=int(block_len), num_blocks=int(num_blocks),
-            max_context=max_context, kv_dtype=kv_dtype)
+            max_context=max_context, kv_dtype=kv_dtype, state=state,
+            # a slot for every row of the largest tick, and the trash slot
+            state_slots=1 + self.decode_buckets[-1] if state else 0)
         blocks = split_decode_layers(v.model)[1]
         self.attention = _attention_of(blocks, "tick", self.spec)
         self.prefill_attention = _attention_of(blocks, "prefill", self.spec)
@@ -391,7 +437,7 @@ class DecodeEngine:
             return v
         spec = self.spec
         if cache_geometry(v.model) != (spec.channels, spec.width,
-                                       spec.max_context) \
+                                       spec.max_context, spec.state) \
                 or _layer_confs(v.model) != self._layers:
             raise ServingError(
                 f"{self.name}: swapped architecture no longer matches the "
@@ -418,11 +464,20 @@ class DecodeEngine:
         executable built (`temp_bytes` beside `arena_bytes`: a program
         that converts or copies the arena holds a temporary of its size;
         `alias_bytes` is what the donation gave back), with the cache's
-        `channels` and `width`. `options` go to the builder and into the
+        `channels` and `width`, and where the stack keeps per-sequence
+        state its `state_bytes` and `state_slots` (a program that copies
+        the state holds a temporary of that size). A stateful stack's
+        program takes the rows' slots after `arg_specs`, as many as its
+        tokens have rows. `options` go to the builder and into the
         record: the phase's `attention`, where its layers have a choice
         (`paged_kernel` / `gather`, `mla_absorbed` / `mla_expanded`)."""
         spec = self.spec
         options = {k: o for k, o in options.items() if o is not None}
+        record = {}
+        if spec.state:
+            arg_specs += (_i32(arg_specs[0].shape[0]),)
+            record = {"state_bytes": spec.state_nbytes(),
+                      "state_slots": spec.state_slots}
         step = watch_compiles(
             jax.jit(build_fn(v.model, v.snapshot, spec, **options),
                     donate_argnums=(1,)),
@@ -435,7 +490,8 @@ class DecodeEngine:
             bucket=bucket, channels=spec.channels, width=spec.width,
             arena_bytes=spec.arena_nbytes(),
             temp_bytes=getattr(mem, "temp_size_in_bytes", None),
-            alias_bytes=getattr(mem, "alias_size_in_bytes", None), **options)
+            alias_bytes=getattr(mem, "alias_size_in_bytes", None), **record,
+            **options)
         return compiled
 
     def prefill_exec(self, v, t_bucket: int):
@@ -523,11 +579,16 @@ class DecodeEngine:
             tokens = np.zeros((1, tb), np.int32)
             tokens[0, :n] = np.asarray(prompt, np.int32)
             tab = np.asarray([self._pad_table(table)], np.int32)
+            # a stateful stack: the sequence's slot, taken now if this is
+            # the first prefill to meet its first block
+            slot = ([np.asarray([pool.slot_for(table[0])], np.int32)]
+                    if self.spec.state else [])
             exec_ = self.prefill_exec(v, tb)
         with _span("dl4j/engine/prefill.dispatch") as dispatch:
             pool.cache, logits, *counts = exec_(
                 v.snapshot.data, pool.cache, jnp.asarray(tokens),
-                jnp.asarray([n], jnp.int32), jnp.asarray(tab))
+                jnp.asarray([n], jnp.int32), jnp.asarray(tab),
+                *map(jnp.asarray, slot))
         with _span("dl4j/engine/prefill.fetch") as fetch:
             out = np.asarray(logits)[0]
             fetch.set(bytes=out.nbytes)
@@ -563,11 +624,16 @@ class DecodeEngine:
             prepare.set(
                 pages_live=int((pos // self.spec.block_len + 1).sum()),
                 pages_table=tab.size)
+            slot = []
+            if self.spec.state:     # pad rows keep the trash slot, 0
+                slot = [np.zeros(bucket, np.int32)]
+                slot[0][:rows] = pool.slots_of([t[0] for t in tables])
+                prepare.set(state_slots_live=rows)
             exec_ = self.decode_exec(v, bucket)
         with _span("dl4j/engine/tick.dispatch") as dispatch:
             pool.cache, logits, *counts = exec_(
                 v.snapshot.data, pool.cache, jnp.asarray(tok),
-                jnp.asarray(pos), jnp.asarray(tab))
+                jnp.asarray(pos), jnp.asarray(tab), *map(jnp.asarray, slot))
             if greedy:
                 logits = self._greedy[bucket, v.precision](logits)
         with _span("dl4j/engine/tick.fetch") as fetch:

@@ -85,6 +85,32 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, rows, heads):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("rows", [8, 64])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_grouped_paged_attention_kernel_compiles_for_v5e(one_chip, rows,
+                                                         dtype):
+    """The kernel with grouped queries at the Granite cell's geometry: 32
+    query heads on 8 key/value heads of 128, an arena of 2 channels x 8,193
+    pages of 16 slots x 1,024 lanes (bfloat16 as served), tables 128 wide.
+    The arena is abstract and is not copied: what the program holds beside
+    it is the block-diagonal query and the output, 128 kB a row each."""
+    from deeplearning4j_tpu.kernels.paged_attention import (
+        paged_decode_attention)
+
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    step = jax.jit(lambda q, kv, c, tables, lengths: paged_decode_attention(
+        q, kv, c, tables, lengths, n_heads=32, n_kv_heads=8,
+        sm_scale=0.0078125, interpret=False))
+    with jax.enable_x64(False):
+        lowered = step.lower(
+            arg(jnp.float32, rows, 4096), arg(dtype, 2, 8193, 16, 1024),
+            arg(jnp.int32), arg(jnp.int32, rows, 128), arg(jnp.int32, rows))
+        assert "paged_decode_attention" in lowered.as_text()
+        compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * rows * 32 * 1024 * 4 + (1 << 20)
+
+
 @pytest.fixture(scope="module")
 def decode_stack():
     """(model, snapshot, spec) at the served cell's cache geometry: width
@@ -186,3 +212,111 @@ def _nbytes(dtype: str, dims: str) -> int:
         n *= int(d)
     bits = re.search(r"\d+$", dtype)          # f32, bf16, s8; pred has none
     return n * (int(bits.group()) if bits else 8) // 8
+
+
+@pytest.fixture(scope="module")
+def hybrid_stack():
+    """(model, snapshot, spec) of three Mamba-2 layers and the grouped-query
+    attention layer at Granite 4.0-H's widths (4096 wide, 128 heads of 64
+    with a state of 128, 32 queries on 8 key/value heads of 128), 4 of the
+    72 experts held and a 256-token vocabulary, over the served cell's 65
+    state slots (0.84 GB of state) and a page table of 256 positions. The
+    weights are shapes: nothing is made."""
+    import functools
+    import importlib.util
+    import json
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
+    from deeplearning4j_tpu.serving.decode.engine import cache_geometry
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+
+    def load(kind):
+        spec = importlib.util.spec_from_file_location(
+            f"compile_test_{kind}", bench / kind / "granite_moe_hybrid.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    ref, models = load("reference"), load("models")
+    real = json.loads(
+        (bench / "configs" / "granite-4.0-h-small.json").read_text())
+    config = dict(
+        real, num_hidden_layers=4,
+        layer_types=["mamba", "attention", "mamba", "mamba"],
+        num_local_experts=4, vocab_size=256, max_position_embeddings=256,
+        deployment=dict(real["deployment"], held_experts=[0, 4]))
+    shapes = SimpleNamespace(
+        dims=ref.dims, init_params=lambda c, s: jax.eval_shape(
+            functools.partial(ref.init_params, c, s)))
+    model = models.build(config, 0, shapes, train=False)
+    leaves, treedef = jax.tree_util.tree_flatten(model.params)
+    snapshot = SimpleNamespace(
+        data=tuple(leaves),
+        rebuild=lambda data: jax.tree_util.tree_unflatten(treedef, list(data)))
+    channels, width, context, state = cache_geometry(model)
+    spec = KvCacheSpec(channels=channels, width=width, block_len=16,
+                       num_blocks=1 + 16 * 64, max_context=context,
+                       kv_dtype="bf16", state=state, state_slots=65)
+    return model, snapshot, spec
+
+
+@pytest.mark.parametrize("phase,bucket,attention", [
+    ("tick", 64, "gather"), ("tick", 64, "paged_kernel"),
+    ("tick", 8, "paged_kernel"), ("prefill", 512, None)])
+def test_decode_steps_update_the_state_in_place_on_v5e(one_chip, hybrid_stack,
+                                                       phase, bucket,
+                                                       attention):
+    """The per-sequence state is donated with the arena and every step
+    writes it where it lies. A 64-row tick (a quarter of the slots or more) runs the
+    recurrence over every slot in one elementwise pass that reads a layer's
+    leaf and writes it, the rows' output in the same fusion: no row gathered
+    out, none scattered back. An 8-row tick gathers its 8 rows' states (34 MB
+    a layer) and scatters them back. A prefill writes one slot. None holds a
+    temporary of a quarter of the state, none copies a layer's leaf, and
+    the outputs alias the inputs. The attention layer's tick through the
+    paged kernel (grouped queries, bfloat16 pages) makes no view of its
+    pages."""
+    import functools
+
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_decode_fn,
+                                                          build_prefill_fn)
+
+    model, snapshot, spec = hybrid_stack
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    w = spec.table_width
+    if phase == "tick":
+        fn = functools.partial(build_decode_fn, attention=attention)
+        args = (i32(bucket), i32(bucket), i32(bucket, w), i32(bucket))
+    else:
+        fn, args = build_prefill_fn, (i32(1, bucket), i32(1), i32(1, w), i32(1))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn(model, snapshot, spec), donate_argnums=(1,)).lower(
+            on_chip(snapshot.data), on_chip(_cache_arg_specs(spec)),
+            *args).compile()
+    state, leaf = spec.state_nbytes(), 65 * 128 * 64 * 128 * 4
+    assert state == 3 * (leaf + 3 * 65 * 8448 * 4) > 0.8e9
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < state / 4
+    assert mem.alias_size_in_bytes >= state + spec.arena_nbytes()
+    text = compiled.as_text()
+    copies = [m for m in re.finditer(r"= (\w+)\[([\d,]*)\]\S* copy\(", text)
+              if _nbytes(m.group(1), m.group(2)) >= leaf]
+    assert not copies, [m.group(0) for m in copies]
+    kernels = re.findall(r"%paged_decode_attention[.\d]* = \S+ custom-call\(",
+                         text)
+    assert len(kernels) == (attention == "paged_kernel")
+    if attention == "paged_kernel":     # no gathered view of the 16 pages
+        assert f"bf16[{bucket},16,16,1024]" not in text
+    if (phase, bucket) == ("tick", 64):
+        # one fusion a layer gives the rows' output and the new leaf
+        assert len(re.findall(
+            r"= \(f32\[65,128,64\]\S*, f32\[65,128,64,128\]\S*\) fusion\(",
+            text)) == 3
+        assert "f32[64,128,64,128]" not in text

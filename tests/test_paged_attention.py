@@ -298,3 +298,62 @@ def test_the_tick_through_the_kernel_agrees_with_the_tick_through_the_view(
                                   written["gather"][:2])
     np.testing.assert_allclose(written["paged_kernel"], written["gather"],
                                rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# grouped queries and a bfloat16 arena (the hybrid block's attention layer)
+# ---------------------------------------------------------------------------
+def _grouped_oracle(q, kv, channel, tables, lengths, heads, kv_heads, scale):
+    """Every query head over its key/value head's gathered view, in
+    float64 on the host."""
+    q, kv = np.asarray(q, np.float64), np.asarray(kv.astype(jnp.float32),
+                                                  np.float64)
+    b, d = q.shape[0], q.shape[1] // heads
+    out = np.zeros((b, heads, d))
+    for row in range(b):
+        n = int(lengths[row])
+        view = lambda c: kv[c][np.asarray(tables[row])].reshape(
+            -1, kv_heads, d)[:n]
+        k, v = view(channel), view(channel + 1)
+        for h in range(heads):
+            s = k[:, h // (heads // kv_heads)] @ q[row].reshape(heads, d)[h]
+            w = np.exp(s * scale - (s * scale).max())
+            out[row, h] = (w / w.sum()) @ v[:, h // (heads // kv_heads)]
+    return out.reshape(b, heads * d)
+
+
+@pytest.mark.parametrize("lengths,heads,kv_heads,dtype,channel", [
+    ([167, 880, 1, 422, 513, 96], 8, 2, "float32", 0),
+    ([1, 129, 128], 4, 1, "float32", 2),       # one key/value head for all
+    ([700, 16], 12, 4, "float32", 0),          # 12 query heads padded to 16
+    ([300, 1024, 33], 8, 2, "bfloat16", 2),    # the arena as a bf16 model holds it
+    ([64, 200], 4, 4, "bfloat16", 0),          # equal counts, bfloat16 pages
+], ids=lambda v: None if isinstance(v, int) else
+    (v if isinstance(v, str) else "x".join(map(str, v[:3]))))
+def test_grouped_queries_read_their_key_value_heads_pages(lengths, heads,
+                                                          kv_heads, dtype,
+                                                          channel):
+    """The kernel with fewer key/value heads than query heads (the arena's
+    lanes hold `Hkv*Dh`), a scale of its own and bfloat16 pages, against
+    each query head's attention over its key/value head, computed plainly."""
+    _, kv, tables, lens = _paged(lengths, kv_heads, layers=1 + channel // 2)
+    kv = kv.astype(dtype)
+    q = jnp.asarray(np.random.default_rng(3).normal(
+        size=(len(lengths), heads * 64)).astype(np.float32))
+    got = np.asarray(paged_decode_attention(
+        q, kv, jnp.int32(channel), tables, lens, n_heads=heads,
+        n_kv_heads=kv_heads, sm_scale=0.05, interpret=True))
+    q_seen = q.astype(dtype).astype(jnp.float32)   # what the products take
+    want = _grouped_oracle(q_seen, kv, channel, tables, lens, heads, kv_heads,
+                           0.05)
+    assert got.shape == want.shape
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" else \
+        dict(rtol=2e-2, atol=2e-2)      # the weights are rounded to bfloat16
+    np.testing.assert_allclose(got, want, **tol)
+    assert paged_attention_supported(kv_heads * 64, BL, dtype) == (
+        kv_heads % 2 == 0)
+    assert not paged_attention_supported(128, 8, "bfloat16")
+    assert not paged_attention_supported(128, 16, "int8")
+    with pytest.raises(ValueError, match="disagree"):
+        paged_decode_attention(q, kv, jnp.int32(0), tables, lens,
+                               n_heads=heads, n_kv_heads=kv_heads + 1)

@@ -58,6 +58,7 @@ def _case(name):
         LastTimeStep, LocalResponseNormalization, LossLayer,
         MixtureOfExpertsLayer, RMSNormLayer, RnnOutputLayer,
         ShortcutMoEBlock, SparseExpertsLayer, Subsampling1DLayer,
+        HybridSSMBlock,
         SubsamplingLayer, TransformerBlock, VariationalAutoencoder,
         ZeroPaddingLayer)
     from deeplearning4j_tpu.nn.layers import RBM
@@ -151,6 +152,15 @@ def _case(name):
                               qk_rope=2, v_head=4, ffn_hidden=16,
                               n_experts=4, n_identity=2, top_k=2,
                               expert_hidden=8), rnn_head],
+            InputType.recurrent(8, 6), _rnn_data(f=8)),
+        "HybridSSMBlock": lambda: (
+            [HybridSSMBlock(mixer="mamba", ssm_heads=4, ssm_head_dim=4,
+                            ssm_state=4, chunk=4, n_experts=4, top_k=2,
+                            expert_hidden=8, shared_hidden=8),
+             HybridSSMBlock(mixer="attention", n_heads=4, n_kv_heads=2,
+                            head_dim=2, attention_multiplier=0.5,
+                            n_experts=4, top_k=2, expert_hidden=8,
+                            held_experts=[0, 2]), rnn_head],
             InputType.recurrent(8, 6), _rnn_data(f=8)),
         "EmbeddingSequenceLayer": lambda: (
             [EmbeddingSequenceLayer(n_in=20, n_out=8), rnn_head],

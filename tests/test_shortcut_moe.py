@@ -195,9 +195,9 @@ def test_held_experts_grouped_match_every_expert_over_every_token(
     """The three ways the held experts' part is computed: a batch within an
     expert's slots (every row under its weight), a longer one (rows
     gathered into slots), and one whose routing overflows the slots (the
-    layer falls back; no pick is dropped). At this size a token picks a
-    sixth of the routes, so 8 slots a mean load hold every batch whole: 2
-    reach the other two ways."""
+    overloaded expert takes further passes; no pick is dropped). At this
+    size a token picks a sixth of the routes, so 8 slots a mean load hold
+    every batch whole: 2 reach the other two ways."""
     monkeypatch.setattr(shortcut_moe, "_SLOT_FACTOR", 2)
     layer, p = _experts(held=(4, 8))
     if skew:        # every token picks held expert 5
@@ -396,13 +396,13 @@ def _block(**kw):
 
 def test_geometry_is_what_the_layers_state():
     gpt = _stack(TransformerBlock(n_heads=4), TransformerBlock(n_heads=2))
-    assert cache_geometry(gpt) == (4, 64, 32)       # 2 blocks x (K, V), H*Dh
+    assert cache_geometry(gpt) == (4, 64, 32, ())   # 2 blocks x (K, V), H*Dh
     lcf = _stack(_block(), _block(), RMSNormLayer(),
                  emb=EmbeddingSequenceLayer(n_in=40, n_out=64,
                                             positional=False,
                                             max_timesteps=48))
     assert "P" not in lcf.params[0]
-    assert cache_geometry(lcf) == (4, 128, 48)      # 2 blocks x 2 latents
+    assert cache_geometry(lcf) == (4, 128, 48, ())  # 2 blocks x 2 latents
 
 
 @pytest.mark.parametrize("stack,why", [
