@@ -684,22 +684,23 @@ def decode_entry(phase: str = "tick",
     Mutations (each must trip exactly one IR rule):
 
       mutate="donate_tokens"  the int32 token ids are donated TOO — they
-        can alias nothing in the (f32/int8 cache, f32 logits) outputs,
-        so the lowering/XLA must drop that donation
+        can alias nothing in the (f32/int8 cache, int32 ids of the
+        largest bucket's length, f32 logits) outputs, so the
+        lowering/XLA must drop that donation
         -> ir-ineffective-donation.
     """
     import jax
     import jax.numpy as jnp
 
     from ..serving.decode.cache import make_cache
-    from ..serving.decode.engine import build_decode_fn, build_prefill_fn
+    from ..serving.decode.engine import build_prefill_fn, build_tick_fn
 
     eng, v = _decode_build()
     spec = eng.spec
     if mutate is None:
         donate = (1,)
     elif mutate == "donate_tokens":
-        donate = (1, 2)
+        donate = (1, 3)
     else:
         raise ValueError(f"unknown mutation {mutate!r}")
     w = spec.table_width
@@ -709,10 +710,12 @@ def decode_entry(phase: str = "tick",
                 jnp.zeros((1, 8), jnp.int32), jnp.ones((1,), jnp.int32),
                 jnp.zeros((1, w), jnp.int32))
     elif phase == "tick":
-        fn = build_decode_fn(v.model, v.snapshot, spec)
+        # the tick as it is served: the last tick's ids before the tokens
+        # (of a largest bucket of 4, so that the 2 tokens alias no output)
+        fn = build_tick_fn(v.model, v.snapshot, spec, 4)
         args = (v.snapshot.data, make_cache(spec),
-                jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
-                jnp.zeros((2, w), jnp.int32))
+                jnp.zeros((4,), jnp.int32), jnp.zeros((2,), jnp.int32),
+                jnp.zeros((2,), jnp.int32), jnp.zeros((2, w), jnp.int32))
     else:
         raise ValueError(f"unknown decode phase {phase!r}")
     from ..telemetry.compile_watch import watch_compiles

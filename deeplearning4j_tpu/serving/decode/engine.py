@@ -35,13 +35,23 @@ neither the model nor the weights, it meets a version once
     per-row valid length (`kernels.attention` kv_length path): the
     kernel's oracle.
 
-  greedy(logits [B, V]) -> tokens [B]
-    The argmax of each row, on the device, compiled beside the bucket's
-    tick (`run_tick(..., greedy=True)`: a tick whose rows are all sampled
-    at temperature 0). The host then fetches B ids in place of `[B, V]`
-    float32 (2 to 3 MB), which the scheduler thread would read once,
-    row by row, for the same argmax: that read ran at one of two speeds
-    from tick to tick and from run to run (PERF.md section 6, PR 34).
+  tick(data, cache, last [Bmax], tokens [B], positions [B], tables [B, W]
+       [, slots [B]])
+      -> (cache', ids [Bmax], logits [B, V])
+    What is compiled and served for a decode bucket (`build_tick_fn`): the
+    decode step above between two small selections. Before it, a row's
+    input token is taken ON THE DEVICE from `last`, the ids the previous
+    tick returned, where `tokens` names a row of it (an entry `-(i + 1)`
+    is row `i` of `last`; an entry >= 0 is the token itself, from the
+    host). After it, `ids` is the argmax of each row's logits, padded to
+    the largest bucket so that any tick's ids feed any bucket's tick. A
+    tick whose rows are all greedy is therefore started from ids that
+    never visit the host (`DecodeEngine.start_tick(..., after=)`), while
+    its predecessor's ids are still on their way down; the host fetches B
+    ids in place of `[B, V]` float32 (2 to 3 MB), which the scheduler
+    thread would read once, row by row, for the same argmax (PERF.md
+    section 6, PRs 34 and 37). The logits are an output all the same: a
+    tick with a row at a temperature fetches them instead.
 
 The layers' contract. The engine names no model: a stack can be served
 if its first layer embeds (`decode_embed(params, tokens, positions)`)
@@ -111,6 +121,7 @@ stay — the continuous-batching isolation contract the tests assert.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import List, Optional, Sequence, Tuple
 
@@ -125,7 +136,7 @@ from ..registry import ServingError, _abstract_sig
 from .cache import BlockPool, CacheIO, KvCacheSpec, make_cache
 
 __all__ = ["DecodeEngine", "build_prefill_fn", "build_decode_fn",
-           "split_decode_layers", "cache_geometry"]
+           "build_tick_fn", "split_decode_layers", "cache_geometry"]
 
 
 _STEP_CONTRACT = ("decode_cache", "decode_state", "decode_attention",
@@ -294,13 +305,8 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
     return named_step("prefill", prefill)
 
 
-def build_decode_fn(model, snapshot, spec: KvCacheSpec,
-                    attention: Optional[str] = None):
-    """Pure one-token decode tick (see module docstring). `attention` is
-    what the stack's layers answer for a tick over `spec` unless given:
-    a GPT block's "paged_kernel" is the compiled kernel, whatever the
-    process's default backend (a test compiles it for a described
-    chip)."""
+def _decode_step(model, snapshot, spec: KvCacheSpec,
+                 attention: Optional[str] = None):
     emb, blocks, head = split_decode_layers(model)
     attention = attention or _attention_of(blocks, "tick", spec)
     io = CacheIO(spec)
@@ -320,15 +326,41 @@ def build_decode_fn(model, snapshot, spec: KvCacheSpec,
         logits = head.preout(params[-1], {}, x)[:, 0]
         return cache, logits.astype(jnp.float32), *_stack_counts(counts)
 
-    return named_step("tick", decode)
+    return decode
+
+
+def build_decode_fn(model, snapshot, spec: KvCacheSpec,
+                    attention: Optional[str] = None):
+    """Pure one-token decode step (see module docstring). `attention` is
+    what the stack's layers answer for a tick over `spec` unless given:
+    a GPT block's "paged_kernel" is the compiled kernel, whatever the
+    process's default backend (a test compiles it for a described
+    chip)."""
+    return named_step("tick", _decode_step(model, snapshot, spec, attention))
+
+
+def build_tick_fn(model, snapshot, spec: KvCacheSpec, rows_max: int,
+                  attention: Optional[str] = None):
+    """The served tick (see module docstring): the decode step, its input
+    tokens selected on the device between the host's and the previous
+    tick's ids `last` `[rows_max]`, and its rows' argmax returned beside
+    the logits, padded to `rows_max`."""
+    decode = _decode_step(model, snapshot, spec, attention)
+
+    def tick(data, cache, last, tokens, positions, tables, *slot):
+        tokens = jnp.where(tokens < 0, last[jnp.maximum(-tokens - 1, 0)],
+                           tokens)
+        cache, logits, *counts = decode(data, cache, tokens, positions,
+                                        tables, *slot)
+        ids = jnp.zeros(rows_max, jnp.int32).at[:tokens.shape[0]].set(
+            jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        return cache, ids, logits, *counts
+
+    return named_step("tick", tick)
 
 
 def _i32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-
-def _greedy_tokens(logits):
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _pow2_buckets(lo: int, hi: int) -> Tuple[int, ...]:
@@ -340,14 +372,30 @@ def _pow2_buckets(lo: int, hi: int) -> Tuple[int, ...]:
     return tuple(sorted(set(out)))
 
 
+@dataclasses.dataclass(frozen=True)
+class _Started:
+    """A prefill or tick that was dispatched and not yet waited for: its
+    results, still on the device, and the spans that timed its start."""
+    rows: int
+    bucket: int
+    ids: Optional[jax.Array]        # a tick's: [largest bucket], its argmax
+    logits: jax.Array
+    counts: list
+    spans: tuple
+
+
 class DecodeEngine:
     """Compiled-step frontend for one servable's generation plane.
 
     Owns the static cache geometry (`spec`) and the bucket ladders; the
     executables live in the registry's per-model cache so swaps and the
     compile accounting behave exactly like the stateless runners. The
-    scheduler calls `run_prefill` / `run_tick` with host data; both only
-    ever invoke finished executables.
+    scheduler calls `start_prefill` / `start_tick` with host data and
+    `finish_prefill` / `finish_tick` with what those returned (`run_prefill`
+    / `run_tick` are the two in one call); all only ever invoke finished
+    executables. Between a `start_*` and the next, nothing on the host may
+    read `pool.cache` or a started step's results: they are futures on
+    the device's one in-order stream.
 
     What depends on the version alone is resolved once a version, in
     `_check_version`: that its layers are the ones the executables were
@@ -384,7 +432,8 @@ class DecodeEngine:
         self.attention = _attention_of(blocks, "tick", self.spec)
         self.prefill_attention = _attention_of(blocks, "prefill", self.spec)
         self._moe_picks = self._moe_pairs = None    # made with the first counts
-        self._greedy = {}       # (decode bucket, precision) -> compiled argmax
+        # what a tick with no predecessor in flight is handed as `last`
+        self._no_ids = jnp.asarray(np.zeros(self.decode_buckets[-1], np.int32))
         self._layers = _layer_confs(v.model)
         self._checked = self._sig = None    # the last version met, its sig
         self.prompt_buckets = (tuple(sorted(int(b) for b in prompt_buckets))
@@ -468,14 +517,14 @@ class DecodeEngine:
         state its `state_bytes` and `state_slots` (a program that copies
         the state holds a temporary of that size). A stateful stack's
         program takes the rows' slots after `arg_specs`, as many as its
-        tokens have rows. `options` go to the builder and into the
+        tables have rows. `options` go to the builder and into the
         record: the phase's `attention`, where its layers have a choice
         (`paged_kernel` / `gather`, `mla_absorbed` / `mla_expanded`)."""
         spec = self.spec
         options = {k: o for k, o in options.items() if o is not None}
         record = {}
         if spec.state:
-            arg_specs += (_i32(arg_specs[0].shape[0]),)
+            arg_specs += (_i32(arg_specs[-1].shape[0]),)
             record = {"state_bytes": spec.state_nbytes(),
                       "state_slots": spec.state_slots}
         step = watch_compiles(
@@ -510,27 +559,18 @@ class DecodeEngine:
             f"prefill-t{t_bucket}")
 
     def decode_exec(self, v, bucket: int):
-        """The decode bucket's tick for `v`, found as `prefill_exec` finds
-        a prefill (it runs in every tick: two dictionary hits, no walk);
-        the bucket's argmax program is made beside it."""
+        """The decode bucket's tick for `v` (`build_tick_fn`), found as
+        `prefill_exec` finds a prefill (it runs in every tick: two
+        dictionary hits, no walk)."""
         self._check_version(v)
-        w = self.spec.table_width
-        tick = self.registry.compile_cached(
+        w, rows_max = self.spec.table_width, self.decode_buckets[-1]
+        return self.registry.compile_cached(
             self.name, ("decode", self._sig, "tick", bucket),
-            lambda: self._compile(v, build_decode_fn, "tick", bucket,
-                                  _i32(bucket), _i32(bucket), _i32(bucket, w),
-                                  attention=self.attention),
+            lambda: self._compile(
+                v, functools.partial(build_tick_fn, rows_max=rows_max), "tick",
+                bucket, _i32(rows_max), _i32(bucket), _i32(bucket),
+                _i32(bucket, w), attention=self.attention),
             f"decode-b{bucket}")
-        if (bucket, v.precision) not in self._greedy:
-            # the rows' argmax over the tick's logits, made with the
-            # bucket's tick so that no greedy tick meets a compile
-            logits = tick.out_info[1]
-            self._greedy[bucket, v.precision] = watch_compiles(
-                jax.jit(_greedy_tokens),
-                f"serving/decode:{self.name}/greedy-{bucket}"
-            ).__wrapped__.lower(
-                jax.ShapeDtypeStruct(logits.shape, logits.dtype)).compile()
-        return tick
 
     # -- host-facing phases ----------------------------------------------
     def _count_picks(self, fetch, phase: str, counts):
@@ -565,14 +605,14 @@ class DecodeEngine:
                                f"exceeds width {w}")
         return list(table) + [0] * (w - len(table))
 
-    def run_prefill(self, v, pool: BlockPool, prompt: Sequence[int],
-                    table: Sequence[int], observe=None) -> np.ndarray:
-        """Write `prompt`'s K/V through `table`, return the next-token
-        logits [V]. Batch 1: one compile per prompt bucket. Three spans,
-        children of whatever span the caller has open: prepare (host),
-        dispatch (uploads + enqueue), fetch (the wait for the device and
-        the copy down); `observe(span)` sees each once it has closed."""
-        with _span("dl4j/engine/prefill.prepare") as prepare:
+    def start_prefill(self, v, pool: BlockPool, prompt: Sequence[int],
+                      table: Sequence[int], observe=None) -> "_Started":
+        """Dispatch the prefill that writes `prompt`'s K/V through `table`
+        (batch 1: one compile per prompt bucket); `finish_prefill` waits
+        for it. Two spans, prepare (host) and dispatch (uploads +
+        enqueue), held until the finish writes them beside its own;
+        `observe(span)` sees each once it has closed."""
+        with _span("dl4j/engine/prefill.prepare").hold() as prepare:
             n = len(prompt)
             tb = self.prompt_bucket_for(n)
             prepare.set(bucket=tb, tokens=n)
@@ -584,31 +624,52 @@ class DecodeEngine:
             slot = ([np.asarray([pool.slot_for(table[0])], np.int32)]
                     if self.spec.state else [])
             exec_ = self.prefill_exec(v, tb)
-        with _span("dl4j/engine/prefill.dispatch") as dispatch:
+        with _span("dl4j/engine/prefill.dispatch").hold() as dispatch:
             pool.cache, logits, *counts = exec_(
                 v.snapshot.data, pool.cache, jnp.asarray(tokens),
                 jnp.asarray([n], jnp.int32), jnp.asarray(tab),
                 *map(jnp.asarray, slot))
-        with _span("dl4j/engine/prefill.fetch") as fetch:
-            out = np.asarray(logits)[0]
-            fetch.set(bytes=out.nbytes)
-            if counts:
-                self._count_picks(fetch, "prefill", counts)
         if observe is not None:
-            for sp in (prepare, dispatch, fetch):
-                observe(sp)
+            observe(prepare)
+            observe(dispatch)
+        return _Started(1, tb, None, logits, counts, (prepare, dispatch))
+
+    def finish_prefill(self, started: "_Started", observe=None) -> np.ndarray:
+        """The next-token logits [V] of a started prefill. Writes its
+        prepare and dispatch spans and a third, fetch (the wait for the
+        device and the copy down), as children of whatever span the
+        caller has open."""
+        for sp in started.spans:
+            sp.write()
+        with _span("dl4j/engine/prefill.fetch") as fetch:
+            out = np.asarray(started.logits)[0]
+            fetch.set(bytes=out.nbytes)
+            if started.counts:
+                self._count_picks(fetch, "prefill", started.counts)
+        if observe is not None:
+            observe(fetch)
         return out
 
-    def run_tick(self, v, pool: BlockPool, tokens: Sequence[int],
-                 positions: Sequence[int], tables: Sequence[Sequence[int]],
-                 bucket: int, observe=None, greedy: bool = False
-                 ) -> np.ndarray:
-        """One decode tick over `len(tokens)` live rows padded up to
-        `bucket` (pad rows park at the trash block, length 1, and their
-        logits are discarded by the caller). Returns logits [rows, V], or
-        with `greedy` the rows' argmax [rows] int32, taken on the device.
-        Spans and `observe` as in `run_prefill`."""
-        with _span("dl4j/engine/tick.prepare", bucket=bucket) as prepare:
+    def run_prefill(self, v, pool: BlockPool, prompt: Sequence[int],
+                    table: Sequence[int], observe=None) -> np.ndarray:
+        """Write `prompt`'s K/V through `table`, return the next-token
+        logits [V]: `start_prefill` and `finish_prefill` in one call."""
+        return self.finish_prefill(
+            self.start_prefill(v, pool, prompt, table, observe), observe)
+
+    def start_tick(self, v, pool: BlockPool, tokens: Sequence[int],
+                   positions: Sequence[int], tables: Sequence[Sequence[int]],
+                   bucket: int, observe=None,
+                   after: Optional["_Started"] = None) -> "_Started":
+        """Dispatch one decode tick over `len(tokens)` live rows padded up
+        to `bucket` (pad rows park at the trash block, length 1, and their
+        results are discarded by the caller); `finish_tick` waits for it.
+        A token `-(i + 1)` is row `i` of the ids of `after`, a tick started
+        before this one and perhaps not finished: the device selects it,
+        the host never sees it. Spans and `observe` as in
+        `start_prefill`."""
+        with _span("dl4j/engine/tick.prepare",
+                   bucket=bucket).hold() as prepare:
             rows = len(tokens)
             if rows > bucket:
                 raise ServingError(f"{rows} rows > decode bucket {bucket}")
@@ -630,18 +691,39 @@ class DecodeEngine:
                 slot[0][:rows] = pool.slots_of([t[0] for t in tables])
                 prepare.set(state_slots_live=rows)
             exec_ = self.decode_exec(v, bucket)
-        with _span("dl4j/engine/tick.dispatch") as dispatch:
-            pool.cache, logits, *counts = exec_(
-                v.snapshot.data, pool.cache, jnp.asarray(tok),
+            last = self._no_ids if after is None else after.ids
+        with _span("dl4j/engine/tick.dispatch").hold() as dispatch:
+            pool.cache, ids, logits, *counts = exec_(
+                v.snapshot.data, pool.cache, last, jnp.asarray(tok),
                 jnp.asarray(pos), jnp.asarray(tab), *map(jnp.asarray, slot))
-            if greedy:
-                logits = self._greedy[bucket, v.precision](logits)
-        with _span("dl4j/engine/tick.fetch") as fetch:
-            full = np.asarray(logits)
-            fetch.set(bytes=full.nbytes)
-            if counts:
-                self._count_picks(fetch, "tick", counts)
         if observe is not None:
-            for sp in (prepare, dispatch, fetch):
-                observe(sp)
-        return full[:rows]
+            observe(prepare)
+            observe(dispatch)
+        return _Started(rows, bucket, ids, logits, counts,
+                        (prepare, dispatch))
+
+    def finish_tick(self, started: "_Started", greedy: bool = False,
+                    observe=None) -> np.ndarray:
+        """The logits [rows, V] of a started tick, or with `greedy` the
+        rows' argmax [rows] int32, taken on the device. Spans as in
+        `finish_prefill`."""
+        for sp in started.spans:
+            sp.write()
+        with _span("dl4j/engine/tick.fetch") as fetch:
+            full = np.asarray(started.ids if greedy else started.logits)
+            fetch.set(bytes=full.nbytes)
+            if started.counts:
+                self._count_picks(fetch, "tick", started.counts)
+        if observe is not None:
+            observe(fetch)
+        return full[:started.rows]
+
+    def run_tick(self, v, pool: BlockPool, tokens: Sequence[int],
+                 positions: Sequence[int], tables: Sequence[Sequence[int]],
+                 bucket: int, observe=None, greedy: bool = False
+                 ) -> np.ndarray:
+        """One decode tick, started and finished in one call: the same
+        executable a scheduler's tick in flight runs."""
+        return self.finish_tick(
+            self.start_tick(v, pool, tokens, positions, tables, bucket,
+                            observe), greedy, observe)

@@ -2,19 +2,42 @@
 over the paged decode engine.
 
 One worker thread per generate-enabled servable runs the generation
-loop: admit waiting sequences, grow/evict KV blocks, run ONE decode
-tick, sample, retire finished rows, repeat. The load-bearing property
-is WHERE admission happens: between every tick (token granularity), so
-a new request starts decoding the moment a batch slot and KV blocks
-exist instead of waiting for the whole current batch to drain.
+loop: start ONE decode tick, retire the tick before it (sample, finish
+the rows that ended), take the first tokens of the prefills started a
+loop ago, start the prefills of waiting sequences, repeat. The
+load-bearing property is WHERE admission happens: between every tick
+(token granularity), so a new request starts decoding the moment a batch
+slot and KV blocks exist instead of waiting for the whole current batch
+to drain.
 
-Invariant per sequence: ``ctx`` is prompt + every sampled token, and
-``cached`` counts how many of ctx's K/V live in the arena. Prefill
-caches all of ctx at once and samples token ``len(ctx)``; each tick
-feeds ``ctx[cached]`` at position ``cached`` and samples the next.
-Eviction (KV-block pressure) just frees the blocks and sets
-``cached = 0`` — on re-admission the sequence re-prefills its whole ctx
-and continues, so a greedy sequence is reproducible across evictions.
+One tick in flight. The device runs what it is handed in order, and a
+call returns before the device has run it, so the loop hands over tick
+N+1 before it waits for tick N's ids: the host's own work for tick N
+(the wait, the copy down, sampling, finishing, admission) happens while
+the chip runs tick N+1. What tick N+1 needs of tick N is each row's new
+token, and for a greedy row that is the argmax the tick already took on
+the device: `DecodeEngine.start_tick(..., after=)` selects it there. The
+rest the host knows beforehand: a row that reaches `max_tokens` or the
+context's end with tick N's token is left out of tick N+1; a row that
+tick N ends on a stop id is found out one tick late, and its row of tick
+N+1 is computed and discarded (`wasted_rows`). A prefill is started
+behind the tick in flight and its logits are fetched a loop later, after
+the next tick has been queued behind it, so an admission does not drain
+the device either. The choice is made a tick at a time from the rows
+themselves: a row at a temperature needs its logits on the host before
+its next token exists, so while one is running (or the scheduler is
+closing) every tick is retired before the next is composed, as ticks
+always were before (`mode="serial"` of `dl4j_decode_ticks_total`).
+
+Invariant per sequence: ``ctx`` is prompt + every sampled token that has
+reached the host, and ``cached`` counts how many of ctx's K/V live in
+the arena or will when the steps in flight have run. Prefill caches all
+of ctx at once and samples token ``len(ctx)``; each tick feeds token
+``cached`` at position ``cached`` (``ctx[cached]``, or the id still on
+the device) and samples the next. Eviction (KV-block pressure) just
+frees the blocks and sets ``cached = 0`` — on re-admission the sequence
+re-prefills its whole ctx and continues, so a greedy sequence is
+reproducible across evictions.
 
 Batch composition per tick goes through the serving batcher's
 `FlushEma` (per-bucket tick-wall-time EMAs): with `avail` live rows it
@@ -52,7 +75,7 @@ class _Seq:
     __slots__ = ("sid", "ctx", "prompt_len", "max_tokens", "temperature",
                  "stop_ids", "rng", "blocks", "cached", "event", "result",
                  "error", "trace", "submitted_at", "enqueued_at",
-                 "first_token_at")
+                 "first_token_at", "epoch", "prefill")
 
     def __init__(self, sid, prompt, max_tokens, temperature, stop_ids, seed,
                  trace=None):
@@ -65,6 +88,8 @@ class _Seq:
         self.rng = np.random.default_rng(sid if seed is None else seed)
         self.blocks: List[int] = []
         self.cached = 0                 # ctx tokens whose K/V are cached
+        self.epoch = 0                  # times its blocks were given up
+        self.prefill: Optional[_Prefill] = None     # started, not fetched
         self.event = threading.Event()
         self.result: Optional[Dict] = None
         self.error: Optional[Exception] = None
@@ -76,6 +101,34 @@ class _Seq:
     @property
     def generated(self) -> List[int]:
         return self.ctx[self.prompt_len:]
+
+
+class _Prefill:
+    """A sequence's prefill in flight: what `start_prefill` returned, and
+    what the admission already knows for the `dl4j/sched/admit` span that
+    its fetch will write."""
+    __slots__ = ("started", "reserve", "t_pop", "t0", "t1", "queue_wait")
+
+    def __init__(self, started, reserve, t_pop, t0, queue_wait):
+        self.started, self.reserve = started, reserve
+        self.t_pop, self.queue_wait = t_pop, queue_wait
+        self.t0, self.t1 = t0, time.perf_counter()     # around start_prefill
+
+
+class _Tick:
+    """A tick in flight: what `start_tick` returned, its rows (each with
+    the epoch it had: a row whose sequence has given up its blocks since
+    is discarded), each row's place by sequence id, and what the
+    `dl4j/sched/tick` span of its retirement will say."""
+    __slots__ = ("started", "rows", "where", "reserve", "t0", "t1",
+                 "overlapped", "device_ids")
+
+    def __init__(self, started, batch, reserve, t0, overlapped, device_ids):
+        self.started, self.reserve = started, reserve
+        self.t0, self.t1 = t0, time.perf_counter()     # around start_tick
+        self.rows = [(s, s.epoch) for s in batch]
+        self.where = {s.sid: i for i, s in enumerate(batch)}
+        self.overlapped, self.device_ids = overlapped, device_ids
 
 
 class GenerationScheduler:
@@ -111,8 +164,11 @@ class GenerationScheduler:
         self._ids = itertools.count(1)
         self._rotate = 0
         self._version = None
+        self._flight: Optional[_Tick] = None    # the tick not yet retired
+        # when the host last saw the device's stream reach a step's end
+        self._seen = 0.0
         self._evictions = self._prefills = self._ticks = 0
-        self._tokens_c = self._admit_c = self._evict_c = None
+        self._tokens_c = self._admit_c = self._evict_c = self._ticks_c = None
         self._phase_h = self._host_h = self._queue_h = None
         self._first_h = self._rows_h = None
         if metrics is not None:
@@ -126,9 +182,16 @@ class GenerationScheduler:
                 "dl4j_decode_evictions_total",
                 "sequences preempted for KV-block pressure",
                 labels=("model",))
+            self._ticks_c = metrics.counter(
+                "dl4j_decode_ticks_total",
+                "decode ticks by how they were started: overlapped (with "
+                "the tick before still in flight) or serial",
+                labels=("model", "mode"))
             self._phase_h = metrics.histogram(
                 "dl4j_decode_phase_seconds",
-                "wall seconds per compiled generation step",
+                "wall seconds the scheduler thread spent on a compiled "
+                "generation step: starting it and waiting for it, less "
+                "what it did between the two with the step in flight",
                 labels=("model", "phase"))
             self._host_h = metrics.histogram(
                 "dl4j_decode_host_seconds",
@@ -194,10 +257,22 @@ class GenerationScheduler:
         self._worker.join()
 
     # -- worker side -----------------------------------------------------
-    def _finish(self, seq: _Seq, reason: str):
-        t0 = time.perf_counter()
+    def _release(self, seq: _Seq):
+        """Give up seq's blocks (and its state slot, which goes with the
+        first) and whatever of it is in flight: a started prefill is
+        dropped, a row of the tick in flight is discarded at the tick's
+        retirement (`_Tick.rows` holds the epoch). The tick in flight may
+        still read and write these blocks; that is safe, because whoever
+        gets them next writes them through a step dispatched later, and
+        the device runs its steps in the order they were dispatched."""
         self.pool.release(seq.blocks)
         seq.blocks = []
+        seq.epoch += 1
+        seq.prefill = None
+
+    def _finish(self, seq: _Seq, reason: str):
+        t0 = time.perf_counter()
+        self._release(seq)
         seq.result = {"tokens": seq.generated, "finish_reason": reason,
                       "prompt_tokens": seq.prompt_len,
                       "generated_tokens": len(seq.generated)}
@@ -210,8 +285,7 @@ class GenerationScheduler:
         seq.event.set()
 
     def _fail(self, seq: _Seq, err: Exception):
-        self.pool.release(seq.blocks)
-        seq.blocks = []
+        self._release(seq)
         seq.error = err
         seq.event.set()
 
@@ -250,14 +324,21 @@ class GenerationScheduler:
 
     def _evict_one(self, keep: _Seq) -> bool:
         """Preempt the NEWEST running sequence other than `keep` back to
-        the waiting queue (its blocks freed; it will re-prefill)."""
+        the waiting queue (its blocks freed; it will re-prefill; what the
+        steps in flight compute for it is discarded)."""
         victims = [s for s in self._running if s is not keep]
         if not victims:
             return False
         victim = max(victims, key=lambda s: s.sid)
+        if victim.prefill is not None:
+            # owed the token its prefill makes: taken first (this waits for
+            # the device), or two sequences that do not fit side by side
+            # would preempt each other for ever, neither getting anywhere
+            self._join_one(victim)
+            if not victim.blocks:       # it ended there: its blocks are free
+                return True
         self._running.remove(victim)
-        self.pool.release(victim.blocks)
-        victim.blocks = []
+        self._release(victim)
         victim.cached = 0
         victim.enqueued_at = time.perf_counter()   # re-queued: wait restarts
         with self._lock:
@@ -300,61 +381,85 @@ class GenerationScheduler:
                        free_blocks=self.pool.free_blocks())
         for seq in list(self._running):
             self._running.remove(seq)
-            self.pool.release(seq.blocks)
-            seq.blocks = []
+            self._release(seq)
             seq.cached = 0
             seq.enqueued_at = time.perf_counter()
             with self._lock:
                 self._waiting.appendleft(seq)
 
     def _admit(self, v):
+        """Start the prefill of every waiting sequence there is room for,
+        behind the tick in flight; `_join` fetches their logits a loop
+        later, when the next tick is queued behind them."""
         cap = self.engine.decode_buckets[-1]
         while True:
             with self._lock:
                 if not self._waiting or len(self._running) >= cap:
                     return
                 seq = self._waiting.popleft()
-            with _span("dl4j/sched/admit", sid=seq.sid,
-                       prompt_len=seq.prompt_len) as admit:
-                self._admit_one(v, seq, admit)
-            self._observe(admit)
+            t_pop = time.perf_counter()
+            queue_wait = t_pop - seq.enqueued_at    # (re-)enqueue -> here
+            if self._queue_h is not None:
+                self._queue_h.observe(queue_wait, model=self.name)
+            with _span("dl4j/sched/reserve").hold() as reserve:
+                evicted = self._evictions
+                ok = self._reserve(seq, len(seq.ctx))
+                reserve.set(evicted=self._evictions - evicted)
+            self._observe(reserve)
+            if not ok:
+                continue
+            t0 = time.perf_counter()
+            try:
+                started = self.engine.start_prefill(
+                    v, self.pool, seq.ctx, seq.blocks, observe=self._observe)
+            except Exception as e:          # noqa: BLE001 - fail the seq
+                self._fail(seq, e)
+                continue
+            seq.prefill = _Prefill(started, reserve, t_pop, t0, queue_wait)
+            seq.cached = len(seq.ctx)
+            self._running.append(seq)
 
-    def _admit_one(self, v, seq: _Seq, admit):
-        """Reserve, prefill and sample the first token of one sequence
-        taken off the queue, inside its `dl4j/sched/admit` span."""
-        t_pop = admit.t0 * 1e-9
-        queue_wait = t_pop - seq.enqueued_at   # enqueue (or re-queue) -> here
-        admit.set(queue_wait_s=queue_wait)
-        if self._queue_h is not None:
-            self._queue_h.observe(queue_wait, model=self.name)
+    def _join(self):
+        """The first token of every sequence whose prefill was started a
+        loop ago: from here on it is a row of the ticks."""
+        for seq in [s for s in self._running if s.prefill is not None]:
+            self._join_one(seq)
+
+    def _join_one(self, seq: _Seq):
+        with _span("dl4j/sched/admit", sid=seq.sid,
+                   prompt_len=seq.prompt_len) as admit:
+            pre, seq.prefill = seq.prefill, None
+            self._first_token(seq, pre, admit)
+        self._observe(admit)
+
+    def _first_token(self, seq: _Seq, pre: _Prefill, admit):
+        """Fetch the prefill's logits and sample the first token, inside
+        the sequence's `dl4j/sched/admit` span (which also gets the spans
+        that timed the prefill's start)."""
+        admit.set(queue_wait_s=pre.queue_wait)
         if seq.trace is not None:
-            seq.trace.emit("queue_wait", seq.enqueued_at, t_pop,
+            seq.trace.emit("queue_wait", seq.enqueued_at, pre.t_pop,
                            model=self.name, sid=seq.sid,
                            ctx_len=len(seq.ctx))
-        with _span("dl4j/sched/reserve") as reserve:
-            evicted = self._evictions
-            ok = self._reserve(seq, len(seq.ctx))
-            reserve.set(evicted=self._evictions - evicted)
-        self._observe(reserve)
-        if not ok:
-            return
-        t0 = time.perf_counter()
+        t_in = time.perf_counter()
+        pre.reserve.write()
         try:
-            logits = self.engine.run_prefill(v, self.pool, seq.ctx,
-                                             seq.blocks,
-                                             observe=self._observe)
+            logits = self.engine.finish_prefill(pre.started,
+                                                observe=self._observe)
         except Exception as e:          # noqa: BLE001 - fail the seq
+            self._running.remove(seq)
             self._fail(seq, e)
             return
-        t1 = time.perf_counter()
-        if self._phase_h is not None:
-            self._phase_h.observe(t1 - t0, model=self.name, phase="prefill")
+        t1 = self._seen = time.perf_counter()
+        if self._phase_h is not None:       # as a tick's: start and wait
+            self._phase_h.observe(pre.t1 - pre.t0 + t1 - t_in,
+                                  model=self.name, phase="prefill")
         # the ordinal the readers select by: the phase histogram's count
         # after this prefill
         self._prefills += 1
         admit.set(prefill=self._prefills)
         if seq.trace is not None:
-            seq.trace.emit("prefill", t0, t1, model=self.name,
+            seq.trace.emit("prefill", pre.t0, t1, model=self.name,
                            tokens=len(seq.ctx))
         if self._admit_c is not None:
             self._admit_c.inc(model=self.name)
@@ -365,7 +470,6 @@ class GenerationScheduler:
                        prompt_len=seq.prompt_len,
                        blocks=len(seq.blocks),
                        free_blocks=self.pool.free_blocks())
-        seq.cached = len(seq.ctx)
         with _span("dl4j/sched/sample") as sample:
             reason = self._append_sample(seq, logits)
             sample.set(finished=int(reason is not None))
@@ -380,59 +484,124 @@ class GenerationScheduler:
                 seq.trace.emit("first_token", seq.submitted_at,
                                seq.first_token_at, model=self.name,
                                sid=seq.sid)
-        if reason is None:
-            self._running.append(seq)
-        else:
+        if reason is not None:
+            self._running.remove(seq)
             self._finish(seq, reason)
 
     def _tick(self, v):
-        if not self._running:
-            return
-        with _span("dl4j/sched/tick") as tick:
-            self._tick_rows(v, tick)
-        self._observe(tick)
+        """Start the next tick and retire the one in flight, in the order
+        the running rows allow: all greedy, the next tick goes first, its
+        tokens taken on the device from the one in flight; with a row at
+        a temperature among them (its next token does not exist until the
+        host has sampled it), or the scheduler closing, each tick is
+        retired before another is composed."""
+        overlap = not self._closed and all(
+            s.temperature <= 0.0 for s in self._running)
+        if not overlap:
+            self._drain()
+        prev = self._flight
+        self._flight = self._start_tick(v, prev)
+        if prev is not None:
+            self._retire(prev)
+        if not overlap:
+            self._drain()
 
-    def _tick_rows(self, v, tick):
+    def _drain(self):
+        if self._flight is not None:
+            prev, self._flight = self._flight, None
+            self._retire(prev)
+
+    def _ends_in(self, prev: _Tick, seq: _Seq) -> bool:
+        """Whether `prev`, in flight, gives seq its last token for sure:
+        `_append_sample`'s length and context ends, known beforehand (a
+        stop id is not)."""
+        return seq.sid in prev.where and (
+            len(seq.ctx) + 1 - seq.prompt_len >= seq.max_tokens
+            or len(seq.ctx) + 1 >= self.engine.max_context)
+
+    def _start_tick(self, v, prev: Optional[_Tick]) -> Optional[_Tick]:
+        """Compose and dispatch a tick of the rows that go on, `prev` (the
+        tick in flight, if any) unseen: None where no row does."""
+        live = [s for s in self._running if s.prefill is None
+                and not (prev is not None and self._ends_in(prev, s))]
+        if not live:
+            return None
         # room for each row's next slot BEFORE composing the batch, so
         # an eviction never invalidates a row already in the padded step
-        with _span("dl4j/sched/reserve") as reserve:
+        with _span("dl4j/sched/reserve").hold() as reserve:
             evicted = self._evictions
-            for seq in list(self._running):
-                if seq in self._running:    # _reserve may evict/fail rows
+            for seq in live:
+                if seq.blocks:              # _reserve may evict/fail rows
                     self._reserve(seq, seq.cached + 1)
             reserve.set(evicted=self._evictions - evicted)
         self._observe(reserve)
-        if not self._running:
-            return
-        avail = len(self._running)
+        live = [s for s in live if s.blocks]
+        if not live:
+            return None
+        avail = len(live)
         rows = self._ema.pick_rows(avail, list(self.engine.decode_buckets),
                                    self.engine.decode_buckets[-1])
-        order = (self._running[self._rotate % avail:]
-                 + self._running[:self._rotate % avail])
+        order = live[self._rotate % avail:] + live[:self._rotate % avail]
         batch = order[:rows]
         self._rotate += rows
         bucket = self.engine.decode_bucket_for(len(batch))
+        # a row of the tick in flight takes its token from that tick's
+        # ids, on the device; any other row's is on the host
+        where = prev.where if prev is not None else {}
+        tokens = [-(where[s.sid] + 1) if s.sid in where else s.ctx[s.cached]
+                  for s in batch]
         t0 = time.perf_counter()
-        logits = self.engine.run_tick(
-            v, self.pool, [s.ctx[s.cached] for s in batch],
-            [s.cached for s in batch], [s.blocks for s in batch], bucket,
-            observe=self._observe,
-            greedy=all(s.temperature <= 0.0 for s in batch))
-        dt = time.perf_counter() - t0
+        started = self.engine.start_tick(
+            v, self.pool, tokens, [s.cached for s in batch],
+            [s.blocks for s in batch], bucket, observe=self._observe,
+            after=prev.started if prev is not None else None)
+        for seq in batch:
+            seq.cached += 1
+        return _Tick(started, batch, reserve, t0, int(prev is not None),
+                     sum(t < 0 for t in tokens))
+
+    def _retire(self, t: _Tick):
+        with _span("dl4j/sched/tick") as tick:
+            self._retire_rows(t, tick)
+        self._observe(tick)
+
+    def _retire_rows(self, t: _Tick, tick):
+        """Wait for a started tick, sample its rows and finish those that
+        ended, inside the `dl4j/sched/tick` span that carries its ordinal
+        (and gets the spans that timed its start)."""
+        t_in = time.perf_counter()
+        t.reserve.write()
+        out = self.engine.finish_tick(
+            t.started, observe=self._observe,
+            greedy=all(s.temperature <= 0.0 for s, _ in t.rows))
+        # what the flush policy is fed, the same for every bucket: from
+        # the later of the tick's dispatch and the last end of a step the
+        # host saw (the tick before it, or a prefill between them) to its
+        # ids on the host. With a tick in flight that is the device's time
+        # for this tick alone; retired at once, all of start and finish.
+        now = time.perf_counter()
+        dt, self._seen = now - max(t.t0, self._seen), now
+        bucket = t.started.bucket
         self._ema.observe(bucket, dt)
         if self._phase_h is not None:
-            self._phase_h.observe(dt, model=self.name, phase="decode")
-            self._rows_h.observe(len(batch), model=self.name)
+            # the scheduler thread's own time for the tick: starting it,
+            # and the wait for it that was left when it was retired
+            self._phase_h.observe(t.t1 - t.t0 + now - t_in, model=self.name,
+                                  phase="decode")
+            self._rows_h.observe(len(t.rows), model=self.name)
+            self._ticks_c.inc(model=self.name,
+                              mode="overlapped" if t.overlapped else "serial")
         # the ordinal the readers select by: the phase histogram's count
         # after this tick. A request's ticks are found by membership.
         self._ticks += 1
-        tick.set(tick=self._ticks, rows=len(batch), bucket=bucket,
-                 requests=[s.trace.trace_id for s in batch
-                           if s.trace is not None])
         with _span("dl4j/sched/sample") as sample:
-            finished = 0
-            for seq, row in zip(batch, logits):
-                seq.cached += 1
+            finished = wasted = 0
+            for (seq, epoch), row in zip(t.rows, out):
+                if seq.epoch != epoch:
+                    # computed for a sequence that has ended since (a stop
+                    # id, met one tick late) or was evicted: discarded
+                    wasted += 1
+                    continue
                 reason = self._append_sample(seq, row)
                 if reason is not None:
                     self._running.remove(seq)
@@ -440,6 +609,11 @@ class GenerationScheduler:
                     finished += 1
             sample.set(finished=finished)
         self._observe(sample)
+        tick.set(tick=self._ticks, rows=len(t.rows), bucket=bucket,
+                 overlapped=t.overlapped, device_ids=t.device_ids,
+                 wasted_rows=wasted,
+                 requests=[s.trace.trace_id for s, _ in t.rows
+                           if s.trace is not None])
 
     def _resolve_version(self):
         """The version this scheduler's arm serves this tick. Canary
@@ -453,10 +627,12 @@ class GenerationScheduler:
         return self.registry.get(self.name)
 
     def _poll(self):
-        """(idle, closed, waiting) under the lock."""
+        """(idle, closed, waiting) under the lock. A tick in flight is
+        work left: its rows may all have ended (a stop id)."""
         with self._lock:
             waiting = len(self._waiting)
-            return not waiting and not self._running, self._closed, waiting
+            idle = not waiting and not self._running and self._flight is None
+            return idle, self._closed, waiting
 
     def _run(self):
         while True:
@@ -482,11 +658,14 @@ class GenerationScheduler:
         try:
             v = self._resolve_version()
             if self._version is not v:
+                self._drain()
                 self._flush_running()
                 self._version = v
-            self._admit(v)
             self._tick(v)
+            self._join()
+            self._admit(v)
         except Exception as e:          # noqa: BLE001 - never die quietly
+            self._flight = None
             for seq in list(self._running):
                 self._running.remove(seq)
                 self._fail(seq, e)

@@ -59,19 +59,36 @@ _FIELDS = ("seq", "ph", "name", "t0", "t1", "tid", "thread", "id", "parent",
 
 class _Span:
     """One open span. After the block: `t0`, `t1` (perf_counter_ns) and
-    `seconds`; `set()` adds attributes known only at the end."""
+    `seconds`; `set()` adds attributes known only at the end. A span that
+    was `hold()`-ed is timed by its block and written by `write()`, later,
+    as a child of the span open THEN: work that was started in one place
+    and is accounted for where it is finished (a decode tick's dispatch,
+    under the span of the loop that retires the tick)."""
 
     __slots__ = ("_tracer", "name", "attrs", "id", "parent", "t0", "t1",
-                 "_stack", "_ann")
+                 "_stack", "_ann", "_held")
 
     def __init__(self, tracer, name, attrs):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self.id = self.parent = self._stack = None
+        self._held = False
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+
+    def hold(self):
+        self._held = True
+        return self
+
+    def write(self):
+        """Write a held span, once its block has closed, under the span
+        open on the calling thread now."""
+        if self.id is not None:
+            tr = self._tracer
+            tr._write("X", self.name, self.t0, self.t1, self.id,
+                      tr.current_span(), None, self.attrs)
 
     @property
     def seconds(self) -> float:
@@ -95,8 +112,9 @@ class _Span:
         if self._stack is not None:
             self._ann.__exit__(*exc)
             self._stack.pop()
-            self._tracer._write("X", self.name, self.t0, self.t1, self.id,
-                                self.parent, None, self.attrs)
+            if not self._held:
+                self._tracer._write("X", self.name, self.t0, self.t1,
+                                    self.id, self.parent, None, self.attrs)
         return False
 
 

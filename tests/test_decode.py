@@ -91,6 +91,17 @@ def served():
     sched.stop()
 
 
+@pytest.fixture
+def span_log():
+    """A fresh process-wide span log for one test."""
+    from deeplearning4j_tpu.telemetry import Tracer, install_tracer
+
+    log = Tracer()
+    prev = install_tracer(log)
+    yield log
+    install_tracer(prev)
+
+
 # ---------------------------------------------------------------------------
 # kernels: explicit per-row valid length
 # ---------------------------------------------------------------------------
@@ -196,7 +207,13 @@ def test_scheduler_concurrent_clients_all_greedy_exact(served):
     assert got == want
 
 
-def test_stop_token_and_context_cap(served):
+def _ticks(log):
+    """The attributes of the log's `dl4j/sched/tick` spans, in order."""
+    return [e["attrs"] for e in log.snapshot()
+            if e["ph"] == "X" and e["name"] == "dl4j/sched/tick"]
+
+
+def test_stop_token_and_context_cap(served, span_log):
     reg, model, sched = served
     prompt = [3, 7, 1, 4, 9, 2]
     full = eager_greedy(model, prompt, 10)
@@ -205,6 +222,17 @@ def test_stop_token_and_context_cap(served):
     assert res["finish_reason"] == "stop"
     # cut at the stop token's FIRST occurrence (greedy chains repeat)
     assert res["tokens"] == full[:full.index(stop)]
+    # the stop id is met one tick late: the sequence's row of the tick that
+    # was already in flight is computed and discarded, and nothing of it
+    # is left held once that tick has been retired
+    deadline = time.monotonic() + 60
+    while sum(t["wasted_rows"] for t in _ticks(span_log)) < 1:
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+    ticks = _ticks(span_log)
+    assert [t["wasted_rows"] for t in ticks] == [0] * (len(ticks) - 1) + [1]
+    assert len(ticks) == len(res["tokens"]) + 1
+    assert sched.pool.used_blocks() == sched.pool.used_slots() == 0
     res = sched.submit(prompt, max_tokens=10_000, timeout=300)
     assert res["finish_reason"] == "context"
     assert len(prompt) + res["generated_tokens"] == TMAX
@@ -224,9 +252,9 @@ def test_temperature_sampling_seeded(served):
 @pytest.mark.parametrize("rows", [1, 2])
 def test_greedy_tick_returns_the_argmax_of_the_logits_tick(served, rows):
     """`run_tick(greedy=True)` hands back the rows' argmax, taken on the
-    device by the executable built beside the bucket's tick: the same
-    tokens as np.argmax over the logits of the same tick (exact: the
-    comparison of float32 values rounds nothing)."""
+    device by the tick's own executable: the same tokens as np.argmax
+    over the logits of the same tick (exact: the comparison of float32
+    values rounds nothing)."""
     reg, model, sched = served
     eng, v = sched.engine, reg.get("gen")
     prompts = [[5, 11, 2, 29, 7], [1, 2, 3]][:rows]
@@ -246,7 +274,7 @@ def test_greedy_tick_returns_the_argmax_of_the_logits_tick(served, rows):
     np.testing.assert_array_equal(tokens, logits.argmax(axis=1))
 
 
-def test_greedy_row_beside_a_sampled_row_stays_exact(served):
+def test_greedy_row_beside_a_sampled_row_stays_exact(served, span_log):
     """A tick with one row at a temperature goes by the logits for every
     row: the greedy neighbour still gets its single-sequence answer."""
     reg, model, sched = served
@@ -263,7 +291,13 @@ def test_greedy_row_beside_a_sampled_row_stays_exact(served):
     for t in threads:
         t.join()
     assert got["greedy"]["tokens"] == eager_greedy(model, prompt, 8)
-    assert all(0 <= t < VOCAB for t in got["sampled"]["tokens"])
+    # the sampled row's answer is its own, whoever stood beside it
+    assert got["sampled"]["tokens"] == sched.submit(
+        prompt, max_tokens=8, timeout=300, temperature=0.9, seed=3)["tokens"]
+    # and while it ran no tick was started ahead of its predecessor: its
+    # next token does not exist before the host has sampled it
+    both = [t for t in _ticks(span_log) if t["rows"] == 2]
+    assert all(t["overlapped"] == 0 and t["device_ids"] == 0 for t in both)
 
 
 # ---------------------------------------------------------------------------
@@ -430,6 +464,12 @@ def test_eviction_resume_greedy_exact_and_counted():
             labels=("model",)).value(model="gen")
         assert evicted >= 1, "pressure never forced an eviction"
         assert sched.pool.used_blocks() == 0
+        # the rows were greedy, so the evictions met ticks in flight
+        ticks = metrics.counter("dl4j_decode_ticks_total", "",
+                                labels=("model", "mode"))
+        assert ticks.value(model="gen", mode="overlapped") >= 1
+        assert sum(ticks.values().values()) == metrics.get(
+            "dl4j_decode_phase_seconds").count(model="gen", phase="decode")
     finally:
         sched.stop()
 
@@ -585,17 +625,6 @@ def test_registry_decode_and_fwd_cache_keys_disjoint():
 # ---------------------------------------------------------------------------
 # a version is resolved once (ISSUE 35)
 # ---------------------------------------------------------------------------
-
-@pytest.fixture
-def span_log():
-    """A fresh process-wide span log for one test."""
-    from deeplearning4j_tpu.telemetry import Tracer, install_tracer
-
-    log = Tracer()
-    prev = install_tracer(log)
-    yield log
-    install_tracer(prev)
-
 
 @pytest.fixture
 def sig_calls(monkeypatch):
@@ -755,6 +784,143 @@ def test_flush_ema_bucket_extrapolation():
     ema3.observe(4, 1e-3)
     ema3.observe(8, 1.1e-3)     # padding up is nearly free
     assert ema3.pick_rows(5, [1, 2, 4, 8], 8) == 5
+
+
+# ---------------------------------------------------------------------------
+# one tick in flight (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def counted():
+    """(registry, model, scheduler, metrics) of a scheduler of its own,
+    with the `/metrics` families."""
+    from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
+
+    reg = ModelRegistry()
+    model = lm(seed=13)
+    reg.register("gen", model, buckets=(1,))
+    metrics = MetricsRegistry()
+    sched = GenerationScheduler(reg, "gen", block_len=4,
+                                decode_buckets=(1, 2, 4), metrics=metrics)
+    yield reg, model, sched, metrics
+    sched.stop()
+
+
+def _modes(metrics):
+    ticks = metrics.counter("dl4j_decode_ticks_total", "",
+                            labels=("model", "mode"))
+    return {m: int(ticks.value(model="gen", mode=m))
+            for m in ("overlapped", "serial")}
+
+
+@pytest.mark.parametrize("temperature,mode", [(0.0, "overlapped"),
+                                              (0.8, "serial")])
+def test_ticks_by_mode_add_up_to_the_ticks_run(counted, span_log,
+                                               temperature, mode):
+    """A greedy sequence's ticks are each started from the ids of the one
+    before, which never visit the host, but for the first; a sequence at a
+    temperature has every tick retired before the next is composed. The
+    spans' `overlapped`, the `/metrics` counter and the phase histogram
+    count the same ticks."""
+    reg, model, sched, metrics = counted
+    res = sched.submit([3, 7, 1, 4], max_tokens=9, temperature=temperature,
+                       seed=5, timeout=300)
+    assert res["generated_tokens"] == 9
+    if temperature == 0.0:
+        assert res["tokens"] == eager_greedy(model, [3, 7, 1, 4], 9)
+    ticks = _ticks(span_log)
+    assert [t["tick"] for t in ticks] == list(range(1, 9))
+    late = 1 if mode == "overlapped" else 0
+    assert [t["overlapped"] for t in ticks] == [0] + [late] * 7
+    assert [t["device_ids"] for t in ticks] == [0] + [late] * 7
+    assert sum(t["wasted_rows"] for t in ticks) == 0
+    modes = _modes(metrics)
+    assert modes["overlapped"] == sum(t["overlapped"] for t in ticks)
+    assert sum(modes.values()) == 8 == metrics.get(
+        "dl4j_decode_phase_seconds").count(model="gen", phase="decode")
+    assert sched.pool.used_blocks() == 0
+
+
+def test_a_swap_with_a_tick_in_flight_restarts_on_the_new_weights(counted):
+    """A version swapped in mid-sequence: the tick in flight is retired
+    under the old weights, the sequence re-prefills under the new ones and
+    goes on: its tokens are the old model's greedy tokens up to some point
+    and the new model's continuation of exactly those from there."""
+    reg, model, sched, metrics = counted
+    new, prompt, got = lm(seed=14), [3, 7, 1, 4], {}
+    t = threading.Thread(target=lambda: got.update(
+        sched.submit(prompt, max_tokens=24, timeout=300)))
+    t.start()
+    deadline = time.monotonic() + 300
+    while sum(_modes(metrics).values()) < 3:        # ticks are running
+        assert time.monotonic() < deadline and t.is_alive()
+        time.sleep(0.001)
+    reg.swap("gen", new, buckets=(1,))
+    t.join()
+    tokens, old = got["tokens"], eager_greedy(model, prompt, 24)
+    assert len(tokens) == 24
+    k = next(i for i in range(25) if tokens[:i] != old[:i]) - 1
+    assert 3 <= k < 24, "the swap never took hold"
+    assert tokens[k:] == eager_greedy(new, prompt + tokens[:k], 24 - k)
+    assert sched.pool.used_blocks() == 0
+
+
+def test_a_tick_in_flight_that_fails_fails_its_rows_and_no_more(counted,
+                                                                monkeypatch):
+    """An error that the wait for a tick raises reaches the rows' callers,
+    the tick queued behind it is dropped with it, and the scheduler serves
+    the next request as if nothing had been."""
+    reg, model, sched, metrics = counted
+    real, calls = sched.engine.finish_tick, []
+
+    def failing(started, *a, **kw):
+        calls.append(started)
+        if len(calls) == 3:
+            raise RuntimeError("the device lost the tick")
+        return real(started, *a, **kw)
+
+    monkeypatch.setattr(sched.engine, "finish_tick", failing)
+    with pytest.raises(RuntimeError, match="lost the tick"):
+        sched.submit([3, 7, 1, 4], max_tokens=9, timeout=300)
+    assert sched.pool.used_blocks() == 0
+    assert sched.submit([3, 7, 1, 4], max_tokens=9, timeout=300)[
+        "tokens"] == eager_greedy(model, [3, 7, 1, 4], 9)
+
+
+def test_run_tick_and_run_prefill_are_the_schedulers_executables(
+        counted, span_log):
+    """The two calls other code drives the engine by keep their arguments
+    and returns, and go through the executables the scheduler's ticks in
+    flight go through: after a served request they compile nothing."""
+    import inspect
+
+    reg, model, sched, metrics = counted
+    eng, v = sched.engine, reg.get("gen")
+    assert list(inspect.signature(eng.run_prefill).parameters) == [
+        "v", "pool", "prompt", "table", "observe"]
+    assert list(inspect.signature(eng.run_tick).parameters) == [
+        "v", "pool", "tokens", "positions", "tables", "bucket", "observe",
+        "greedy"]
+    prompt = [3, 7, 1, 4]
+    want = sched.submit(prompt, max_tokens=3, timeout=300)["tokens"]
+    built = lambda: [e["attrs"] for e in span_log.snapshot()
+                     if e["name"] in ("dl4j/engine/executable", "xla/compile")]
+    n_built = len(built())
+    assert n_built >= 2
+    pool = eng.new_pool()
+    table = pool.alloc(eng.spec.blocks_for(len(prompt) + 2))
+    logits = eng.run_prefill(v, pool, prompt, table)
+    assert logits.shape == (VOCAB,) and logits.dtype == np.float32
+    got = [int(np.argmax(logits))]
+    logits = eng.run_tick(v, pool, got, [len(prompt)], [table], bucket=1)
+    assert logits.shape == (1, VOCAB) and logits.dtype == np.float32
+    got.append(int(np.argmax(logits[0])))
+    ids = eng.run_tick(v, pool, [got[-1]], [len(prompt) + 1], [table],
+                       bucket=1, greedy=True)
+    assert ids.shape == (1,) and ids.dtype == np.int32
+    assert got + [int(ids[0])] == want
+    assert len(built()) == n_built
+    assert eng.decode_exec(v, 1) is eng.decode_exec(v, 1)
 
 
 # ---------------------------------------------------------------------------

@@ -140,7 +140,8 @@ def decode_stack():
 
 @pytest.mark.parametrize("phase,bucket,attention", [
     ("tick", 16, "gather"), ("tick", 16, "paged_kernel"),
-    ("prefill", 512, None)])
+    ("prefill", 512, None)] + [
+    ("served_tick", b, "paged_kernel") for b in (1, 2, 4, 8, 16)])
 def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
                                                        phase, bucket,
                                                        attention):
@@ -158,12 +159,15 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
     this one. The paged kernel reads the arena where it lies: no view, no
     head-split relayout, under 32 MiB of temporaries, and the kernel's 16
     page operands do not make XLA copy the arena it is about to scatter
-    into again."""
+    into again. The tick as it is served (`build_tick_fn`: the tokens
+    selected on the device from the last tick's ids, the argmax beside the
+    logits) is held to the same in every bucket of the served cell."""
     import functools
 
     from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
                                                           build_decode_fn,
-                                                          build_prefill_fn)
+                                                          build_prefill_fn,
+                                                          build_tick_fn)
 
     model, snapshot, spec = decode_stack
     on_chip = lambda tree: jax.tree_util.tree_map(
@@ -174,6 +178,9 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
     if phase == "tick":
         fn = functools.partial(build_decode_fn, attention=attention)
         args = (i32(bucket), i32(bucket), i32(bucket, w))
+    elif phase == "served_tick":
+        fn = functools.partial(build_tick_fn, rows_max=16, attention=attention)
+        args = (i32(16), i32(bucket), i32(bucket), i32(bucket, w))
     else:
         fn, args = build_prefill_fn, (i32(1, bucket), i32(1), i32(1, w))
     with jax.enable_x64(False):
@@ -204,6 +211,9 @@ def test_decode_steps_update_the_arena_in_place_on_v5e(one_chip, decode_stack,
         # neither the gathered view nor its heads split out
         assert "f32[1024,16,1024]" not in text
         assert "[16,1024,16,64]" not in text
+    if phase == "served_tick":      # (cache, ids [16], logits, ...)
+        assert [o.shape for o in compiled.out_info[1:3]] == [(16,),
+                                                             (bucket, 256)]
 
 
 def _nbytes(dtype: str, dims: str) -> int:
@@ -265,7 +275,8 @@ def hybrid_stack():
 
 @pytest.mark.parametrize("phase,bucket,attention", [
     ("tick", 64, "gather"), ("tick", 64, "paged_kernel"),
-    ("tick", 8, "paged_kernel"), ("prefill", 512, None)])
+    ("tick", 8, "paged_kernel"), ("prefill", 512, None),
+    ("served_tick", 64, "paged_kernel"), ("served_tick", 8, "paged_kernel")])
 def test_decode_steps_update_the_state_in_place_on_v5e(one_chip, hybrid_stack,
                                                        phase, bucket,
                                                        attention):
@@ -278,12 +289,13 @@ def test_decode_steps_update_the_state_in_place_on_v5e(one_chip, hybrid_stack,
     temporary of a quarter of the state, none copies a layer's leaf, and
     the outputs alias the inputs. The attention layer's tick through the
     paged kernel (grouped queries, bfloat16 pages) makes no view of its
-    pages."""
+    pages. The tick as it is served (`build_tick_fn`) is held to the same."""
     import functools
 
     from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
                                                           build_decode_fn,
-                                                          build_prefill_fn)
+                                                          build_prefill_fn,
+                                                          build_tick_fn)
 
     model, snapshot, spec = hybrid_stack
     on_chip = lambda tree: jax.tree_util.tree_map(
@@ -294,6 +306,9 @@ def test_decode_steps_update_the_state_in_place_on_v5e(one_chip, hybrid_stack,
     if phase == "tick":
         fn = functools.partial(build_decode_fn, attention=attention)
         args = (i32(bucket), i32(bucket), i32(bucket, w), i32(bucket))
+    elif phase == "served_tick":
+        fn = functools.partial(build_tick_fn, rows_max=64, attention=attention)
+        args = (i32(64), i32(bucket), i32(bucket), i32(bucket, w), i32(bucket))
     else:
         fn, args = build_prefill_fn, (i32(1, bucket), i32(1), i32(1, w), i32(1))
     with jax.enable_x64(False):
@@ -314,7 +329,7 @@ def test_decode_steps_update_the_state_in_place_on_v5e(one_chip, hybrid_stack,
     assert len(kernels) == (attention == "paged_kernel")
     if attention == "paged_kernel":     # no gathered view of the 16 pages
         assert f"bf16[{bucket},16,16,1024]" not in text
-    if (phase, bucket) == ("tick", 64):
+    if bucket == 64:
         # one fusion a layer gives the rows' output and the new leaf
         assert len(re.findall(
             r"= \(f32\[65,128,64\]\S*, f32\[65,128,64,128\]\S*\) fusion\(",

@@ -19,6 +19,7 @@ import importlib.util
 import json
 import sys
 import threading
+import time
 from pathlib import Path
 
 import jax
@@ -579,6 +580,35 @@ def test_eviction_and_re_prefill_reproduce_the_greedy_sequences():
     assert np.argmax(z, axis=1).tolist() == want[1]
 
 
+def test_a_stop_id_met_a_tick_late_frees_the_blocks_and_the_slot():
+    """The scheduler starts the next tick before it has seen this one's
+    ids, so a sequence that ends on a stop id has a row in the tick in
+    flight: that row still reads and writes the sequence's pages and state
+    slot, which are given up all the same (the next owner's prefill is
+    dispatched later). The caller gets the tokens before the stop id, and
+    a sequence admitted into the freed slot gets its own greedy tokens."""
+    config = tiny_config()
+    model = build(config)
+    registry = ModelRegistry(buckets=(1,))
+    registry.register("stop", model)
+    s = GenerationScheduler(registry, "stop", block_len=4,
+                            decode_buckets=(1, 2))
+    try:
+        prompt = [5, 6, 7, 8, 9]
+        full = s.submit(prompt, max_tokens=8, timeout=300)["tokens"]
+        stop = next(t for t in full[1:] if t != full[0])
+        got = s.submit(prompt, max_tokens=8, stop=[stop], timeout=300)
+        assert got["finish_reason"] == "stop"
+        assert got["tokens"] == full[:full.index(stop)]
+        assert s.submit(prompt, max_tokens=8, timeout=300)["tokens"] == full
+        deadline = time.monotonic() + 60
+        while s.pool.used_blocks() or s.pool.used_slots():
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+    finally:
+        s.stop()
+
+
 # ---------------------------------------------------------------------------
 # spans and counters; what the other families' engines keep
 # ---------------------------------------------------------------------------
@@ -653,10 +683,10 @@ def _gpt_model():
 @pytest.mark.parametrize("family", ["gpt", "longcat", "granite"])
 def test_executables_take_the_arguments_they_took(family, span_log):
     """A stack with no stateful layer has no new leaf, argument or upload:
-    its tick takes (weights, {"kv"}, tokens, positions, tables) and its
-    prefill (weights, {"kv"}, tokens, lengths, tables), as before; no slot
-    is kept for it and its spans carry none. Granite's take the rows' slots
-    as a sixth argument and a cache with "state"."""
+    its tick takes (weights, {"kv"}, the last tick's ids, tokens, positions,
+    tables) and its prefill (weights, {"kv"}, tokens, lengths, tables); no
+    slot is kept for it and its spans carry none. Granite's take the rows'
+    slots as one argument more and a cache with "state"."""
     model = {"gpt": _gpt_model, "longcat": _lcf_engine,
              "granite": lambda: build(tiny_config())}[family]()
     registry = ModelRegistry(buckets=(1,))
@@ -667,9 +697,10 @@ def test_executables_take_the_arguments_they_took(family, span_log):
     stateful = family == "granite"
     assert bool(engine.spec.state) == stateful
     assert engine.spec.state_slots == (3 if stateful else 0)
-    for exe in (engine.decode_exec(v, 2), engine.prefill_exec(v, 8)):
+    for exe, more in ((engine.decode_exec(v, 2), 1),
+                      (engine.prefill_exec(v, 8), 0)):
         (args, _) = exe.in_tree.unflatten(list(range(exe.in_tree.num_leaves)))
-        assert len(args) == (6 if stateful else 5)
+        assert len(args) == (6 if stateful else 5) + more
         assert set(args[1]) == ({"kv", "state"} if stateful else {"kv"})
     pool = engine.new_pool(MetricsRegistry())
     assert set(pool.cache) == ({"kv", "state"} if stateful else {"kv"})

@@ -222,9 +222,18 @@ def test_every_tick_holds_its_children(generated):
             "dl4j/sched/reserve", "dl4j/engine/tick.prepare",
             "dl4j/engine/tick.dispatch", "dl4j/engine/tick.fetch",
             "dl4j/sched/sample"]
-        assert all(t["t0"] <= k["t0"] <= k["t1"] <= t["t1"] for k in kids)
-        assert sum(k["t1"] - k["t0"] for k in kids) <= t["t1"] - t["t0"]
+        # the tick's span is its retirement: the wait and the sampling lie
+        # inside it, what started the tick was timed before and hung under
+        # it there (a tick may be started a loop before it is retired)
+        started, inside = kids[:3], kids[3:]
+        assert all(t["t0"] <= k["t0"] <= k["t1"] <= t["t1"] for k in inside)
+        assert all(k["t0"] <= k["t1"] <= t["t0"] for k in started)
+        assert [k["t0"] for k in started] == sorted(k["t0"] for k in started)
+        assert sum(k["t1"] - k["t0"] for k in inside) <= t["t1"] - t["t0"]
         assert t["parent"] in loops
+        assert t["attrs"]["overlapped"] in (0, 1)
+        assert 0 <= t["attrs"]["device_ids"] <= t["attrs"]["rows"]
+        assert t["attrs"]["wasted_rows"] == 0
         assert 1 <= t["attrs"]["rows"] <= t["attrs"]["bucket"] <= 2
         assert len(t["attrs"]["requests"]) == t["attrs"]["rows"]
         assert set(t["attrs"]["requests"]) <= set(trace_ids)
