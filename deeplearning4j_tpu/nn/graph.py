@@ -67,6 +67,15 @@ class ComputationGraph:
         return self.conf.topological_order
 
     def init(self, seed: Optional[int] = None) -> "ComputationGraph":
+        """Make the parameters from the seed, under the span `dl4j/nn/init`
+        (`layers`, `leaves`, `given` 0)."""
+        with _span("dl4j/nn/init", layers=len(self.layer_vertices),
+                   given=0) as span:
+            self._init(seed)
+            span.set(leaves=len(jax.tree_util.tree_leaves(self.params)))
+        return self
+
+    def _init(self, seed):
         from . import activations as _acts
         for layer in self.layer_vertices.values():
             if layer.activation is not None:  # fail fast on bad names
@@ -86,7 +95,6 @@ class ComputationGraph:
         self.updater_state = {
             name: self._layer_updater(self.conf.vertices[name]).init(p)
             for name, p in params.items()}
-        return self
 
     def _input_type_for(self, name):
         rec = self.conf.inferred_input_types.get(name)

@@ -137,12 +137,20 @@ class MultiLayerNetwork:
         layer, as `init` would make them) where the caller has them: no
         second set is then made, which a model that fills most of a chip
         has no room for. Their shapes are held to what the layers would
-        have made; their dtypes are the caller's."""
-        from . import activations as _acts
+        have made; their dtypes are the caller's. The span `dl4j/nn/init`
+        (`layers`, `leaves`, `given` 1 where `params` were passed) times
+        it."""
         if params is not None and len(params) != len(self.layers):
             raise ValueError(f"init(params=...) got {len(params)} entries "
                              f"for {len(self.layers)} layers")
-        given = params
+        with _span("dl4j/nn/init", layers=len(self.layers),
+                   given=int(params is not None)) as span:
+            self._init(seed, params)
+            span.set(leaves=len(jax.tree_util.tree_leaves(self.params)))
+        return self
+
+    def _init(self, seed, given):
+        from . import activations as _acts
         for layer in self.layers:
             if layer.activation is not None:  # fail fast on bad names
                 _acts.get(layer.activation)
@@ -178,7 +186,6 @@ class MultiLayerNetwork:
         self.state = tuple(state)
         self.updater_state = tuple(
             self._layer_updater(l).init(p) for l, p in zip(self.layers, params))
-        return self
 
     def _layer_updater(self, layer: LayerConf):
         return layer.updater or self.conf.conf.updater

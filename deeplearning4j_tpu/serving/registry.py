@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..datasets.pipeline import pad_rows
+from ..telemetry.runtime import span
 from .quantize import QuantizedTree, cast_tree, quantize_tree
 
 __all__ = ["ModelRegistry", "ServableVersion", "UnknownModelError",
@@ -329,10 +330,6 @@ class ModelRegistry:
             "XLA inference compiles per (model, bucket) — flat after "
             "startup/swap means the request path never cold-compiles",
             labels=("model", "bucket"))
-        self._compile_s = metrics.histogram(
-            "dl4j_serving_compile_seconds",
-            "wall seconds per serving AOT lower+compile",
-            labels=("model",))
         self._canary_req = metrics.counter(
             "dl4j_continual_canary_requests_total",
             "requests observed per arm while a canary is active",
@@ -614,15 +611,16 @@ class ModelRegistry:
             compiled = entry.compiled.get(key)
             if compiled is None:
                 x_spec = jax.ShapeDtypeStruct((b,) + shape, jnp.float32)
-                t0 = time.perf_counter()
-                try:
-                    compiled = fn.lower(snapshot.data, state,
-                                        x_spec).compile()
-                except ServingError:
-                    raise
-                except Exception as e:
-                    raise AotCompileError(name, b, e) from e
-                staged[key] = (compiled, time.perf_counter() - t0)
+                with span("dl4j/registry/compile", model=name, plane="fwd",
+                          label=str(b)) as built:
+                    try:
+                        compiled = fn.lower(snapshot.data, state,
+                                            x_spec).compile()
+                    except ServingError:
+                        raise
+                    except Exception as e:
+                        raise AotCompileError(name, b, e) from e
+                staged[key] = (compiled, built.seconds)
             runners[b] = compiled
         for key, (compiled, wall) in staged.items():
             entry.compiled[key] = compiled
@@ -653,22 +651,23 @@ class ModelRegistry:
         the compile-accounting bucket tag (e.g. "decode4", "prefill1x32")
         — one `record_aot` per cache miss, so the server-lifetime compile
         invariant ("one XLA compile per signature") is auditable from the
-        CompileWatcher report exactly like the stateless buckets."""
+        CompileWatcher report exactly like the stateless buckets. Each
+        build is the span `dl4j/registry/compile` (`model`, `plane`
+        "decode", `label`) in the span log."""
         with self._lock:
             entry = self._entries.setdefault(name, _Entry())
         with entry.swap_lock:
             compiled = entry.compiled.get(key)
             if compiled is None:
-                t0 = time.perf_counter()
-                compiled = build()
-                self._record_compile(name, label,
-                                     time.perf_counter() - t0)
+                with span("dl4j/registry/compile", model=name,
+                          plane=key[0], label=label) as built:
+                    compiled = build()
+                self._record_compile(name, label, built.seconds)
                 entry.compiled[key] = compiled
         return compiled
 
     def _record_compile(self, name: str, bucket, wall_s: float):
         self._compiles.inc(model=name, bucket=str(bucket))
-        self._compile_s.observe(wall_s, model=name)
         from ..telemetry import runtime
         tel = runtime.active()
         if tel is not None:

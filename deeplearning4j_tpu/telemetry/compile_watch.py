@@ -1,13 +1,15 @@
-"""XLA compile watcher: count compilations per jitted entry point, record
-compile wall time, warn on recompilation storms.
+"""XLA compile watcher: count compilations per jitted entry point, warn on
+recompilation storms.
 
 Every distinct argument signature (shapes/dtypes/static args) costs a full
 XLA trace+compile of the function — on TPU often seconds. Shape churn
 (ragged final batches, per-call scan lengths) silently multiplies that:
 throughput collapses with no error anywhere. The watcher detects a compile
 by the growth of the jitted function's executable cache (`_cache_size()`)
-across a call; the recorded wall time is the first-call wall time (trace +
-compile + first run — the latency the user actually experiences).
+across a call; `report()` keeps the first-call wall time (trace + compile
++ first run — the latency the user actually experiences). Where those
+seconds went is in the span log: `xla/trace`, `xla/lower`, `xla/compile`
+and `xla/cache_load` under the step that compiled (`telemetry/tracing.py`).
 
 `watch_compiles(fn, name)` wraps a jitted callable; with no active
 telemetry session the wrapper is a single global read + passthrough call.
@@ -19,8 +21,6 @@ import time
 import warnings
 import weakref
 from typing import Callable, Dict, List, Optional, Tuple
-
-from .tracing import tracer
 
 __all__ = ["CompileWatcher", "watch_compiles", "RecompilationStormWarning",
            "roster", "roster_names"]
@@ -97,15 +97,11 @@ class CompileWatcher:
         self._warned = set()
         self._sigs: Dict[str, set] = {}
         self.storm_threshold = max(1, int(storm_threshold))
-        self._compilations = self._compile_s = None
+        self._compilations = None
         if registry is not None:
             self._compilations = registry.counter(
                 "dl4j_xla_compilations_total",
                 "XLA compilations per jitted entry point",
-                labels=("function",))
-            self._compile_s = registry.histogram(
-                "dl4j_xla_compile_seconds",
-                "first-call wall seconds (trace + compile + run)",
                 labels=("function",))
 
     def call(self, name: str, fn: Callable, args, kwargs):
@@ -141,9 +137,6 @@ class CompileWatcher:
                 self._warned.add(name)
         if self._compilations is not None:
             self._compilations.inc(n, function=name)
-            self._compile_s.observe(wall_s, function=name)
-        tracer().instant(f"xla/compile:{name}", count=total,
-                         wall_s=round(wall_s, 4))
         if storm:
             warnings.warn(
                 f"XLA recompilation storm: '{name}' has compiled {total} "

@@ -2,7 +2,8 @@
 
 Every span the program records lands here: `telemetry.span(...)` on the
 fit and scheduler threads, `TraceContext.emit` for per-request spans with
-explicit timestamps, instants (`xla/compile`, epoch marks) and counter
+explicit timestamps, the compile path as jax reports it (`xla/trace`,
+`xla/lower`, `xla/compile`, `xla/cache_load`), instants and counter
 samples. The ring holds `capacity` events and overwrites the OLDEST, so
 a server that has run for an hour still shows its last minutes;
 `dropped_events` counts what was overwritten. The default, 262,144, is
@@ -294,14 +295,71 @@ def named_step(name: str, fn):
     return step
 
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# -- the compile path, as jax reports it -------------------------------------
+# jax records each of these when the step it times ENDS, on the thread that
+# did the work, inside the call that needed the executable: so the span open
+# there is the program step that asked, and end = now, start = now - the
+# duration. The persistent cache's events fire inside a backend compile, on
+# the same thread, before the compile's own duration: a hit (and its load),
+# or a request that used the cache and found nothing (a miss); "off" where
+# no cache directory is set. A served window meets none of this: its
+# executables are built before it opens (tests/test_decode.py holds it).
+_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla/lower",
+    "/jax/core/compile/backend_compile_duration": "xla/compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "xla/cache_load",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
 
 
-def _on_compile(event, duration, **_):
-    # jax calls this on the thread that compiled, inside the call that
-    # needed the executable: the open span there says which step it was
-    if event == _BACKEND_COMPILE:
-        _tracer.instant("xla/compile", seconds=float(duration))
+def _cache_state(t):
+    """The thread's record of the backend compile in progress: when its
+    first cache event came, what the cache said, and the id its span will
+    have (reserved by a load written before it)."""
+    state = getattr(t, "compiling", None)
+    if state is None:
+        state = t.compiling = {"t": time.perf_counter_ns(), "cache": "off",
+                               "id": None}
+    return state
 
 
-jax.monitoring.register_event_duration_secs_listener(_on_compile)
+def _on_event(event, **_):
+    cache = _CACHE_EVENTS.get(event)
+    if cache is not None and _tracer.enabled:
+        state = _cache_state(_tracer._thread())
+        # jax asks the cache even where no directory is set: nothing to find
+        if state["cache"] != "hit" and jax.config.jax_compilation_cache_dir:
+            state["cache"] = cache
+
+
+def _on_duration(event, duration, **kwargs):
+    name = _DURATION_SPANS.get(event)
+    tr = _tracer
+    if name is None or not tr.enabled:
+        return
+    t1 = time.perf_counter_ns()
+    t0 = t1 - int(duration * 1e9)
+    if name == "xla/cache_load":
+        state = _cache_state(tr._thread())
+        state["id"] = state["id"] or next(tr._span_ids)
+        tr.emit(name, t0, t1, parent=state["id"], seconds=float(duration))
+        return
+    attrs = {"fun": kwargs.get("fun_name"), "seconds": float(duration)}
+    span_id = None
+    if name == "xla/compile":
+        t = tr._thread()
+        state, t.compiling = getattr(t, "compiling", None), None
+        # events older than this compile belong to one that raised
+        fresh = state is not None and state["t"] >= t0
+        attrs["cache"] = state["cache"] if fresh else "off"
+        span_id = state["id"] if fresh else None
+    tr.emit(name, t0, t1, span_id=span_id, **attrs)
+
+
+jax.monitoring.register_event_listener(_on_event)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)

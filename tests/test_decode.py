@@ -923,6 +923,34 @@ def test_run_tick_and_run_prefill_are_the_schedulers_executables(
     assert eng.decode_exec(v, 1) is eng.decode_exec(v, 1)
 
 
+def test_a_served_window_traces_and_compiles_nothing(counted, span_log):
+    """The hot path meets no compile listener: with every bucket built and
+    one request served (the benchmark's warm-up and ramp), concurrent
+    requests of every prompt and batch size write no `xla/*` span. One
+    that did would name the step that recompiled."""
+    reg, model, sched, metrics = counted
+    eng, v = sched.engine, reg.get("gen")
+    for tb in eng.prompt_buckets:
+        eng.prefill_exec(v, tb)
+    for b in eng.decode_buckets:
+        eng.decode_exec(v, b)
+    sched.submit([3, 7, 1], max_tokens=3, timeout=300)
+    marks = len(span_log.snapshot())
+    prompts = [[1 + i % 5] * (1 + 3 * i) for i in range(6)]
+    threads = [threading.Thread(target=sched.submit, args=(p,),
+                                kwargs={"max_tokens": 2 + i, "timeout": 300})
+               for i, p in enumerate(prompts)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300)
+    assert not any(t.is_alive() for t in threads)
+    window = span_log.snapshot()[marks:]
+    assert len(_ticks(span_log)) > 6
+    assert [(e["name"], e["attrs"].get("fun"), e["parent"]) for e in window
+            if e["name"].startswith("xla/")] == []
+
+
 # ---------------------------------------------------------------------------
 # HTTP endpoint
 # ---------------------------------------------------------------------------

@@ -10,11 +10,15 @@ and scheduler threads write, also into the profiler's trace.
     a request, ending where the sampling after its prefill ends;
   * `fit` of both model families writes `dl4j/fit/step` with its children;
   * the same names are in the xplane under `jax.profiler.start_trace`;
-  * `xla/compile` carries the id of the span it happened under;
+  * the compile path (`xla/trace`, `xla/lower`, `xla/compile` with what the
+    persistent cache said, `xla/cache_load` inside a hit) lies under the
+    span that asked for it; `dl4j/nn/init` and `dl4j/registry/compile`
+    time set-up;
   * the jitted train step, prefill and tick carry their named scope.
 """
 import glob
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -333,9 +337,13 @@ def test_the_default_ring_holds_a_traced_serving_run(generated):
     """What the scheduler writes a loop (admissions at this run's high
     share included), at 100 loops a second for the 90 s between a
     benchmark run's first request and its readers' turn, fits the
-    default ring: the readers need the window's first tick."""
+    default ring: the readers need the window's first tick. Set-up (the
+    executables built on first use, and the compile path under them) is
+    written once, not a loop."""
     log, _, _ = generated
-    events, loops = log.snapshot(), _spans(log, "dl4j/sched/loop")
+    events = [e for e in log.snapshot() if not e["name"].startswith(
+        ("xla/", "dl4j/registry/compile", "dl4j/nn/init"))]
+    loops = _spans(log, "dl4j/sched/loop")
     per_loop = len(events) / len(loops)
     assert 6 <= per_loop <= 25, per_loop
     assert per_loop * 100 * 90 <= Tracer().capacity
@@ -513,16 +521,117 @@ def test_the_same_names_are_in_the_profilers_trace(log, tmp_path):
 
 
 def test_compile_event_names_the_span_it_happened_under(log):
+    """The compile path as spans under the step that asked for it: trace,
+    lower and backend compile (with the function's name and what the
+    persistent cache said); the second call writes none."""
     fn = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = np.ones(7, np.float32)
     with telemetry.span("outer"):
         with telemetry.span("compiles/here") as here:
-            fn(jnp.ones(7)).block_until_ready()
+            fn(x).block_until_ready()
+        marks = len(log.snapshot())
         with telemetry.span("compiles/not_here"):
-            fn(jnp.ones(7)).block_until_ready()
-    compiles = [e for e in log.snapshot() if e["name"] == "xla/compile"]
-    assert compiles and all(e["ph"] == "i" for e in compiles)
+            fn(x).block_until_ready()
+    snap = log.snapshot()
+    compiles = [e for e in snap if e["name"] == "xla/compile"]
+    assert compiles and all(e["ph"] == "X" for e in compiles)
     assert {e["parent"] for e in compiles} == {here.id}
-    assert all(e["attrs"]["seconds"] > 0 for e in compiles)
+    for e in compiles:
+        assert e["attrs"]["fun"] and e["attrs"]["seconds"] > 0
+        assert e["attrs"]["cache"] in ("hit", "miss", "off")
+        assert here.t0 <= e["t0"] <= e["t1"] <= here.t1
+    for name in ("xla/trace", "xla/lower"):
+        found = [e for e in snap if e["name"] == name]
+        assert found and {e["parent"] for e in found} == {here.id}
+        assert all(e["ph"] == "X" and e["attrs"]["fun"] for e in found)
+    assert not [e for e in snap[marks:] if e["name"].startswith("xla/")]
+
+
+def _compile_events(cache_events, load_s=None, fun="jit_step"):
+    """Fire a backend compile's events in jax's order
+    (`compiler.compile_or_get_cached` inside the compile's timing): the
+    cache's events, the load where it hit, then the compile's duration."""
+    from jax import monitoring
+    t0 = time.perf_counter()
+    for event in cache_events:
+        time.sleep(0.002)
+        monitoring.record_event(f"/jax/compilation_cache/{event}")
+    if load_s is not None:
+        time.sleep(load_s)
+        monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/compile_time_saved_sec", 1.0)
+        monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", load_s)
+    time.sleep(0.002)
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration",
+        time.perf_counter() - t0, fun_name=fun)
+
+
+def test_a_cache_load_is_a_hit_inside_its_compile_not_a_compile(log):
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", "/no/such/cache")
+    try:
+        with telemetry.span("loads/here") as here:
+            _compile_events(["compile_requests_use_cache", "cache_hits"],
+                            load_s=0.01, fun="jit_hit")
+            _compile_events(["compile_requests_use_cache", "cache_misses"],
+                            fun="jit_miss")
+        jax.config.update("jax_compilation_cache_dir", None)
+        _compile_events(["compile_requests_use_cache"], fun="jit_off")
+        log.enabled = False
+        _compile_events(["compile_requests_use_cache", "cache_hits"],
+                        load_s=0.001, fun="jit_quiet")
+        log.enabled = True
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    compiles = _spans(log, "xla/compile")
+    assert [(c["attrs"]["fun"], c["attrs"]["cache"]) for c in compiles] == [
+        ("jit_hit", "hit"), ("jit_miss", "miss"), ("jit_off", "off")]
+    assert [c["parent"] for c in compiles] == [here.id, here.id, None]
+    (load,) = _spans(log, "xla/cache_load")
+    hit = compiles[0]
+    assert load["parent"] == hit["id"]
+    assert hit["t0"] <= load["t0"] < load["t1"] <= hit["t1"]
+    assert load["attrs"]["seconds"] == pytest.approx(0.01)
+
+
+def test_init_is_a_span_that_says_whether_the_parameters_were_given(log):
+    made = _mlp()
+    MultiLayerNetwork(made.conf).init(params=made.params)
+    _graph()
+    inits = _spans(log, "dl4j/nn/init")
+    assert [(s["attrs"]["given"], s["attrs"]["layers"], s["attrs"]["leaves"])
+            for s in inits] == [(0, 2, 4), (1, 2, 4), (0, 2, 4)]
+
+
+def test_every_registry_build_is_a_span_with_its_plane_and_label(log):
+    """`dl4j/registry/compile` once per executable the registry builds:
+    the stateless forward a bucket, each prefill and tick; an executable
+    found in the cache writes none. The engine's record of an executable
+    and the compile path lie inside its build."""
+    registry = ModelRegistry(buckets=(1, 2))
+    registry.register("gen", _lm())
+    eng = DecodeEngine(registry, "gen", block_len=4, decode_buckets=(1, 2),
+                       prompt_buckets=(8, 16))
+    v = registry.get("gen")
+    for tb in eng.prompt_buckets:
+        eng.prefill_exec(v, tb)
+    for b in eng.decode_buckets:
+        eng.decode_exec(v, b)
+    eng.decode_exec(v, 2)
+    builds = _spans(log, "dl4j/registry/compile")
+    assert [(s["attrs"]["model"], s["attrs"]["plane"], s["attrs"]["label"])
+            for s in builds] == [
+        ("gen", "fwd", "1"), ("gen", "fwd", "2"),
+        ("gen", "decode", "prefill-t8"), ("gen", "decode", "prefill-t16"),
+        ("gen", "decode", "decode-b1"), ("gen", "decode", "decode-b2")]
+    decode_ids = {s["id"] for s in builds[2:]}
+    records = [e for e in log.snapshot()
+               if e["name"] == "dl4j/engine/executable"]
+    assert sorted(e["parent"] for e in records) == sorted(decode_ids)
+    under = {e["parent"] for e in _spans(log, "xla/compile")}
+    assert {s["id"] for s in builds} <= under
 
 
 def _lowered_text(what):
