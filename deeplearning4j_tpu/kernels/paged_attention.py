@@ -64,8 +64,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _MASK, _NEG_INF, _NN, _NT, _round_up
 
-__all__ = ["paged_decode_attention", "paged_attention_supported",
-           "paged_plan", "PagedPlan"]
+__all__ = ["paged_decode_attention", "paged_latent_attention", "PagedPlan",
+           "paged_attention_supported", "paged_plan", "latent_plan"]
 
 _CHUNK_TOKENS = 128     # cache slots one iteration of a row's loop covers
 _SUBLANES = 8           # float32 rows of a tile: heads are padded up to it
@@ -305,3 +305,220 @@ def paged_decode_attention(q, kv, channel, tables, lengths, *, n_heads: int,
     out = out[:, :n_heads].reshape(B, n_heads, n_kv_heads, d_head)
     return jnp.sum(jnp.where(on[None, :, :, None], out, 0.0),
                    axis=2).reshape(B, q_width)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention: one absorbed query a row over a latent cache's pages.
+#
+# A latent-attention layer (`nn/layers/shortcut_moe.py`) caches one vector a
+# token and attention, `[c | k_rope]` zero-padded to the arena's `width`
+# lanes, and its tick's query arrives with the up-projection absorbed,
+# `[q_nope W_uk | q_rope | 0]` a head. A page then holds the keys AND the
+# values: the scores are `q @ page^T` over all `width` lanes, the weighted
+# sum `p @ page` over the first `v_width` (the latent `c`). So a page is
+# copied once (not as K and then as V) into one of two slots, a chunk's
+# pages waited for at once, and every head multiplies at once on the MXU: `[H, width] @ [width, slots]`, then `[H, slots] @ [slots,
+# v_width]`, in the dtype of the query and the pages, summed in float32.
+# The walk is `paged_decode_attention`'s: a grid step a row, its live chunks
+# in a loop, the next chunk (or the next row's first) in flight, dead table
+# slots never read, the ragged tail masked by the length.
+#
+# A chunk covers `_LATENT_CHUNK_TOKENS` cache slots (32 pages of 16). At the
+# LongCat-Flash cell's shape (32 rows of some 540 slots, 64 heads, 640
+# bfloat16 lanes, 8 attentions) a tick's eight calls took 0.88 / 0.66 / 0.61
+# / 0.75 ms of the v5e's time with chunks of 128 / 256 / 512 / 1,024 slots
+# (every page copy written out), against 2.70 ms for XLA's gathered view:
+# a chunk's two products at 64 rows keep the MXU about half busy, and each
+# iteration costs beside them. DMAs that skip the last chunk's repeated
+# pages saved nothing.
+# ---------------------------------------------------------------------------
+_LATENT_CHUNK_TOKENS = 512      # cache slots one iteration of a row's loop covers
+_LATENT_DMA_UNROLL = 8          # page copies written out in a DMA loop's body
+
+
+def _value_lanes(width: int, v_width: int) -> int:
+    """The lanes the weighted sum keeps: `v_width` in whole lane tiles."""
+    return min(width, _round_up(v_width, 128))
+
+
+def latent_plan(rows: int, table_width: int, block_len: int, n_heads: int,
+                width: int, v_width: int, itemsize: int = 2) -> PagedPlan:
+    """Pages a chunk from the shape: `_LATENT_CHUNK_TOKENS` cache slots'
+    worth, no more than the table is wide; the heads padded to whole tiles
+    of the query's dtype (`itemsize`: the pages' and the query's)."""
+    pages = max(1, min(_LATENT_CHUNK_TOKENS // block_len, table_width))
+    hp = _round_up(n_heads, _SUBLANES * max(1, 4 // itemsize))
+    slots, vw = pages * block_len, _value_lanes(width, v_width)
+    vmem = (3 * slots * width * itemsize        # two slots of pages; a value
+            + 2 * hp * width * itemsize         # the row's query
+            + 2 * hp * vw * 4                   # the row's output
+            + 2 * hp * slots * 4                # scores, weights
+            + hp * vw * 4)                      # the accumulator
+    return PagedPlan(pages, pl.cdiv(table_width, pages), rows, hp, vmem)
+
+
+@functools.lru_cache(maxsize=64)
+def _planned_latent(rows, table_width, block_len, n_heads, width, v_width,
+                    num_blocks, dtype) -> PagedPlan:
+    """`latent_plan` for one call shape, worked out once a process; it
+    leaves the record `dl4j/kernels/paged_attention` with `latent` 1."""
+    from ..telemetry import tracer
+
+    plan = latent_plan(rows, table_width, block_len, n_heads, width, v_width,
+                       jnp.dtype(dtype).itemsize)
+    tracer().instant("dl4j/kernels/paged_attention", rows=rows,
+                     table_width=table_width, block_len=block_len,
+                     n_heads=n_heads, width=width, num_blocks=num_blocks,
+                     latent=1, v_width=v_width, dtype=dtype, **plan._asdict())
+    return plan
+
+
+def _make_latent_kernel(plan: PagedPlan, block_len: int, v_lanes: int,
+                        sm_scale: float, dtype):
+    """Grid (rows,). A row's loop holds `pages_a_chunk` pages at a time in
+    one of two slots; the query is `[1, heads, width]` a grid step, the
+    output `[1, heads, v_lanes]`; scores `[heads, slots]`, statistics
+    `[heads, 1]`, all float32."""
+    from math import gcd
+
+    pages, hp, n_rows = plan.pages_a_chunk, plan.heads_padded, plan.steps_a_call
+    chunk, unrolled = pages * block_len, gcd(pages, _LATENT_DMA_UNROLL)
+
+    def kernel(tables_ref, lengths_ref, channel_ref, q_ref, kv_ref, o_ref,
+               buf, sems, slot_ref):
+        b = pl.program_id(0)
+        channel = channel_ref[0]
+
+        def start(row, c, slot):
+            """The DMAs of chunk `c` of `row` into `slot`, a page a table
+            column; past the row's last live page, that page again. A loop
+            over groups of `unrolled` copies: all 32 written out made each
+            tick executable trace and lower for seconds more; a loop of one
+            copy an iteration made the kernel 40% slower."""
+            last = jnp.maximum(lengths_ref[row] - 1, 0) // block_len
+
+            def group(g, carry):
+                for j in range(unrolled):
+                    i = g * unrolled + j
+                    blk = tables_ref[row, jnp.minimum(c * pages + i, last)]
+                    pltpu.make_async_copy(kv_ref.at[channel, blk],
+                                          buf.at[slot, i],
+                                          sems.at[slot]).start()
+                return carry
+
+            jax.lax.fori_loop(0, pages // unrolled, group, 0)
+
+        @pl.when(b == 0)
+        def _():
+            slot_ref[0] = 0
+            start(0, 0, 0)
+
+        length = lengths_ref[b]
+        n_chunks = jnp.maximum(length - 1, 0) // chunk + 1
+        first = slot_ref[0]              # the slot this row's chunk 0 is in
+        q = q_ref[0].astype(dtype)                              # [hp, width]
+
+        def body(c, carry):
+            m_prev, l_prev, acc = carry
+            slot = (first + c) % 2
+            more = c + 1 < n_chunks
+
+            @pl.when(more | (b + 1 < n_rows))
+            def _():         # the next chunk, or the next row's first
+                start(jnp.where(more, b, b + 1), jnp.where(more, c + 1, 0),
+                      1 - slot)
+
+            # one wait for the chunk's pages: the semaphore counts bytes
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sems.at[slot]).wait()
+            page = buf[slot].reshape(chunk, -1).astype(dtype)   # [chunk, width]
+            s = jax.lax.dot_general(
+                q, page, _NT, preferred_element_type=jnp.float32) * sm_scale
+            at = c * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (hp, chunk), 1)
+            # chunk 0 holds slot 0, which every row may see: after it the
+            # running max is a real score and a masked one weighs exactly 0
+            s = jnp.where(at < length, s, _MASK)                # [hp, chunk]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * corr + jax.lax.dot_general(
+                p.astype(dtype), page[:, :v_lanes], _NN,
+                preferred_element_type=jnp.float32)             # [hp, v_lanes]
+            return m_new, l_new, acc
+
+        _, l, acc = jax.lax.fori_loop(
+            0, n_chunks, body,
+            (jnp.full((hp, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((hp, 1), jnp.float32),
+             jnp.zeros((hp, v_lanes), jnp.float32)))
+        slot_ref[0] = (first + n_chunks) % 2
+        o_ref[0] = acc / l
+
+    return kernel
+
+
+def paged_latent_attention(q, kv, channel, tables, lengths, *, v_width: int,
+                           sm_scale: float, interpret: Optional[bool] = None):
+    """Attention of one absorbed query a row over its latent cache's pages.
+
+    q [B, H, width]: a head's `[q_nope W_uk | q_rope | 0]`, as many lanes as
+    the arena; kv the arena `[C, num_blocks, block_len, width]`, float32 or
+    bfloat16, read in place; `channel` (int32 scalar, may be traced) the
+    attention's latent channel; tables [B, W] int32 block ids; lengths [B]
+    int32 live cache slots a row (>= 1; slot `lengths - 1` is the query's
+    own). The products take their operands in the dtype of q and kv
+    promoted and sum in float32; the softmax is float32. Returns
+    [B, H, v_width] float32: softmax(q . page * sm_scale) over the row's
+    slots, times the pages' first `v_width` lanes. Compiled Pallas on the
+    TPU; `interpret=True` (automatic off it) runs the same kernel through
+    the interpreter."""
+    B, n_heads, width = q.shape
+    _, num_blocks, block_len, kv_width = kv.shape
+    if width != kv_width or not 0 < v_width <= width:
+        raise ValueError(f"q {q.shape} and arena {kv.shape} disagree on the "
+                         f"latent's width, or v_width {v_width} lies outside it")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _latent_call(q, kv, channel, tables, lengths, int(v_width),
+                        float(sm_scale), bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))  # graftlint: disable=unwatched-jit-entry
+def _latent_call(q, kv, channel, tables, lengths, v_width, sm_scale,
+                 interpret):
+    """`paged_latent_attention`'s kernel, jitted on its own: the calls of
+    one shape in a step (two attentions a block, blocks traced again) then
+    trace once a process and lower once an executable."""
+    B, n_heads, width = q.shape
+    _, num_blocks, block_len, _ = kv.shape
+    dtype = jnp.promote_types(q.dtype, kv.dtype)
+    plan = _planned_latent(B, tables.shape[1], block_len, n_heads, width,
+                           v_width, num_blocks, jnp.dtype(dtype).name)
+    hp, v_lanes = plan.heads_padded, _value_lanes(width, v_width)
+    q = jnp.pad(q.astype(dtype), ((0, 0), (0, hp - n_heads), (0, 0)))
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))}
+    out = pl.pallas_call(
+        _make_latent_kernel(plan, block_len, v_lanes, sm_scale, dtype),
+        out_shape=jax.ShapeDtypeStruct((B, hp, v_lanes), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, hp, width), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, hp, v_lanes), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, plan.pages_a_chunk, block_len, width),
+                           kv.dtype),                       # the page slots
+                pltpu.SemaphoreType.DMA((2,)),              # a slot each
+                pltpu.SMEM((1,), jnp.int32),    # the slot of the next chunk 0
+            ]),
+        interpret=interpret,
+        name="paged_latent_attention",
+        **params,
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.reshape(channel, (1,)).astype(jnp.int32), q, kv)
+    return out[:, :n_heads, :v_width]

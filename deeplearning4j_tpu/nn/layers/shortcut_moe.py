@@ -61,8 +61,12 @@ block caches, for a token and an attention, the latent `c` (after norm
 and scale) beside the rotated `k_rope`, zero-padded to whole 128-lane
 tiles. A prefill expands `c W_kvb` into heads ("mla_expanded": cheaper
 over a whole prompt); a tick absorbs `W_kvb` into the query and the
-output and attends over the cached latent as it lies ("mla_absorbed"),
-never building heads of K/V for every cached token.
+output and attends over the cached latent as it lies, never building
+heads of K/V for every cached token: on the TPU inside one Pallas kernel
+that reads the rows' live pages through their tables and multiplies on the
+MXU ("mla_paged", `kernels.paged_attention.paged_latent_attention`),
+elsewhere over the view the tables gather ("mla_absorbed": the CPU, an
+int8 arena, the kernel's oracle).
 """
 from __future__ import annotations
 
@@ -509,26 +513,54 @@ class ShortcutMoEBlock(LayerConf):
                          preferred_element_type=_F32)
         return out.reshape(*out.shape[:2], -1)
 
+    def _absorbed_query(self, p, q_nope, q_rope, width):
+        """[q_nope W_uk | q_rope | 0] [B, T, H, width] float32: a head's
+        query over the cache as it lies (W_kvb's key part absorbed)."""
+        w_uk = self._up(p)[0]
+        q_abs = jnp.einsum("bthn,chn->bthc", q_nope.astype(w_uk.dtype), w_uk,
+                           preferred_element_type=_F32)
+        pad = width - self.kv_rank - self.qk_rope
+        return jnp.concatenate(
+            [q_abs, q_rope, jnp.zeros((*q_rope.shape[:-1], pad), _F32)], -1)
+
+    def _absorbed_out(self, p, o):
+        """The weighted latents o [B, T, H, kv_rank] float32 through W_kvb's
+        value part: -> [B, T, H * v]."""
+        w_uv = self._up(p)[1]
+        out = jnp.einsum("bthc,chv->bthv", o.astype(w_uv.dtype), w_uv,
+                         preferred_element_type=_F32)
+        return out.reshape(*out.shape[:2], -1)
+
     def _attend_absorbed(self, p, q_nope, q_rope, view, pos, lengths):
         """W_kvb absorbed into the query and the output; `view`
         [B, S, latent_width] is the cache as it lies: -> [B, T, H * v]."""
-        w_uk, w_uv = self._up(p)
-        dt = jnp.promote_types(view.dtype, w_uk.dtype)
+        dt = jnp.promote_types(view.dtype, p["W_kvb"].dtype)
         view = view.astype(dt)
-        q_abs = jnp.einsum("bthn,chn->bthc", q_nope.astype(w_uk.dtype), w_uk,
-                           preferred_element_type=_F32)
-        pad = view.shape[-1] - self.kv_rank - self.qk_rope
-        q = jnp.concatenate(
-            [q_abs, q_rope, jnp.zeros((*q_rope.shape[:-1], pad), _F32)], -1)
+        q = self._absorbed_query(p, q_nope, q_rope, view.shape[-1])
         s = jnp.einsum("bthl,bsl->bhts", q.astype(dt), view,
                        preferred_element_type=_F32)
         w = self._softmax(s / math.sqrt(self.qk_nope + self.qk_rope), pos,
                           lengths)
         o = jnp.einsum("bhts,bsc->bthc", w.astype(dt),
                        view[..., :self.kv_rank], preferred_element_type=_F32)
-        out = jnp.einsum("bthc,chv->bthv", o.astype(w_uv.dtype), w_uv,
-                         preferred_element_type=_F32)
-        return out.reshape(*out.shape[:2], -1)
+        return self._absorbed_out(p, o)
+
+    def _attend_paged(self, p, q_nope, q_rope, kv, channel, tables, lengths):
+        """The absorbed attention of a tick (one query a row) over the
+        arena's latent pages where they lie, through the rows' tables
+        (`kernels.paged_attention.paged_latent_attention`, always the
+        COMPILED kernel): -> [B, 1, H * v]. The arithmetic is
+        `_attend_absorbed`'s but for the softmax's sums, taken a chunk of
+        pages at a time."""
+        from ...kernels import paged_attention as paged
+
+        dt = jnp.promote_types(kv.dtype, p["W_kvb"].dtype)
+        q = self._absorbed_query(p, q_nope, q_rope, kv.shape[-1])[:, 0]
+        o = paged.paged_latent_attention(
+            q.astype(dt), kv, channel, tables, lengths, v_width=self.kv_rank,
+            sm_scale=1.0 / math.sqrt(self.qk_nope + self.qk_rope),
+            interpret=False)
+        return self._absorbed_out(p, o[:, None])
 
     # -- the block ---------------------------------------------------------
     def _block(self, p, x, attend, live=None):
@@ -567,7 +599,23 @@ class ShortcutMoEBlock(LayerConf):
         return None
 
     def decode_attention(self, phase: str, spec):
-        return "mla_absorbed" if phase == "tick" else "mla_expanded"
+        """How `phase` attends over a cache of `spec`. A prefill builds heads
+        from its own latents: "mla_expanded". A tick: "mla_paged" where the
+        backend is the TPU (`pallas_supported`: and the kernels are not
+        switched off) and the arena's pages are whole tiles of float32 or
+        bfloat16 (`kernels.paged_attention.paged_latent_attention`), else
+        "mla_absorbed" (the view through the tables: the CPU, int8)."""
+        from ...kernels import pallas_supported
+        from ...kernels.paged_attention import paged_attention_supported
+        from ...serving.decode.cache import KV_DTYPES
+
+        if phase != "tick":
+            return "mla_expanded"
+        if (pallas_supported() and spec.kv_dtype in ("fp32", "bf16")
+                and paged_attention_supported(
+                    spec.width, spec.block_len, KV_DTYPES[spec.kv_dtype])):
+            return "mla_paged"
+        return "mla_absorbed"
 
     def _cached(self, io, latent):
         """The latent zero-padded to the arena's width (the scatter
@@ -595,9 +643,12 @@ class ShortcutMoEBlock(LayerConf):
         return step
 
     def decode_tick_step(self, io, attention="mla_absorbed"):
-        if attention not in ("mla_absorbed", "mla_expanded"):
-            raise ValueError(f"attention must be mla_absorbed|mla_expanded, "
-                             f"got {attention!r}")
+        if attention not in ("mla_paged", "mla_absorbed", "mla_expanded"):
+            raise ValueError(f"attention must be mla_paged|mla_absorbed|"
+                             f"mla_expanded, got {attention!r}")
+        if attention == "mla_paged" and io.spec.kv_dtype == "int8":
+            raise ValueError("mla_paged reads the pages as they lie: an int8 "
+                             "arena is read through its view (mla_absorbed)")
         attend_view = (self._attend_absorbed if attention == "mla_absorbed"
                        else self._attend_expanded)
 
@@ -610,6 +661,9 @@ class ShortcutMoEBlock(LayerConf):
                 latent = self._latent(pa, xn, pos)
                 cache[:] = io.scatter(*cache, self._cached(io, latent)[:, 0],
                                       blk, off, channel + i)
+                if attention == "mla_paged":
+                    return self._attend_paged(pa, qn, qr, cache[0],
+                                              channel + i, tables, lengths)
                 view = io.gather(*cache, tables, channel + i)
                 view = view.reshape(view.shape[0], -1, view.shape[-1])
                 return attend_view(pa, qn, qr, view, pos, lengths)

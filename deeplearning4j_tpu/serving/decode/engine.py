@@ -28,8 +28,9 @@ neither the model nor the weights, it meets a version once
     into the arena, attend over the row's live cache slots, project
     logits. The attention reads the arena through the block table inside
     one Pallas kernel (`kernels.paged_attention`: live pages only, all
-    heads on the merged lanes, no view of the cache made) where
-    the block's `decode_attention` finds the TPU, a float32 arena and pages of whole
+    heads on the merged lanes, no view of the cache made; a latent block's
+    pages through `paged_latent_attention`) where the block's
+    `decode_attention` finds the TPU and pages of whole float32 or bfloat16
     tiles. Elsewhere — the CPU, the int8 arena, odd widths — it gathers
     the row's whole table into a view and attends with causal offsets +
     per-row valid length (`kernels.attention` kv_length path): the
@@ -519,7 +520,8 @@ class DecodeEngine:
         program takes the rows' slots after `arg_specs`, as many as its
         tables have rows. `options` go to the builder and into the
         record: the phase's `attention`, where its layers have a choice
-        (`paged_kernel` / `gather`, `mla_absorbed` / `mla_expanded`)."""
+        (`paged_kernel` / `gather`, `mla_paged` / `mla_absorbed` /
+        `mla_expanded`)."""
         spec = self.spec
         options = {k: o for k, o in options.items() if o is not None}
         record = {}

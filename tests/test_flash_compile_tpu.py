@@ -335,3 +335,124 @@ def test_decode_steps_update_the_state_in_place_on_v5e(one_chip, hybrid_stack,
             r"= \(f32\[65,128,64\]\S*, f32\[65,128,64,128\]\S*\) fusion\(",
             text)) == 3
         assert "f32[64,128,64,128]" not in text
+
+
+@pytest.mark.parametrize("rows", [1, 8, 32])
+def test_latent_paged_attention_kernel_compiles_for_v5e(one_chip, rows):
+    """The latent kernel at the LongCat-Flash cell's geometry: 64 heads over
+    640-lane bfloat16 pages of 16 slots, an arena of 8 channels x 4,097
+    blocks, tables 128 wide, the weighted sum over the first 512 lanes. The
+    arena is abstract and is not copied."""
+    from deeplearning4j_tpu.kernels.paged_attention import (
+        paged_latent_attention)
+
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    step = jax.jit(lambda q, kv, c, tables, lengths: paged_latent_attention(
+        q, kv, c, tables, lengths, v_width=512, sm_scale=192 ** -0.5,
+        interpret=False))
+    with jax.enable_x64(False):
+        lowered = step.lower(
+            arg(jnp.bfloat16, rows, 64, 640), arg(jnp.bfloat16, 8, 4097, 16, 640),
+            arg(jnp.int32), arg(jnp.int32, rows, 128), arg(jnp.int32, rows))
+        assert "paged_latent_attention" in lowered.as_text()
+        compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.fixture(scope="module")
+def latent_stack():
+    """(model, snapshot, spec) of one LongCat-Flash block at the cell's
+    widths (6144 wide, 64 heads, ranks 1536 / 512, heads 128 + 64 / 128; 2
+    of the 16 held experts and a 256-token vocabulary) over the cell's
+    latent arena, 4,097 blocks of 16 slots x 640 bfloat16 lanes, two
+    channels. The weights are shapes: nothing is made."""
+    import functools
+    import importlib.util
+    import json
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
+    from deeplearning4j_tpu.serving.decode.engine import cache_geometry
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+
+    def load(kind):
+        spec = importlib.util.spec_from_file_location(
+            f"compile_test_lcf_{kind}", bench / kind / "longcat_flash.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    ref, models = load("reference"), load("models")
+    real = json.loads(
+        (bench / "configs" / "longcat-flash-chat.json").read_text())
+    config = dict(real, num_layers=1, n_routed_experts=2, vocab_size=256,
+                  deployment=dict(real["deployment"], held_experts=[0, 2]))
+    shapes = SimpleNamespace(
+        dims=ref.dims, init_params=lambda c, s: jax.eval_shape(
+            functools.partial(ref.init_params, c, s)))
+    model = models.build(config, 0, shapes, train=False)
+    leaves, treedef = jax.tree_util.tree_flatten(model.params)
+    snapshot = SimpleNamespace(
+        data=tuple(leaves),
+        rebuild=lambda data: jax.tree_util.tree_unflatten(treedef, list(data)))
+    channels, width, context, _ = cache_geometry(model)
+    spec = KvCacheSpec(channels=channels, width=width, block_len=16,
+                       num_blocks=1 + 128 * 32, max_context=context,
+                       kv_dtype="bf16")
+    return model, snapshot, spec
+
+
+@pytest.mark.parametrize("phase,attention", [
+    ("tick", "mla_paged"), ("served_tick", "mla_paged"),
+    ("tick", "mla_absorbed")])
+def test_latent_ticks_update_the_arena_in_place_on_v5e(one_chip, latent_stack,
+                                                       phase, attention):
+    """A 32-row tick of the latent block, as the TPU serves it (`mla_paged`):
+    one `paged_latent_attention` custom call an attention, reading the
+    pages where they lie, and no gathered view of them (the view path,
+    `mla_absorbed`, holds one `[32, 128, 16, 640]` gather a channel, 84 MB
+    of temporaries); the arena is donated, aliased, neither copied nor
+    converted."""
+    import functools
+
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_decode_fn,
+                                                          build_tick_fn)
+
+    model, snapshot, spec = latent_stack
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    rows, w = 32, spec.table_width
+    if phase == "tick":
+        fn = functools.partial(build_decode_fn, attention=attention)
+        args = (i32(rows), i32(rows), i32(rows, w))
+    else:
+        fn = functools.partial(build_tick_fn, rows_max=rows,
+                               attention=attention)
+        args = (i32(rows), i32(rows), i32(rows), i32(rows, w))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn(model, snapshot, spec), donate_argnums=(1,)).lower(
+            on_chip(snapshot.data), on_chip(_cache_arg_specs(spec)),
+            *args).compile()
+    arena, view = spec.arena_nbytes(), rows * w * 16 * 640 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= arena
+    text = compiled.as_text()
+    layout = text[text.index("entry_computation_layout="):].split("\n")[0]
+    assert "bf16[2,4097,16,640]{3,2,1,0:" in layout
+    big = [m for m in re.finditer(r"= (\w+)\[([\d,]*)\]\S* copy\(", text)
+           if _nbytes(m.group(1), m.group(2)) >= arena / spec.channels]
+    assert not big, [m.group(0) for m in big]
+    kernels = re.findall(r"%paged_latent_attention[.\d]* = \S+ custom-call\(",
+                         text)
+    views = re.findall(r"= bf16\[32,128,16,640\]\S* gather\(", text)
+    if attention == "mla_paged":
+        assert (len(kernels), len(views)) == (spec.channels, 0)
+        assert mem.temp_size_in_bytes < view / 2
+    else:                       # the view, 84 MB, is the largest temporary
+        assert (len(kernels), len(views)) == (0, spec.channels)
+        assert mem.temp_size_in_bytes > view

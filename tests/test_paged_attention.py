@@ -29,7 +29,8 @@ from deeplearning4j_tpu import (Adam, EmbeddingSequenceLayer, InputType,
                                 RnnOutputLayer, TransformerBlock, telemetry)
 from deeplearning4j_tpu.kernels import paged_attention as paged_mod
 from deeplearning4j_tpu.kernels.paged_attention import (
-    paged_attention_supported, paged_decode_attention, paged_plan)
+    paged_attention_supported, paged_decode_attention, paged_latent_attention,
+    paged_plan)
 from deeplearning4j_tpu.serving.decode import engine as engine_mod
 from deeplearning4j_tpu.serving.decode.cache import CacheIO, KvCacheSpec
 from deeplearning4j_tpu.serving.decode.engine import DecodeEngine
@@ -357,3 +358,148 @@ def test_grouped_queries_read_their_key_value_heads_pages(lengths, heads,
     with pytest.raises(ValueError, match="disagree"):
         paged_decode_attention(q, kv, jnp.int32(0), tables, lens,
                                n_heads=heads, n_kv_heads=kv_heads + 1)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (the LongCat-Flash block's tick): one absorbed query a row
+# over a latent cache's pages, keys and values in one page
+# ---------------------------------------------------------------------------
+LATENT = dict(n_heads=64, kv_rank=512, qk_rope=64, qk_nope=128, v_head=128)
+LW = 640                        # [c | k_rope] = 576, padded to lane tiles
+
+
+def _latent_block():
+    from deeplearning4j_tpu import ShortcutMoEBlock
+    return ShortcutMoEBlock(n_model=64, q_rank=32, **LATENT)
+
+
+def _latent_paged(lengths, dtype="float32", channels=2, seed=0, dead_block=0,
+                  table_width=128):
+    """(arena, tables, lengths) of latent pages: lanes past the latent's 576
+    hold zeros, as the layer caches them; every row's live pages are blocks
+    of its own in a shuffled order, its dead table slots name `dead_block`
+    (block 1 is kept for NaN)."""
+    r = np.random.default_rng(seed)
+    pages = [-(-n // BL) for n in lengths]
+    kv = r.normal(size=(channels, 2 + sum(pages), BL, LW)).astype(np.float32)
+    kv[..., 576:] = 0.0
+    tables = np.full((len(lengths), table_width), dead_block, np.int32)
+    ids = 2 + r.permutation(sum(pages))
+    for row, n in enumerate(pages):
+        tables[row, :n], ids = ids[:n], ids[n:]
+    return (jnp.asarray(kv).astype(dtype), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _latent_queries(rows, seed=1):
+    """(q_nope, q_rope) [rows, 1, H, .] and the block's W_kvb, float32."""
+    r = np.random.default_rng(seed)
+    h = LATENT["n_heads"]
+    qn = r.normal(size=(rows, 1, h, LATENT["qk_nope"])).astype(np.float32)
+    qr = r.normal(size=(rows, 1, h, LATENT["qk_rope"])).astype(np.float32)
+    w = r.normal(size=(LATENT["kv_rank"], h * 256)) / np.sqrt(512)
+    return (jnp.asarray(qn), jnp.asarray(qr),
+            {"W_kvb": jnp.asarray(w.astype(np.float32))})
+
+
+@pytest.fixture
+def latent_interpreted(monkeypatch):
+    """The layer's call site runs the kernel through the interpreter (its
+    own choice is the compiled kernel, which only a TPU takes)."""
+    monkeypatch.setattr(
+        paged_mod, "paged_latent_attention",
+        lambda *a, interpret, **kw: paged_latent_attention(
+            *a, interpret=True, **kw))
+
+
+def _latent_both(lengths, dtype, channel, **kw):
+    """The block's tick attention both ways: through the kernel over the
+    pages in place, and `_attend_absorbed` over `CacheIO.gather`'s view."""
+    kv, tables, lens = _latent_paged(lengths, dtype, channels=channel + 1,
+                                     **kw)
+    qn, qr, p = _latent_queries(len(lengths))
+    block = _latent_block()
+    got = block._attend_paged(p, qn, qr, kv, jnp.int32(channel), tables, lens)
+    spec = KvCacheSpec(channels=kv.shape[0], width=LW, block_len=BL,
+                       num_blocks=kv.shape[1],
+                       max_context=tables.shape[1] * BL)
+    view = CacheIO(spec).gather(kv, None, tables, channel)
+    want = block._attend_absorbed(
+        p, qn, qr, view.reshape(len(lengths), -1, LW), (lens - 1)[:, None],
+        lens)
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("lengths,dtype,channel", [
+    ([1, 512, 513, 2048], "bfloat16", 2),   # one slot, a chunk, one past, all
+    ([1, 512, 513, 2048], "float32", 0),
+    ([167, 880, 33, 1024, 1500, 300], "bfloat16", 1),
+    ([16, 17, 511], "float32", 3),          # a page, one past, a chunk less one
+], ids=lambda v: v if isinstance(v, str) else
+    None if isinstance(v, int) else "x".join(map(str, v[:4])))
+def test_latent_kernel_matches_absorbed_attention_over_the_view(
+        latent_interpreted, lengths, dtype, channel):
+    """64 heads over 640 lanes, the weighted sum over the first 512: the
+    kernel's result through `W_uv` equals the absorbed attention over the
+    gathered view to float32 sums in another order (the pages are read as
+    they lie, bfloat16 or float32; the weights are float32 here, so both
+    multiply in float32)."""
+    got, want = _latent_both(lengths, dtype, channel)
+    assert got.shape == want.shape == (len(lengths), 1, 64 * 128)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_latent_dead_pages_are_never_read():
+    """Dead table slots name a block of NaN: the result stays finite and
+    equal to the oracle's, which reads the trash block there and masks it."""
+    lengths = [1, 300, 513, 1100]
+    kv, tables, lens = _latent_paged(lengths, "bfloat16", seed=3,
+                                     dead_block=1)
+    kv = kv.at[:, 1].set(jnp.nan)
+    q = jnp.asarray(np.random.default_rng(4).normal(
+        size=(len(lengths), 64, LW)).astype(np.float32))
+    q = q.at[..., 576:].set(0.0)
+    got = np.asarray(paged_latent_attention(
+        q, kv, jnp.int32(1), tables, lens, v_width=512, sm_scale=0.07,
+        interpret=True))
+    assert got.shape == (len(lengths), 64, 512) and np.isfinite(got).all()
+    live = jnp.arange(tables.shape[1])[None, :] * BL < lens[:, None]
+    safe = jnp.where(live, tables, 0)
+    pages = np.asarray(kv[1][safe].astype(jnp.float32), np.float64)
+    q64 = np.asarray(q.astype(jnp.bfloat16).astype(jnp.float32), np.float64)
+    for row, n in enumerate(lengths):
+        view = pages[row].reshape(-1, LW)[:n]
+        s = q64[row] @ view.T * 0.07
+        w = np.exp(s - s.max(-1, keepdims=True))
+        want = (w / w.sum(-1, keepdims=True)) @ view[:, :512]
+        np.testing.assert_allclose(got[row], want, rtol=2e-2, atol=2e-2)
+
+
+def test_latent_row_is_bit_identical_alone_and_among_31_others():
+    r = np.random.default_rng(5)
+    lengths = r.integers(1, 2049, 32).tolist()
+    kv, tables, lens = _latent_paged(lengths, "bfloat16", channels=1, seed=5)
+    q = jnp.asarray(r.normal(size=(32, 64, LW)).astype(np.float32))
+    run = functools.partial(paged_latent_attention, v_width=512,
+                            sm_scale=0.07, interpret=True)
+    among = np.asarray(run(q, kv, jnp.int32(0), tables, lens))
+    for row in (0, 13, 31):
+        alone = np.asarray(run(q[row:row + 1], kv, jnp.int32(0),
+                               tables[row:row + 1], lens[row:row + 1]))
+        np.testing.assert_array_equal(alone[0], among[row])
+
+
+def test_latent_plan_walks_a_row_in_chunks_of_512_slots():
+    from deeplearning4j_tpu.kernels.paged_attention import latent_plan
+    plan = latent_plan(32, 128, 16, 64, 640, 512)
+    assert (plan.pages_a_chunk, plan.chunks_a_row, plan.steps_a_call,
+            plan.heads_padded) == (32, 4, 32, 64)
+    assert plan.vmem_bytes < 16 << 20
+    assert latent_plan(4, 8, 16, 4, 128, 16).pages_a_chunk == 8   # a narrow table
+    assert latent_plan(4, 8, 16, 4, 128, 16, itemsize=4).heads_padded == 8
+    assert latent_plan(4, 8, 16, 4, 128, 16, itemsize=2).heads_padded == 16
+    with pytest.raises(ValueError, match="width"):
+        paged_latent_attention(jnp.zeros((1, 4, 128)), jnp.zeros((1, 3, 16, 256)),
+                               jnp.int32(0), jnp.zeros((1, 2), jnp.int32),
+                               jnp.ones((1,), jnp.int32), v_width=16,
+                               sm_scale=1.0)

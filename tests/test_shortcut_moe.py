@@ -301,6 +301,96 @@ def test_absorbed_tick_matches_the_expanded_one(served):
         build_decode_fn(model, snapshot, spec, attention="gather")
 
 
+@pytest.fixture
+def latent_interpreted(monkeypatch):
+    """`mla_paged` always means the COMPILED kernel; here it runs through
+    the Pallas interpreter instead."""
+    from deeplearning4j_tpu.kernels import paged_attention as paged
+    kernel = paged.paged_latent_attention
+    monkeypatch.setattr(paged, "paged_latent_attention",
+                        lambda *a, interpret, **kw: kernel(
+                            *a, interpret=True, **kw))
+
+
+def test_paged_tick_matches_the_absorbed_one(served, latent_interpreted):
+    """The tick's kernel path against its oracle over the same latent
+    cache: the pages read in place through the tables, or gathered into a
+    view; pad row, dead table slots and the arena written alike."""
+    _, model, _, engine = served
+    spec = engine.spec
+    snapshot = _snapshot_params(model, "fp32")
+    ticks = {a: jax.jit(build_decode_fn(model, snapshot, spec, attention=a))
+             for a in ("mla_paged", "mla_absorbed")}
+    r = np.random.default_rng(6)
+    cache = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(r.normal(size=a.shape), a.dtype),
+        make_cache(spec))
+    tables = jnp.asarray([[1, 2, 3, 4] + [7] * 12, [5, 6, 0, 0] + [0] * 12,
+                          [0] * 16], jnp.int32)
+    args = (jnp.asarray([3, 9, 0], jnp.int32),
+            jnp.asarray([14, 6, 0], jnp.int32), tables)
+    got = {a: t(snapshot.data, cache, *args) for a, t in ticks.items()}
+    np.testing.assert_allclose(np.asarray(got["mla_paged"][1][:2]),
+                               np.asarray(got["mla_absorbed"][1][:2]),
+                               rtol=RTOL, atol=ATOL)
+    np.testing.assert_allclose(np.asarray(got["mla_paged"][0]["kv"]),
+                               np.asarray(got["mla_absorbed"][0]["kv"]),
+                               rtol=RTOL, atol=ATOL)
+    np.testing.assert_array_equal(np.asarray(got["mla_paged"][2]),
+                                  np.asarray(got["mla_absorbed"][2]))
+    int8 = CacheIO(KvCacheSpec(channels=4, width=128, block_len=8,
+                               num_blocks=9, max_context=64, kv_dtype="int8"))
+    with pytest.raises(ValueError, match="int8"):
+        model.layers[1].decode_tick_step(int8, "mla_paged")
+
+
+def test_served_ticks_through_the_kernel_keep_the_greedy_tokens(
+        monkeypatch, latent_interpreted):
+    """The served stack on pages of 8 slots, once as the TPU would serve it
+    (the layers answer `mla_paged`; the kernel interpreted) and once over
+    the gathered view: the same greedy tokens over eight ticks, the longer
+    rows crossing pages, logits as close as the file's tolerances. Two
+    registries: executables are keyed by shapes, not by the attention."""
+    from deeplearning4j_tpu import kernels
+
+    config = tiny_config(held=(0, 4))
+    r = np.random.default_rng(7)
+    prompts = [r.integers(0, 96, n).tolist() for n in (5, 19, 12)]
+    out = {}
+    for answer in ("mla_paged", "mla_absorbed"):
+        monkeypatch.setattr(kernels, "pallas_supported",
+                            lambda: answer == "mla_paged")
+        registry = ModelRegistry(buckets=(1,))
+        registry.register("lcf", build(config))
+        engine = DecodeEngine(registry, "lcf", block_len=8,
+                              decode_buckets=(4,), prompt_buckets=(32,))
+        assert engine.attention == answer
+        out[answer] = _serve(engine, registry.get("lcf"), engine.new_pool(),
+                             prompts, 8)[:2]
+    assert out["mla_paged"][0] == out["mla_absorbed"][0]
+    for a, b in zip(out["mla_paged"][1], out["mla_absorbed"][1]):
+        np.testing.assert_allclose(a, b, rtol=RTOL, atol=ATOL)
+
+
+@pytest.mark.parametrize("backend,kw,want", [
+    ("tpu", {}, "mla_paged"),
+    ("tpu", {"kv_dtype": "bf16", "block_len": 16}, "mla_paged"),
+    ("tpu", {"kv_dtype": "bf16"}, "mla_absorbed"),      # half a bf16 tile
+    ("tpu", {"kv_dtype": "int8"}, "mla_absorbed"),
+    ("tpu", {"width": 192}, "mla_absorbed"),            # no lane tiles
+    ("cpu", {}, "mla_absorbed"),
+], ids=["fp32", "bf16", "bf16-block8", "int8", "width192", "cpu"])
+def test_the_tick_takes_the_kernel_only_where_it_can_run(monkeypatch,
+                                                         backend, kw, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    spec = KvCacheSpec(**{**dict(channels=4, width=640, block_len=8,
+                                 num_blocks=9, max_context=64), **kw})
+    assert _block().decode_attention("tick", spec) == want
+    assert _block().decode_attention("prefill", spec) == "mla_expanded"
+    monkeypatch.setenv("DL4J_TPU_DISABLE_PALLAS", "1")
+    assert _block().decode_attention("tick", spec) == "mla_absorbed"
+
+
 def test_counts_reach_the_spans_and_the_counters(served):
     from deeplearning4j_tpu import telemetry
     _, _, registry, engine = served
