@@ -30,6 +30,7 @@ from .nn.layers import (ActivationLayer, AutoEncoder, BatchNormalization,
                         LossFunctionWrapper, LossLayer, OutputLayer,
                         PoolingType, RBM, RMSNormLayer, RnnOutputLayer,
                         ShortcutMoEBlock, SparseExpertsLayer,
+                        SambaYBlock, CrossDecoderBlock, LayerNormLayer,
                         Subsampling1DLayer, SubsamplingLayer,
                         VariationalAutoencoder, ZeroPaddingLayer)
 from .nn.updaters import (AdaDelta, AdaGrad, Adam, AdaMax, Nesterovs, NoOp,
@@ -66,6 +67,7 @@ __all__ = [
     "HybridSSMBlock", "LocalResponseNormalization", "LossFunctionWrapper", "LossLayer",
     "OutputLayer", "PoolingType", "RBM", "RMSNormLayer", "RnnOutputLayer",
     "ShortcutMoEBlock", "SparseExpertsLayer",
+    "SambaYBlock", "CrossDecoderBlock", "LayerNormLayer",
     "Subsampling1DLayer", "SubsamplingLayer", "VariationalAutoencoder",
     "ZeroPaddingLayer",
     "AdaDelta", "AdaGrad", "Adam", "AdaMax", "Nesterovs", "NoOp", "RmsProp",

@@ -522,3 +522,131 @@ def _latent_call(q, kv, channel, tables, lengths, v_width, sm_scale,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
       jnp.reshape(channel, (1,)).astype(jnp.int32), q, kv)
     return out[:, :n_heads, :v_width]
+
+
+# ---------------------------------------------------------------------------
+# Differential attention: two score maps a head over halves of the keys, one
+# value.
+#
+# A differential-attention layer (`nn/layers/sambay.py`) caches, a token, the
+# keys of its Hkv key/value heads, each a pair (k1, k2) of Dh lanes, and their
+# values of 2 Dh, each merged into Hkv*2Dh lanes; head i of H reads key/value
+# head floor(i / (H/Hkv)), its query q1 against k1 and q2 against k2. That is
+# `paged_decode_attention`'s grouped walk over a block-diagonal query of 2H
+# rows: row (s, i) holds q^s of head i on the k^s lanes of its key/value head
+# (`diff_block_diagonal`), so one product gives both maps' scores, each row
+# keeps its own softmax statistics, and `acc [2H, Hkv*2Dh] += p @ V_chunk`
+# holds each row's weighted value on its key/value head's 2 Dh lanes
+# (`diff_own_lanes`). The body is the grouped kernel's, called on that query
+# under a name of its own, `paged_diff_attention`, so that a trace tells it
+# from other layers' calls: each K and V page is read once a layer and row,
+# for both maps.
+# ---------------------------------------------------------------------------
+def diff_block_diagonal(q, kv_heads: int):
+    """q [B, H, 2, Dh] -> [B, 2H, Hkv*2Dh]: row (s, i) holds q^s of head i on
+    the lanes of k^s of its key/value head, zeros elsewhere."""
+    b, h, _, dh = q.shape
+    own = jnp.arange(h)[:, None] // (h // kv_heads) == jnp.arange(kv_heads)
+    sel = own[None, :, :, None] & jnp.eye(2, dtype=bool)[:, None, None, :]
+    qb = q.transpose(0, 2, 1, 3)[:, :, :, None, None, :]
+    return jnp.where(sel[None, ..., None], qb, 0.0).reshape(
+        b, 2 * h, kv_heads * 2 * dh)
+
+
+def diff_own_lanes(o, heads: int, kv_heads: int):
+    """o [..., R, Hkv*V]: row r is head r mod `heads`; its answer lies on the
+    V lanes of that head's key/value head -> [..., R, V]."""
+    rows = o.shape[-2]
+    own = (jnp.arange(rows)[:, None] % heads) // (heads // kv_heads) \
+        == jnp.arange(kv_heads)
+    o = o.reshape(*o.shape[:-1], kv_heads, -1)
+    return jnp.sum(jnp.where(own[..., None], o, 0.0), axis=-2)
+
+
+@functools.lru_cache(maxsize=64)
+def _planned_diff(rows, table_width, block_len, n_heads, q_rows, width,
+                  num_blocks, dtype) -> PagedPlan:
+    """`paged_plan` for one call shape of `paged_diff_attention` (its 2H
+    query rows padded to `q_rows`, whole tiles of the arena's dtype), worked
+    out once a process; it leaves the record `dl4j/kernels/paged_attention`
+    with `diff` 1."""
+    from ..telemetry import tracer
+
+    plan = paged_plan(rows, table_width, block_len, q_rows, width,
+                      jnp.dtype(dtype).itemsize)
+    tracer().instant("dl4j/kernels/paged_attention", rows=rows,
+                     table_width=table_width, block_len=block_len,
+                     n_heads=n_heads, width=width, num_blocks=num_blocks,
+                     diff=1, dtype=dtype, **plan._asdict())
+    return plan
+
+
+def paged_diff_attention(q, kv, channel, tables, lengths, *, n_kv_heads: int,
+                         sm_scale: float, interpret: Optional[bool] = None):
+    """Differential attention's two maps, one query pair a row, over its
+    paged cache.
+
+    q [B, H, 2, Dh] (head i's q1 and q2); kv the arena `[C, num_blocks,
+    block_len, Hkv*2Dh]`, float32 or bfloat16, read in place: channel
+    `channel` (int32 scalar, may be traced) holds each key/value head's
+    (k1, k2), channel + 1 its value of 2 Dh; tables [B, W] int32 block ids;
+    lengths [B] int32 live cache slots a row (>= 1). Returns [B, 2, H, 2Dh]
+    float32: for s = 1, 2, softmax(q^s_i . k^s * sm_scale) over the row's
+    slots times the values, head i on key/value head floor(i / (H/Hkv)).
+    Compiled Pallas on the TPU; `interpret=True` (automatic off it) runs the
+    same kernel through the interpreter."""
+    B, n_heads, two, d_head = q.shape
+    width = kv.shape[-1]
+    if two != 2 or n_heads % n_kv_heads or width != n_kv_heads * 2 * d_head:
+        raise ValueError(f"q {q.shape} in pairs on {n_kv_heads} key/value "
+                         f"heads and arena {kv.shape} disagree on the lanes")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _diff_call(q, kv, channel, tables, lengths, int(n_kv_heads),
+                      float(sm_scale), bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))  # graftlint: disable=unwatched-jit-entry
+def _diff_call(q, kv, channel, tables, lengths, n_kv_heads, sm_scale,
+               interpret):
+    """`paged_diff_attention`'s kernel, jitted on its own: the calls of one
+    shape in a step (a full layer and its cross layers) trace once a
+    process and lower once an executable."""
+    B, n_heads, _, d_head = q.shape
+    _, num_blocks, block_len, width = kv.shape
+    rows = 2 * n_heads
+    q_rows = _round_up(rows, _SUBLANES * max(1, 4 // kv.dtype.itemsize))
+    plan = _planned_diff(B, tables.shape[1], block_len, n_heads, q_rows,
+                         width, num_blocks, kv.dtype.name)
+    qbd = diff_block_diagonal(q.astype(jnp.float32), n_kv_heads)
+    qbd = jnp.pad(qbd, ((0, 0), (0, plan.heads_padded - rows), (0, 0)))
+    block = pl.BlockSpec((1, plan.heads_padded, width),
+                         lambda b, *_: (b, 0, 0))
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))}
+    out = pl.pallas_call(
+        _make_kernel(plan.heads_padded, 2 * d_head, plan, block_len, sm_scale,
+                     n_kv_heads, kv.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, plan.heads_padded, width),
+                                       jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((2, plan.pages_a_chunk * block_len, width),
+                           kv.dtype),                       # K pages
+                pltpu.VMEM((2, plan.pages_a_chunk * block_len, width),
+                           kv.dtype),                       # V pages
+                pltpu.SemaphoreType.DMA((2, 2)),            # [slot, K | V]
+                pltpu.SMEM((1,), jnp.int32),    # the slot of the next chunk 0
+            ]),
+        interpret=interpret,
+        name="paged_diff_attention",
+        **params,
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.reshape(channel, (1,)).astype(jnp.int32), qbd, kv)
+    out = diff_own_lanes(out[:, :rows], n_heads, n_kv_heads)
+    return out.reshape(B, 2, n_heads, 2 * d_head)

@@ -21,6 +21,7 @@ from .moe import MixtureOfExpertsLayer
 from .transformer import EmbeddingSequenceLayer, TransformerBlock
 from .shortcut_moe import RMSNormLayer, ShortcutMoEBlock, SparseExpertsLayer
 from .hybrid_ssm import HybridSSMBlock
+from .sambay import CrossDecoderBlock, LayerNormLayer, SambaYBlock
 
 __all__ = [
     "DenseLayer", "OutputLayer", "LossLayer", "ActivationLayer",
@@ -37,5 +38,5 @@ __all__ = [
     "MixtureOfExpertsLayer",
     "EmbeddingSequenceLayer", "TransformerBlock",
     "RMSNormLayer", "ShortcutMoEBlock", "SparseExpertsLayer",
-    "HybridSSMBlock",
+    "HybridSSMBlock", "SambaYBlock", "CrossDecoderBlock", "LayerNormLayer",
 ]

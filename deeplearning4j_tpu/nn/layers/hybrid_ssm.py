@@ -57,7 +57,8 @@ from ..conf.base import LayerConf, register_layer
 from ..conf.input_type import InputType
 from .shortcut_moe import _F32, _NEG, SparseExpertsLayer, _mm, _rms_norm
 
-__all__ = ["HybridSSMBlock", "ssm_scan", "ssm_step"]
+__all__ = ["HybridSSMBlock", "ssm_scan", "ssm_step", "causal_conv", "conv_prompt",
+           "conv_tail", "conv_tick", "state_tick", "to_slots"]
 
 MIXERS = ("mamba", "attention")
 
@@ -129,6 +130,81 @@ def ssm_step(state, xs, dt, a, bm, cm):
     keep = jnp.exp(dt * a)[..., None, None]
     state = keep * state + (xs * dt[..., None])[..., None] * bm[:, None, None]
     return jnp.sum(state * cm[:, None, None], axis=-1), state
+
+
+# ---------------------------------------------------------------------------
+# what every state-space mixer of a served stack does alike: the causal
+# depthwise convolution over a prompt and over a tick's stored inputs, and
+# the rows' slots of a per-sequence leaf (`nn/layers/sambay.py` shares them)
+# ---------------------------------------------------------------------------
+def causal_conv(p, window, taps: int):
+    """window [K, ..., C]: the K newest inputs, oldest first -> silu of the
+    convolution (`p["conv_W"]` [K, C], `p["conv_b"]` [C]) for the newest."""
+    w = p["conv_W"].astype(_F32)
+    total = sum(w[k] * window[k] for k in range(taps))
+    return jax.nn.silu(total + p["conv_b"].astype(_F32))
+
+
+def conv_prompt(p, xbc, taps: int):
+    """The convolution over whole sequences xbc [B, T, C]: (out [B, T, C],
+    the inputs with the K-1 zeros before the start, [B, K-1+T, C], which
+    `conv_tail` reads)."""
+    t = xbc.shape[1]
+    padded = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+    return causal_conv(p, [padded[:, j:j + t] for j in range(taps)],
+                       taps), padded
+
+
+def conv_tail(padded, lengths, taps: int):
+    """The K-1 inputs a tick needs after position `lengths - 1` of a
+    right-padded prompt, [B, K-1, C] (the leaf holds them [K-1, slots, C]),
+    from `conv_prompt`'s `padded`."""
+    # inputs length-K+1 .. length-1: rows length .. length+K-2 of padded
+    at = lengths[:, None] + jnp.arange(taps - 1)[None, :]
+    return jnp.take_along_axis(padded, at[..., None], axis=1)
+
+
+def to_slots(rows, slot, slots: int):
+    """rows [B, ...] laid on a leaf's `slots` at `slot` [B], zeros where no
+    row is."""
+    return jnp.zeros((slots,) + rows.shape[1:], rows.dtype).at[slot].set(rows)
+
+
+def everywhere(rows: int, slots: int) -> bool:
+    """Whether a tick of `rows` updates every slot where it lies: from a
+    quarter of the slots up a pass over the whole leaf in place costs less
+    than gathering the rows' slots out and scattering them back."""
+    return 4 * rows >= slots
+
+
+def conv_tick(p, conv, new, slot, taps: int):
+    """One input a row, new [B, C], through the stored inputs `conv`
+    [K-1, slots, C] of the rows' slots: (the convolution's output [B, C],
+    the new leaf). From a quarter of the slots up every slot where it lies,
+    a slot no row names keeping its inputs (`everywhere`)."""
+    slots = conv.shape[1]
+    if everywhere(slot.shape[0], slots):
+        named = to_slots(jnp.ones_like(slot, dtype=bool), slot, slots)
+        window = jnp.concatenate([conv, to_slots(new, slot, slots)[None]],
+                                 axis=0)
+        out = causal_conv(p, window, taps)[slot]
+        return out, jnp.where(named[None, :, None], window[1:], conv)
+    window = jnp.concatenate([conv[:, slot], new[None]], axis=0)
+    return causal_conv(p, window, taps), conv.at[:, slot].set(window[1:])
+
+
+def state_tick(step, state, slot, *rows):
+    """`step(state, *rows) -> (y, new state)` on the rows' slots of a leaf
+    `state` [slots, ...]. From a quarter of the slots up it runs over every
+    slot where it lies, the rows laid on their slots (a slot no row names
+    must come out of `step` unchanged: a step of 0); fewer rows read and
+    write their own slots alone."""
+    slots = state.shape[0]
+    if everywhere(slot.shape[0], slots):
+        y, state = step(state, *(to_slots(r, slot, slots) for r in rows))
+        return y[slot], state
+    y, mine = step(state[slot], *rows)
+    return y, state.at[slot].set(mine)
 
 
 @register_layer
@@ -235,13 +311,6 @@ class HybridSSMBlock(LayerConf):
         i, c = self._inner, self._conv_width
         return zxd[..., :i], zxd[..., i:i + c], zxd[..., i + c:]
 
-    def _conv(self, p, window):
-        """window [K, ..., C]: the K newest inputs, oldest first -> the
-        convolution's output for the newest, after silu."""
-        w = p["conv_W"].astype(_F32)
-        taps = sum(w[k] * window[k] for k in range(self.conv_kernel))
-        return jax.nn.silu(taps + p["conv_b"].astype(_F32))
-
     def _split(self, p, xbc, dt):
         """(xs [..., H, P], B [..., N], C [..., N], dt [..., H] after its
         bias and softplus, a [H])."""
@@ -263,20 +332,15 @@ class HybridSSMBlock(LayerConf):
         (out [B, T, d], the state after position `lengths - 1` (None: the
         last): ssm [B, H, P, N], conv [K-1, B, C])."""
         b, t, _ = u.shape
-        k = self.conv_kernel
         z, xbc, dt = self._project(p, u)
-        # the K-1 inputs before the start are zeros
-        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-        conv = self._conv(p, [padded[:, j:j + t] for j in range(k)])
+        conv, padded = conv_prompt(p, xbc, self.conv_kernel)
         xs, bm, cm, dt, a = self._split(p, conv, dt)
         if lengths is None:
             lengths = jnp.full((b,), t, jnp.int32)
         live = jnp.arange(t)[None, :] < lengths[:, None]
         dt = jnp.where(live[..., None], dt, 0.0)
         y, last = ssm_scan(xs, dt, a, bm, cm, self.chunk, p["W_in"].dtype)
-        # inputs length-K+1 .. length-1: rows length .. length+K-2 of padded
-        at = lengths[:, None] + jnp.arange(k - 1)[None, :]
-        tail = jnp.take_along_axis(padded, at[..., None], axis=1)
+        tail = conv_tail(padded, lengths, self.conv_kernel)
         return self._gate_out(p, y, xs, z), last, tail.transpose(1, 0, 2)
 
     def _mamba_tick(self, p, u, state, slot):
@@ -290,30 +354,13 @@ class HybridSSMBlock(LayerConf):
         read and a write of the leaf), no row gathered out and none
         scattered back. Fewer rows read and write their own slots alone:
         a gathered copy of theirs, some five passes over it, far under the
-        leaf."""
-        ssm, conv = state["ssm"], state["conv"]
-        slots = ssm.shape[0]
+        leaf (`conv_tick`, `state_tick`)."""
         z, xbc, dt = self._project(p, u[:, 0])
-        everywhere = 4 * u.shape[0] >= slots
-        if everywhere:
-            to_slots = lambda rows: jnp.zeros(
-                (slots,) + rows.shape[1:], rows.dtype).at[slot].set(rows)
-            named = to_slots(jnp.ones_like(slot, dtype=bool))
-            window = jnp.concatenate([conv, to_slots(xbc)[None]], axis=0)
-            out = self._conv(p, window)[slot]
-            conv = jnp.where(named[None, :, None], window[1:], conv)
-        else:
-            window = jnp.concatenate([conv[:, slot], xbc[None]], axis=0)
-            out = self._conv(p, window)
-            conv = conv.at[:, slot].set(window[1:])
+        out, conv = conv_tick(p, state["conv"], xbc, slot, self.conv_kernel)
         xs, bm, cm, dt, a = self._split(p, out, dt)
-        if everywhere:
-            y, ssm = ssm_step(ssm, to_slots(xs), to_slots(dt), a,
-                              to_slots(bm), to_slots(cm))
-            y = y[slot]
-        else:
-            y, rows = ssm_step(ssm[slot], xs, dt, a, bm, cm)
-            ssm = ssm.at[slot].set(rows)
+        y, ssm = state_tick(
+            lambda s, xs_, dt_, bm_, cm_: ssm_step(s, xs_, dt_, a, bm_, cm_),
+            state["ssm"], slot, xs, dt, bm, cm)
         return (self._gate_out(p, y, xs, z)[:, None],
                 {"ssm": ssm, "conv": conv})
 

@@ -95,9 +95,21 @@ with the up-projection absorbed, in a tick; the hybrid block
 (`nn/layers/hybrid_ssm.py`) K and V of its `Hkv*Dh` key/value heads where
 its mixer is grouped-query attention, and where it is a Mamba-2 layer no
 page at all but a recurrent state `[H, P, N]` float32 and the last three
-inputs of its convolution for each sequence. Such a stack's executables
-take one argument more, the rows' slots (a fourth upload a tick); a stack
-without a stateful layer has no such leaf, argument or upload.
+inputs of its convolution for each sequence; the SambaY blocks
+(`nn/layers/sambay.py`) keep a Mamba layer's state or a window layer's
+ring of keys and values a sequence, and the cross-decoder pages ONE pair
+of channels that its full-attention layer writes and its cross layers
+read. Such a stack's executables take one argument more, the rows' slots
+(a fourth upload a tick); a stack without a stateful layer has no such
+leaf, argument or upload. A prefill step may hand on each row's last
+real token alone, `[B, 1, d]` (the cross-decoder: the layers after its
+shared keys and values run for that token only); the head then reads it
+as it stands. A layer may state, for the records and nothing else, the
+window of keys a tick reads at most (`decode_window`), how many layers
+read its pages (`decode_shared_readers`) and that its prefill hands on the
+last token alone (`decode_prefill_last`): the executable's instant carries
+`window` and `shared_readers`, `tick.prepare` `window_live` (the ring
+slots the rows read), `prefill.prepare` `cross_tokens`.
 
 The cache pytree is DONATED and laid out `[2L, num_blocks, block_len,
 H*Dh]` (`cache.py` says why), so the arena updates in place on device: a
@@ -160,7 +172,9 @@ def split_decode_layers(model):
             "with decode steps (TransformerBlock: pages of K and V; "
             "ShortcutMoEBlock: pages of a latent; HybridSSMBlock: pages of "
             "grouped K and V, or a state-space layer's per-sequence state; "
-            "RMSNormLayer: neither) -> an output layer; got "
+            "SambaYBlock: per-sequence state or a window's ring; "
+            "CrossDecoderBlock: one shared pair of pages; RMSNormLayer, "
+            "LayerNormLayer: neither) -> an output layer; got "
             f"{[type(l).__name__ for l in (layers or [])]}")
     if getattr(model.conf, "preprocessors", None):
         raise ServingError(
@@ -298,6 +312,9 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
         off = jnp.broadcast_to(tidx % spec.block_len, (b, tp))
         x, cache, counts = _through(blocks, steps, emb.n_out, params[1:-1],
                                     cache, x, slot, blk, off, pos, lengths)
+        if x.shape[1] == 1 < tp:    # the layers handed on the last token
+            return (cache, head.preout(params[-1], {}, x)[:, 0].astype(
+                jnp.float32), *_stack_counts(counts))
         logits = head.preout(params[-1], {}, x)          # [B, Tp, V]
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
@@ -432,6 +449,15 @@ class DecodeEngine:
         blocks = split_decode_layers(v.model)[1]
         self.attention = _attention_of(blocks, "tick", self.spec)
         self.prefill_attention = _attention_of(blocks, "prefill", self.spec)
+        # what a stack may state of itself beside the contract, for the
+        # records: a window of keys a tick reads at most, the layers that
+        # read one shared pair of channels, a prefill that hands on each
+        # prompt's last token alone past some layer
+        said = lambda name: [getattr(b, name) for b in blocks
+                             if getattr(b, name, None)]
+        self.window = max(said("decode_window"), default=0)
+        self.shared_readers = sum(said("decode_shared_readers"))
+        self.prefill_last = bool(said("decode_prefill_last"))
         self._moe_picks = self._moe_pairs = None    # made with the first counts
         # what a tick with no predecessor in flight is handed as `last`
         self._no_ids = jnp.asarray(np.zeros(self.decode_buckets[-1], np.int32))
@@ -524,11 +550,13 @@ class DecodeEngine:
         `mla_expanded`)."""
         spec = self.spec
         options = {k: o for k, o in options.items() if o is not None}
-        record = {}
+        record = {k: n for k, n in (("window", self.window),
+                                    ("shared_readers", self.shared_readers))
+                  if n}
         if spec.state:
             arg_specs += (_i32(arg_specs[-1].shape[0]),)
-            record = {"state_bytes": spec.state_nbytes(),
-                      "state_slots": spec.state_slots}
+            record.update(state_bytes=spec.state_nbytes(),
+                          state_slots=spec.state_slots)
         step = watch_compiles(
             jax.jit(build_fn(v.model, v.snapshot, spec, **options),
                     donate_argnums=(1,)),
@@ -618,6 +646,8 @@ class DecodeEngine:
             n = len(prompt)
             tb = self.prompt_bucket_for(n)
             prepare.set(bucket=tb, tokens=n)
+            if self.prefill_last:   # tokens the layers after the cut compute
+                prepare.set(cross_tokens=1)
             tokens = np.zeros((1, tb), np.int32)
             tokens[0, :n] = np.asarray(prompt, np.int32)
             tab = np.asarray([self._pad_table(table)], np.int32)
@@ -687,6 +717,9 @@ class DecodeEngine:
             prepare.set(
                 pages_live=int((pos // self.spec.block_len + 1).sum()),
                 pages_table=tab.size)
+            if self.window:     # the ring slots a window layer reads
+                prepare.set(window_live=int(
+                    np.minimum(pos[:rows] + 1, self.window).sum()))
             slot = []
             if self.spec.state:     # pad rows keep the trash slot, 0
                 slot = [np.zeros(bucket, np.int32)]

@@ -456,3 +456,124 @@ def test_latent_ticks_update_the_arena_in_place_on_v5e(one_chip, latent_stack,
     else:                       # the view, 84 MB, is the largest temporary
         assert (len(kernels), len(views)) == (0, spec.channels)
         assert mem.temp_size_in_bytes > view
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_diff_paged_attention_kernel_compiles_for_v5e(one_chip, rows):
+    """The differential kernel at the Phi-4-mini-flash cell's geometry: 20
+    heads of query pairs (40 rows, padded to 48) on 10 key/value heads whose
+    keys are pairs of 64 and values 128, over bfloat16 pages of 16 slots x
+    1,280 lanes, an arena of 2 channels x 16,385 blocks, tables 256 wide. The
+    arena is abstract and is not copied."""
+    from deeplearning4j_tpu.kernels.paged_attention import paged_diff_attention
+
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    step = jax.jit(lambda q, kv, c, tables, lengths: paged_diff_attention(
+        q, kv, c, tables, lengths, n_kv_heads=10, sm_scale=0.125,
+        interpret=False))
+    with jax.enable_x64(False):
+        lowered = step.lower(
+            arg(jnp.float32, rows, 20, 2, 64),
+            arg(jnp.bfloat16, 2, 16385, 16, 1280), arg(jnp.int32),
+            arg(jnp.int32, rows, 256), arg(jnp.int32, rows))
+        assert "paged_diff_attention" in lowered.as_text()
+        compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * rows * 48 * 1280 * 4 + (1 << 20)
+
+
+@pytest.fixture(scope="module")
+def sambay_stack():
+    """(model, snapshot, spec) of Phi-4-mini-flash-reasoning at its widths
+    (2,560 wide, Mamba 5,120 x 16, 20 differential heads on 10 key/value
+    heads, a window of 512, MLP 10,240) in 8 layers: Mamba, window, Mamba,
+    window | Mamba, full, GMU, cross; a 256-token vocabulary; over the cell's
+    65 state slots and its arena of 16,385 pages of 16 x 1,280 bfloat16
+    lanes. The weights are shapes: nothing is made."""
+    import functools
+    import importlib.util
+    import json
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
+    from deeplearning4j_tpu.serving.decode.engine import cache_geometry
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+
+    def load(kind):
+        spec = importlib.util.spec_from_file_location(
+            f"compile_test_p4f_{kind}", bench / kind / "phi4flash.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    ref, models = load("reference"), load("models")
+    config = dict(json.loads(
+        (bench / "configs" / "phi-4-mini-flash-reasoning.json").read_text()),
+        num_hidden_layers=8, vocab_size=256)
+    shapes = SimpleNamespace(
+        dims=ref.dims, kinds=ref.kinds, init_params=lambda c, s: jax.eval_shape(
+            functools.partial(ref.init_params, c, s)))
+    model = models.build(config, 0, shapes, train=False)
+    leaves, treedef = jax.tree_util.tree_flatten(model.params)
+    snapshot = SimpleNamespace(
+        data=tuple(leaves),
+        rebuild=lambda data: jax.tree_util.tree_unflatten(treedef, list(data)))
+    channels, width, context, state = cache_geometry(model)
+    spec = KvCacheSpec(channels=channels, width=width, block_len=16,
+                       num_blocks=1 + 256 * 64, max_context=context,
+                       kv_dtype="bf16", state=state, state_slots=65)
+    return model, snapshot, spec
+
+
+@pytest.mark.parametrize("phase,bucket", [("served_tick", 64), ("tick", 8),
+                                          ("prefill", 1024)])
+def test_sambay_steps_update_arena_rings_and_state_in_place_on_v5e(
+        one_chip, sambay_stack, phase, bucket):
+    """The cell's prefill and tick, compiled for a described v5e: the arena
+    (1.34 GB), the window rings (85 MB a layer) and the Mamba state are
+    donated and written where they lie; no program holds a temporary of a
+    quarter of them or copies a ring or a layer's state. The tick reads the
+    shared pages through the differential kernel, once for each of the two
+    layers that read them here, and makes no view of them."""
+    import functools
+
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_decode_fn,
+                                                          build_prefill_fn,
+                                                          build_tick_fn)
+
+    model, snapshot, spec = sambay_stack
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    w = spec.table_width
+    if phase == "served_tick":
+        fn = functools.partial(build_tick_fn, rows_max=64,
+                               attention="diff_paged")
+        args = (i32(64), i32(bucket), i32(bucket), i32(bucket, w), i32(bucket))
+    elif phase == "tick":
+        fn = functools.partial(build_decode_fn, attention="diff_paged")
+        args = (i32(bucket), i32(bucket), i32(bucket, w), i32(bucket))
+    else:
+        fn, args = build_prefill_fn, (i32(1, bucket), i32(1), i32(1, w), i32(1))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn(model, snapshot, spec), donate_argnums=(1,)).lower(
+            on_chip(snapshot.data), on_chip(_cache_arg_specs(spec)),
+            *args).compile()
+    ring, ssm = 65 * 512 * 1280 * 2, 65 * 5120 * 16 * 4
+    held = spec.state_nbytes() + spec.arena_nbytes()
+    assert spec.state_nbytes() == 2 * 2 * ring + 3 * (ssm + 3 * 65 * 5120 * 4)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < held / 4
+    assert mem.alias_size_in_bytes >= held
+    text = compiled.as_text()
+    copies = [m for m in re.finditer(r"= (\w+)\[([\d,]*)\]\S* copy\(", text)
+              if _nbytes(m.group(1), m.group(2)) >= ssm]
+    assert not copies, [m.group(0) for m in copies]
+    kernels = re.findall(r"%paged_diff_attention[.\d]* = \S+ custom-call\(",
+                         text)
+    assert len(kernels) == (0 if phase == "prefill" else 2)
+    assert f"bf16[{bucket},256,16,1280]" not in text

@@ -503,3 +503,112 @@ def test_latent_plan_walks_a_row_in_chunks_of_512_slots():
                                jnp.int32(0), jnp.zeros((1, 2), jnp.int32),
                                jnp.ones((1,), jnp.int32), v_width=16,
                                sm_scale=1.0)
+
+
+# ---------------------------------------------------------------------------
+# differential attention (the SambaY cross-decoder's tick): two score maps a
+# head over halves of its key/value head's keys, one value of twice the width
+# ---------------------------------------------------------------------------
+def _diff_paged(lengths, heads, kv_heads, dtype="float32", channel=0, seed=0,
+                dead_block=0):
+    """(q [B, H, 2, 64], arena, tables, lengths): the arena's lanes hold
+    Hkv x (k1 | k2) on the key channel and Hkv values of 128 on the next;
+    block 1 is kept for NaN."""
+    _, kv, tables, lens = _paged(lengths, kv_heads * 2, seed=seed,
+                                 dead_block=dead_block,
+                                 layers=1 + channel // 2)
+    q = np.random.default_rng(seed + 7).normal(
+        size=(len(lengths), heads, 2, 64)).astype(np.float32)
+    return jnp.asarray(q), kv.astype(dtype), tables, lens
+
+
+def _diff_oracle(q, kv, channel, tables, lengths, kv_heads, scale):
+    """For s = 1, 2 and head i: softmax(q^s_i . k^s of key/value head
+    i // (H/Hkv)) over the row's live slots, times that head's value, in
+    float64 on the host over the gathered view."""
+    q = np.asarray(q.astype(kv.dtype).astype(jnp.float32), np.float64)
+    kv = np.asarray(kv.astype(jnp.float32), np.float64)
+    b, heads = q.shape[:2]
+    out = np.zeros((b, 2, heads, 128))
+    for row in range(b):
+        n = int(lengths[row])
+        view = lambda c: kv[c][np.asarray(tables[row])].reshape(
+            -1, kv_heads, 128)[:n]
+        k, v = view(channel), view(channel + 1)
+        for i in range(heads):
+            g = i // (heads // kv_heads)
+            for s in range(2):
+                z = k[:, g, 64 * s:64 * (s + 1)] @ q[row, i, s] * scale
+                w = np.exp(z - z.max())
+                out[row, s, i] = (w / w.sum()) @ v[:, g]
+    return out
+
+
+@pytest.mark.parametrize("lengths,heads,kv_heads,dtype,channel", [
+    ([167, 880, 1, 422, 513, 96], 4, 2, "float32", 0),
+    ([33, 129, 1024], 20, 10, "bfloat16", 2),   # the cell's heads and pages
+    ([16, 17, 128], 2, 1, "float32", 0),        # a page, one past, a chunk
+], ids=lambda v: v if isinstance(v, str) else
+    None if isinstance(v, int) else "x".join(map(str, v[:3])))
+def test_diff_kernel_matches_both_maps_over_the_gathered_view(
+        lengths, heads, kv_heads, dtype, channel):
+    q, kv, tables, lens = _diff_paged(lengths, heads, kv_heads, dtype, channel)
+    got = np.asarray(paged_mod.paged_diff_attention(
+        q, kv, jnp.int32(channel), tables, lens, n_kv_heads=kv_heads,
+        sm_scale=0.125, interpret=True))
+    want = _diff_oracle(q, kv, channel, tables, lens, kv_heads, 0.125)
+    assert got.shape == want.shape == (len(lengths), 2, heads, 128)
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" else \
+        dict(rtol=2e-2, atol=2e-2)      # the products' operands in bfloat16
+    np.testing.assert_allclose(got, want, **tol)
+    with pytest.raises(ValueError, match="disagree"):
+        paged_mod.paged_diff_attention(q, kv, jnp.int32(0), tables, lens,
+                                       n_kv_heads=kv_heads * 2, sm_scale=1.0)
+
+
+def test_diff_dead_pages_are_never_read():
+    """Dead table slots name a block of NaN, and so does every slot past a
+    row's last live page: the result stays finite and equal to the oracle's,
+    which reads the trash block there and masks it."""
+    lengths = [167, 1, 512, 513, 129, 1008]
+    q, kv, tables, lens = _diff_paged(lengths, 4, 2, seed=1, dead_block=1)
+    kv = kv.at[:, 1].set(jnp.nan)
+    got = np.asarray(paged_mod.paged_diff_attention(
+        q, kv, jnp.int32(0), tables, lens, n_kv_heads=2, sm_scale=0.125,
+        interpret=True))
+    assert np.isfinite(got).all()
+    live = jnp.arange(W)[None, :] * BL < lens[:, None]
+    want = _diff_oracle(q, kv, 0, jnp.where(live, tables, 0), lens, 2, 0.125)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+def test_diff_row_is_bit_identical_alone_and_among_others():
+    lengths = [300, 17, 1024, 640, 1, 95]
+    q, kv, tables, lens = _diff_paged(lengths, 4, 2, "bfloat16", seed=2)
+    run = functools.partial(paged_mod.paged_diff_attention, n_kv_heads=2,
+                            sm_scale=0.125, interpret=True)
+    among = np.asarray(run(q, kv, jnp.int32(0), tables, lens))
+    for row in (0, 2, 4):
+        alone = np.asarray(run(q[row:row + 1], kv, jnp.int32(0),
+                               tables[row:row + 1], lens[row:row + 1]))
+        np.testing.assert_array_equal(alone[0], among[row])
+
+
+def test_diff_kernel_leaves_its_record_once_a_call_shape():
+    previous = telemetry.tracer()
+    telemetry.install_tracer(telemetry.Tracer())
+    paged_mod._planned_diff.cache_clear()
+    try:
+        q, kv, tables, lens = _diff_paged([40, 90], 4, 2, "bfloat16", seed=3)
+        for _ in range(2):
+            paged_mod.paged_diff_attention(q, kv, jnp.int32(0), tables, lens,
+                                           n_kv_heads=2, sm_scale=0.125,
+                                           interpret=True)
+        recs = [r["attrs"] for r in telemetry.tracer().snapshot()
+                if r["name"] == "dl4j/kernels/paged_attention"]
+    finally:
+        telemetry.install_tracer(previous)
+    assert len(recs) == 1
+    assert (recs[0]["diff"], recs[0]["n_heads"], recs[0]["width"],
+            recs[0]["dtype"], recs[0]["heads_padded"]) == (
+                1, 4, 256, "bfloat16", 16)

@@ -58,7 +58,7 @@ def _case(name):
         LastTimeStep, LocalResponseNormalization, LossLayer,
         MixtureOfExpertsLayer, RMSNormLayer, RnnOutputLayer,
         ShortcutMoEBlock, SparseExpertsLayer, Subsampling1DLayer,
-        HybridSSMBlock,
+        HybridSSMBlock, SambaYBlock, CrossDecoderBlock, LayerNormLayer,
         SubsamplingLayer, TransformerBlock, VariationalAutoencoder,
         ZeroPaddingLayer)
     from deeplearning4j_tpu.nn.layers import RBM
@@ -162,6 +162,21 @@ def _case(name):
                             n_experts=4, top_k=2, expert_hidden=8,
                             held_experts=[0, 2]), rnn_head],
             InputType.recurrent(8, 6), _rnn_data(f=8)),
+        "SambaYBlock": lambda: (
+            [SambaYBlock(mixer="mamba", ssm_state=4, dt_rank=2, chunk=4,
+                         n_heads=2, n_kv_heads=1, head_dim=2, window=3,
+                         mlp_hidden=8),
+             SambaYBlock(mixer="window", n_heads=2, n_kv_heads=1, head_dim=2,
+                         window=3, mlp_hidden=8), rnn_head],
+            InputType.recurrent(8, 6), _rnn_data(f=8)),
+        "CrossDecoderBlock": lambda: (
+            [CrossDecoderBlock(layers=4, first_layer=2, ssm_state=4,
+                               dt_rank=2, chunk=4, n_heads=2, n_kv_heads=1,
+                               head_dim=2, mlp_hidden=8), rnn_head],
+            InputType.recurrent(8, 6), _rnn_data(f=8)),
+        "LayerNormLayer": lambda: (
+            [DenseLayer(n_out=8, activation="tanh"), LayerNormLayer(), head],
+            ff, fx),
         "EmbeddingSequenceLayer": lambda: (
             [EmbeddingSequenceLayer(n_in=20, n_out=8), rnn_head],
             InputType.recurrent(1, 6),
