@@ -43,6 +43,9 @@ and the convolution's last K-1 inputs `conv [K-1, slots, H*P + 2N]`
 rows would be padded to 8). A prefill writes the state its prompt leaves
 into its slot, a tick reads its rows' slots and writes them back; the
 leaves are donated with the rest of the cache and updated in place.
+Either mixer's tick runs its held experts as `SparseExpertsLayer` says
+(`shortcut_moe.py` module docstring): through one Pallas kernel a layer
+on the TPU (`decode_experts`), under a conditional an expert elsewhere.
 """
 from __future__ import annotations
 
@@ -392,13 +395,14 @@ class HybridSSMBlock(LayerConf):
         return _mm(out.reshape(b, t, -1), p["W_o"])
 
     # -- the block ---------------------------------------------------------
-    def _block(self, p, x, mix, live=None):
-        """The topology; `mix(p_mixer, x_normed)` is the mixer."""
+    def _block(self, p, x, mix, live=None, experts="cond"):
+        """The topology; `mix(p_mixer, x_normed)` is the mixer, `experts`
+        the held experts' path (`SparseExpertsLayer.mix`)."""
         r = self.residual_multiplier
         x = x.astype(_F32)
         x = x + r * mix(p["mixer"], _rms_norm(x, p["n1"], self.eps))
         m, counts = self.experts().mix(
-            p["moe"], _rms_norm(x, p["n2"], self.eps), live)
+            p["moe"], _rms_norm(x, p["n2"], self.eps), live, experts)
         return x + r * m, counts
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
@@ -452,6 +456,12 @@ class HybridSSMBlock(LayerConf):
             return "paged_kernel"
         return "gather"
 
+    def decode_experts(self, phase: str, width: int) -> str:
+        """The held experts' path in `phase` (`SparseExpertsLayer.
+        decode_experts`): "grouped_kernel" for a tick on the TPU, else
+        "cond"."""
+        return self.experts().decode_experts(phase, width)
+
     def decode_prefill_step(self, io, attention=None):
         if self.mixer == "mamba":
             def step(p, x, kv, sc, channel, blk, off, pos, lengths, state,
@@ -481,7 +491,7 @@ class HybridSSMBlock(LayerConf):
             return y, *cache, counts
         return step
 
-    def decode_tick_step(self, io, attention=None):
+    def decode_tick_step(self, io, attention=None, experts="cond"):
         if self.mixer == "mamba":
             def step(p, x, kv, sc, channel, blk, off, tables, positions,
                      lengths, state, slot):
@@ -493,7 +503,8 @@ class HybridSSMBlock(LayerConf):
 
                 # block 0 is the trash block: a row that writes there is a
                 # pad (its slot is the trash slot, 0)
-                y, counts = self._block(p, x, mix, (blk > 0)[:, None])
+                y, counts = self._block(p, x, mix, (blk > 0)[:, None],
+                                        experts)
                 return y, kv, sc, counts, kept["state"]
             return step
 
@@ -528,6 +539,6 @@ class HybridSSMBlock(LayerConf):
                     positions[:, None], lengths,
                     jnp.promote_types(k_all.dtype, pm["W_o"].dtype))
 
-            y, counts = self._block(p, x, mix, (blk > 0)[:, None])
+            y, counts = self._block(p, x, mix, (blk > 0)[:, None], experts)
             return y, *cache, counts
         return step

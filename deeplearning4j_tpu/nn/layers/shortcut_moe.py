@@ -52,9 +52,16 @@ left over are worked off in further passes of a quarter of the slots, by
 the experts that have rows left and no other, each under a conditional: no
 pick is ever dropped, and an uneven routing costs the experts it loads,
 not every expert over every token. A batch of at most twice the slots (a
-decode tick) is not gathered: every held expert runs over its rows under
-its mask, and not at all where no row picked it (its weights are then
-not read).
+decode tick) is not gathered: every held expert runs over all the rows
+under their weights, and not at all where no row picked it (its weights
+are then not read). That batch takes one of two paths (`EXPERT_PATHS`):
+a tick on the TPU streams the held experts through ONE Pallas kernel a
+layer ("grouped_kernel", `kernels.grouped_experts`: a grid step an expert
+or a tile of it, the next one's weights fetched while this one computes,
+the experts no row picked neither fetched nor computed), where the layer's
+`decode_experts("tick", width)` finds the TPU and weights of whole lane
+tiles; everywhere else (`apply`, a prefill, the CPU) each expert runs
+under its own conditional ("cond": the kernel's oracle).
 
 Serving (`serving/decode/engine.py` states the layers' contract): the
 block caches, for a token and an attention, the latent `c` (after norm
@@ -98,6 +105,7 @@ PICK_COUNTS = ("picks", "identity", "held", "held_hit", "held_load_max")
 # slots (`_held_sum`).
 _SLOT_FACTOR = 8
 SCORINGS = ("softmax_all", "softmax_picked")
+EXPERT_PATHS = ("cond", "grouped_kernel")
 
 
 def _rms_norm(x, g, eps):
@@ -274,11 +282,31 @@ class SparseExpertsLayer(LayerConf):
         _, ids = jax.lax.top_k(s + p["router_bias"].astype(_F32), k)
         return ids, self.routed_scaling * jnp.take_along_axis(s, ids, axis=-1)
 
-    def mix(self, p, x, live=None):
+    def decode_experts(self, phase: str, width: int) -> str:
+        """The path of the held experts' products in `phase` of a served
+        stack (module docstring): "grouped_kernel" for a tick where the
+        backend is the TPU (`pallas_supported`: and the kernels are not
+        switched off) and the experts, `width` wide, are float32 or
+        bfloat16 in whole lane tiles (`grouped_experts_supported`), else
+        "cond"."""
+        from ...kernels import pallas_supported
+        from ...kernels.grouped_experts import grouped_experts_supported
+
+        h = self.expert_hidden or 4 * width
+        if phase == "tick" and pallas_supported() and \
+                grouped_experts_supported(width, h, self.dtype or "float32"):
+            return "grouped_kernel"
+        return "cond"
+
+    def mix(self, p, x, live=None, experts="cond"):
         """(m [B, T, d] float32, counts [5] int32 as `PICK_COUNTS`): the
         held experts' part, the identity experts' part and the shared
         expert's of the layer's output for `x`; `live` [B, T] leaves pad
-        tokens out of the first two and of the counts."""
+        tokens out of the first two and of the counts. `experts` is the
+        path of a batch within twice the slots (`EXPERT_PATHS`)."""
+        if experts not in EXPERT_PATHS:
+            raise ValueError(f"experts must be one of {EXPERT_PATHS}, got "
+                             f"{experts!r}")
         shape = x.shape
         u = x.reshape(-1, shape[-1])
         ids, w = self.route(p, u)
@@ -293,7 +321,7 @@ class SparseExpertsLayer(LayerConf):
         took = jnp.any(hit, axis=1)                             # [N, E]
         w_held = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
         loads = jnp.sum(took, axis=0)
-        m = self._held_sum(p, u, w_held, took, loads)
+        m = self._held_sum(p, u, w_held, took, loads, experts)
         if self.n_identity:
             m = m + jnp.sum(jnp.where(on_identity, w, 0.0), axis=-1,
                             keepdims=True) * u.astype(_F32)
@@ -305,13 +333,19 @@ class SparseExpertsLayer(LayerConf):
                             jnp.max(loads)]).astype(jnp.int32)
         return m.reshape(shape).astype(_F32), counts
 
-    def _held_sum(self, p, u, w_held, took, loads):
+    def _held_sum(self, p, u, w_held, took, loads, experts="cond"):
         """sum over held e of w_held[:, e] * Expert_e(u): grouped (module
         docstring). u [N, d]; w_held, took [N, E]; loads [E]."""
         n, d = u.shape
         e_held = w_held.shape[1]
         slots = self.rows_per_expert(n)
         u = u.astype(p["expert_W_g"].dtype)     # what the products take
+        if n <= 2 * slots and experts == "grouped_kernel":
+            from ...kernels.grouped_experts import grouped_experts
+            # always the COMPILED kernel, whatever the process's backend
+            return grouped_experts(u, w_held, loads, p["expert_W_g"],
+                                   p["expert_W_u"], p["expert_W_d"],
+                                   interpret=False)
 
         def expert(e, rows, w_rows, left):
             """w_rows * Expert_e(rows), or nothing where no row is `left`
@@ -563,13 +597,14 @@ class ShortcutMoEBlock(LayerConf):
         return self._absorbed_out(p, o[:, None])
 
     # -- the block ---------------------------------------------------------
-    def _block(self, p, x, attend, live=None):
-        """The topology; `attend(i, p_attn, x_normed)` is attention i."""
+    def _block(self, p, x, attend, live=None, experts="cond"):
+        """The topology; `attend(i, p_attn, x_normed)` is attention i,
+        `experts` the held experts' path (`SparseExpertsLayer.mix`)."""
         h = x.astype(_F32)
         a = h + _mm(attend(0, p["attn0"], _rms_norm(h, p["n1"], self.eps)),
                     p["attn0"]["W_o"])
         u = _rms_norm(a, p["n2"], self.eps)
-        m, counts = self.experts().mix(p["moe"], u, live)
+        m, counts = self.experts().mix(p["moe"], u, live, experts)
         b = a + _ffn(p["ffn0"], u)
         c = b + _mm(attend(1, p["attn1"], _rms_norm(b, p["n3"], self.eps)),
                     p["attn1"]["W_o"])
@@ -617,6 +652,12 @@ class ShortcutMoEBlock(LayerConf):
             return "mla_paged"
         return "mla_absorbed"
 
+    def decode_experts(self, phase: str, width: int) -> str:
+        """The held experts' path in `phase` (`SparseExpertsLayer.
+        decode_experts`): "grouped_kernel" for a tick on the TPU, else
+        "cond"."""
+        return self.experts().decode_experts(phase, width)
+
     def _cached(self, io, latent):
         """The latent zero-padded to the arena's width (the scatter
         rounds it to the arena's dtype)."""
@@ -642,7 +683,7 @@ class ShortcutMoEBlock(LayerConf):
             return y, *cache, counts
         return step
 
-    def decode_tick_step(self, io, attention="mla_absorbed"):
+    def decode_tick_step(self, io, attention="mla_absorbed", experts="cond"):
         if attention not in ("mla_paged", "mla_absorbed", "mla_expanded"):
             raise ValueError(f"attention must be mla_paged|mla_absorbed|"
                              f"mla_expanded, got {attention!r}")
@@ -669,6 +710,7 @@ class ShortcutMoEBlock(LayerConf):
                 return attend_view(pa, qn, qr, view, pos, lengths)
 
             # block 0 is the trash block: a row that writes there is a pad
-            y, counts = self._block(p, x, attend, (blk > 0)[:, None])
+            y, counts = self._block(p, x, attend, (blk > 0)[:, None],
+                                    experts)
             return y, *cache, counts
         return step

@@ -109,7 +109,12 @@ window of keys a tick reads at most (`decode_window`), how many layers
 read its pages (`decode_shared_readers`) and that its prefill hands on the
 last token alone (`decode_prefill_last`): the executable's instant carries
 `window` and `shared_readers`, `tick.prepare` `window_live` (the ring
-slots the rows read), `prefill.prepare` `cross_tokens`.
+slots the rows read), `prefill.prepare` `cross_tokens`. A layer with
+routed experts also answers `decode_experts(phase, width)`, the path of
+its held experts' products (`grouped_kernel`: one Pallas kernel a layer,
+on the TPU; `cond`: a conditional an expert), and its tick step takes
+that answer as a third argument; the tick's instant carries it as
+`experts`.
 
 The cache pytree is DONATED and laid out `[2L, num_blocks, block_len,
 H*Dh]` (`cache.py` says why), so the arena updates in place on device: a
@@ -225,12 +230,15 @@ def _first_channels(blocks, width):
     return out
 
 
-def _attention_of(blocks, phase, spec):
-    """The attention path of the stack's `phase`: what its caching layers
-    answer (they must agree; None where none has a choice)."""
-    names = {blk.decode_attention(phase, spec) for blk in blocks} - {None}
+def _answer_of(blocks, question, phase, arg):
+    """The path of the stack's `phase` that its layers answer to
+    `question`: `decode_attention` over the cache's spec (every layer),
+    `decode_experts` over the width (the layers with routed experts). They
+    must agree; None where none has a choice."""
+    names = {getattr(blk, question)(phase, arg) for blk in blocks
+             if hasattr(blk, question)} - {None}
     if len(names) > 1:
-        raise ServingError(f"layers disagree on the {phase}'s attention: "
+        raise ServingError(f"layers disagree on the {phase}'s {question}: "
                            f"{sorted(names)}")
     return names.pop() if names else None
 
@@ -293,7 +301,8 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
     `data` tuple stays a runtime argument, so re-quantized checkpoints
     share the executable (the stateless plane's convention)."""
     emb, blocks, head = split_decode_layers(model)
-    attention = attention or _attention_of(blocks, "prefill", spec)
+    attention = attention or _answer_of(blocks, "decode_attention",
+                                        "prefill", spec)
     io = CacheIO(spec)
     steps = _shared_steps(
         blocks, lambda layer: layer.decode_prefill_step(io, attention))
@@ -324,12 +333,18 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
 
 
 def _decode_step(model, snapshot, spec: KvCacheSpec,
-                 attention: Optional[str] = None):
+                 attention: Optional[str] = None,
+                 experts: Optional[str] = None):
     emb, blocks, head = split_decode_layers(model)
-    attention = attention or _attention_of(blocks, "tick", spec)
+    attention = attention or _answer_of(blocks, "decode_attention", "tick",
+                                        spec)
+    experts = experts or _answer_of(blocks, "decode_experts", "tick",
+                                    emb.n_out)
     io = CacheIO(spec)
     steps = _shared_steps(
-        blocks, lambda layer: layer.decode_tick_step(io, attention))
+        blocks, lambda layer: layer.decode_tick_step(io, attention, experts)
+        if hasattr(layer, "decode_experts")
+        else layer.decode_tick_step(io, attention))
 
     def decode(data, cache, tokens, positions, tables, *slot):
         params = snapshot.rebuild(data)
@@ -348,22 +363,25 @@ def _decode_step(model, snapshot, spec: KvCacheSpec,
 
 
 def build_decode_fn(model, snapshot, spec: KvCacheSpec,
-                    attention: Optional[str] = None):
-    """Pure one-token decode step (see module docstring). `attention` is
-    what the stack's layers answer for a tick over `spec` unless given:
-    a GPT block's "paged_kernel" is the compiled kernel, whatever the
-    process's default backend (a test compiles it for a described
-    chip)."""
-    return named_step("tick", _decode_step(model, snapshot, spec, attention))
+                    attention: Optional[str] = None,
+                    experts: Optional[str] = None):
+    """Pure one-token decode step (see module docstring). `attention` and
+    `experts` are what the stack's layers answer for a tick over `spec`
+    unless given: a GPT block's "paged_kernel" or an expert layer's
+    "grouped_kernel" is the compiled kernel, whatever the process's
+    default backend (a test compiles it for a described chip)."""
+    return named_step("tick", _decode_step(model, snapshot, spec, attention,
+                                           experts))
 
 
 def build_tick_fn(model, snapshot, spec: KvCacheSpec, rows_max: int,
-                  attention: Optional[str] = None):
+                  attention: Optional[str] = None,
+                  experts: Optional[str] = None):
     """The served tick (see module docstring): the decode step, its input
     tokens selected on the device between the host's and the previous
     tick's ids `last` `[rows_max]`, and its rows' argmax returned beside
     the logits, padded to `rows_max`."""
-    decode = _decode_step(model, snapshot, spec, attention)
+    decode = _decode_step(model, snapshot, spec, attention, experts)
 
     def tick(data, cache, last, tokens, positions, tables, *slot):
         tokens = jnp.where(tokens < 0, last[jnp.maximum(-tokens - 1, 0)],
@@ -446,9 +464,13 @@ class DecodeEngine:
             max_context=max_context, kv_dtype=kv_dtype, state=state,
             # a slot for every row of the largest tick, and the trash slot
             state_slots=1 + self.decode_buckets[-1] if state else 0)
-        blocks = split_decode_layers(v.model)[1]
-        self.attention = _attention_of(blocks, "tick", self.spec)
-        self.prefill_attention = _attention_of(blocks, "prefill", self.spec)
+        emb, blocks, _ = split_decode_layers(v.model)
+        self.attention = _answer_of(blocks, "decode_attention", "tick",
+                                    self.spec)
+        self.prefill_attention = _answer_of(blocks, "decode_attention",
+                                            "prefill", self.spec)
+        self.experts = _answer_of(blocks, "decode_experts", "tick",
+                                  emb.n_out)
         # what a stack may state of itself beside the contract, for the
         # records: a window of keys a tick reads at most, the layers that
         # read one shared pair of channels, a prefill that hands on each
@@ -547,7 +569,8 @@ class DecodeEngine:
         tables have rows. `options` go to the builder and into the
         record: the phase's `attention`, where its layers have a choice
         (`paged_kernel` / `gather`, `mla_paged` / `mla_absorbed` /
-        `mla_expanded`)."""
+        `mla_expanded`), and a tick's `experts` (`grouped_kernel` /
+        `cond`), where it has layers with experts."""
         spec = self.spec
         options = {k: o for k, o in options.items() if o is not None}
         record = {k: n for k, n in (("window", self.window),
@@ -599,7 +622,8 @@ class DecodeEngine:
             lambda: self._compile(
                 v, functools.partial(build_tick_fn, rows_max=rows_max), "tick",
                 bucket, _i32(rows_max), _i32(bucket), _i32(bucket),
-                _i32(bucket, w), attention=self.attention),
+                _i32(bucket, w), attention=self.attention,
+                experts=self.experts),
             f"decode-b{bucket}")
 
     # -- host-facing phases ----------------------------------------------

@@ -577,3 +577,73 @@ def test_sambay_steps_update_arena_rings_and_state_in_place_on_v5e(
                          text)
     assert len(kernels) == (0 if phase == "prefill" else 2)
     assert f"bf16[{bucket},256,16,1280]" not in text
+
+
+@pytest.mark.parametrize("rows,d,h,experts", [
+    (64, 4096, 768, 36), (1, 4096, 768, 36), (32, 6144, 2048, 16),
+    (8, 6144, 2048, 16)], ids=["g4h-64", "g4h-1", "lcf-32", "lcf-8"])
+def test_grouped_experts_kernel_compiles_for_v5e(one_chip, rows, d, h,
+                                                 experts):
+    """The held experts' kernel at the two expert cells' shapes, bfloat16
+    as served: Granite 4.0-H's 36 experts of 4096 x 768 whole a grid step,
+    LongCat-Flash's 16 of 6144 x 2048 in tiles of 512, inside the VMEM limit
+    its plan states. The experts are abstract and are not copied: the
+    program holds the rows and their output beside them."""
+    from deeplearning4j_tpu.kernels.grouped_experts import (experts_plan,
+                                                            grouped_experts)
+
+    plan = experts_plan(rows, d, h, experts, 2)
+    assert plan.vmem_bytes < plan.vmem_limit_bytes
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    step = jax.jit(lambda u, w, loads, g, up, dn: grouped_experts(
+        u, w, loads, g, up, dn, interpret=False))
+    with jax.enable_x64(False):
+        lowered = step.lower(
+            arg(jnp.float32, rows, d), arg(jnp.float32, rows, experts),
+            arg(jnp.int32, experts), arg(jnp.bfloat16, experts, d, h),
+            arg(jnp.bfloat16, experts, d, h), arg(jnp.bfloat16, experts, h, d))
+        assert "grouped_experts" in lowered.as_text()
+        compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("stack", ["hybrid_stack", "latent_stack"])
+def test_expert_kernel_ticks_hold_no_conditional_and_no_copy_on_v5e(
+        one_chip, stack, request):
+    """The tick as the TPU serves it (`experts` "grouped_kernel"): one
+    `grouped_experts` custom call an expert layer, no conditional left (the
+    "cond" path holds one an expert and layer: `experiments/tick_hlo.py`),
+    no copy of an expert leaf, the cache still aliased."""
+    import functools
+
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_tick_fn)
+
+    model, snapshot, spec = request.getfixturevalue(stack)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    rows, w = (64, spec.table_width) if spec.state else (32, spec.table_width)
+    attention = "paged_kernel" if spec.state else "mla_paged"
+    layers = [b for b in model.layers if hasattr(b, "decode_experts")]
+    held = layers[0].experts().held()
+    leaves = {",".join(map(str, a.shape)) for a in snapshot.data
+              if a.ndim == 3 and a.shape[0] == len(held)}
+    fn = functools.partial(build_tick_fn, rows_max=rows, attention=attention,
+                           experts="grouped_kernel")
+    args = (i32(rows), i32(rows), i32(rows), i32(rows, w)) \
+        + ((i32(rows),) if spec.state else ())
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn(model, snapshot, spec), donate_argnums=(1,)).lower(
+            on_chip(snapshot.data), on_chip(_cache_arg_specs(spec)),
+            *args).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= spec.arena_nbytes() + spec.state_nbytes()
+    text = compiled.as_text()
+    kernels = re.findall(r"%grouped_experts[.\d]* = \S+ custom-call\(", text)
+    assert len(kernels) == len(layers)
+    assert " conditional(" not in text
+    copies = [m.group(0) for m in re.finditer(r"= \w+\[([\d,]*)\]\S* copy\(",
+                                              text) if m.group(1) in leaves]
+    assert len(leaves) == 2 and not copies, copies

@@ -434,9 +434,10 @@ def test_executable_records_carry_the_cache_and_the_attention(served):
                    if e["name"] == "dl4j/engine/executable"]
     finally:
         telemetry.install_tracer(previous)
-    assert [(r["phase"], r["channels"], r["width"], r["attention"])
-            for r in records] == [("prefill", 2, 128, "mla_expanded"),
-                                  ("tick", 2, 128, "mla_absorbed")]
+    assert [(r["phase"], r["channels"], r["width"], r["attention"],
+             r.get("experts")) for r in records] == [
+        ("prefill", 2, 128, "mla_expanded", None),
+        ("tick", 2, 128, "mla_absorbed", "cond")]
 
 
 # ---------------------------------------------------------------------------
