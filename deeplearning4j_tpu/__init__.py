@@ -27,8 +27,9 @@ from .nn.layers import (ActivationLayer, AutoEncoder, BatchNormalization,
                         GlobalPoolingLayer, GravesBidirectionalLSTM,
                         GravesLSTM, HybridSSMBlock,
                         LocalResponseNormalization,
-                        LossFunctionWrapper, LossLayer, OutputLayer,
-                        PoolingType, RBM, RMSNormLayer, RnnOutputLayer,
+                        LossFunctionWrapper, LossLayer, NemotronHBlock,
+                        OutputLayer, PoolingType, RBM, RMSNormLayer,
+                        RnnOutputLayer,
                         ShortcutMoEBlock, SparseExpertsLayer,
                         SambaYBlock, CrossDecoderBlock, LayerNormLayer,
                         Subsampling1DLayer, SubsamplingLayer,
@@ -65,7 +66,8 @@ __all__ = [
     "GaussianReconstructionDistribution",
     "GlobalPoolingLayer", "GravesBidirectionalLSTM", "GravesLSTM",
     "HybridSSMBlock", "LocalResponseNormalization", "LossFunctionWrapper", "LossLayer",
-    "OutputLayer", "PoolingType", "RBM", "RMSNormLayer", "RnnOutputLayer",
+    "NemotronHBlock", "OutputLayer", "PoolingType", "RBM", "RMSNormLayer",
+    "RnnOutputLayer",
     "ShortcutMoEBlock", "SparseExpertsLayer",
     "SambaYBlock", "CrossDecoderBlock", "LayerNormLayer",
     "Subsampling1DLayer", "SubsamplingLayer", "VariationalAutoencoder",

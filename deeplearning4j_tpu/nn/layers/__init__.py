@@ -22,6 +22,7 @@ from .transformer import EmbeddingSequenceLayer, TransformerBlock
 from .shortcut_moe import RMSNormLayer, ShortcutMoEBlock, SparseExpertsLayer
 from .hybrid_ssm import HybridSSMBlock
 from .sambay import CrossDecoderBlock, LayerNormLayer, SambaYBlock
+from .nemotron_h import NemotronHBlock
 
 __all__ = [
     "DenseLayer", "OutputLayer", "LossLayer", "ActivationLayer",
@@ -39,4 +40,5 @@ __all__ = [
     "EmbeddingSequenceLayer", "TransformerBlock",
     "RMSNormLayer", "ShortcutMoEBlock", "SparseExpertsLayer",
     "HybridSSMBlock", "SambaYBlock", "CrossDecoderBlock", "LayerNormLayer",
+    "NemotronHBlock",
 ]

@@ -10,14 +10,17 @@ scaled into the residual stream by `residual_multiplier` r:
 The mixer is a Mamba-2 state-space layer (`mixer="mamba"`; Dao & Gu 2024)
 or grouped-query attention without positions (`mixer="attention"`):
 
-    Mamba-2 (H heads of P, state N, one group, convolution of K taps):
-      [z (H*P) | xBC (H*P + 2N) | dt (H)] = u W_in
+    Mamba-2 (H heads of P, state N, G groups, convolution of K taps):
+      [z (H*P) | xBC (H*P + 2GN) | dt (H)] = u W_in
       xBC = silu(causal depthwise conv_K(xBC) + b_conv)
-      [xs (H*P) | B (N) | C (N)] = xBC
+      [xs (H*P) | B (G x N) | C (G x N)] = xBC
       dt = softplus(dt + dt_bias);  A = -exp(A_log)            a head
-      H_t = exp(dt_t A) H_{t-1} + dt_t * xs_t (x) B_t          [P, N] a head
-      y_t = H_t C_t + D * xs_t                                 B, C shared
-      out = RMSNorm_g(y * silu(z)) W_out          the norm over all H*P
+      H_t = exp(dt_t A) H_{t-1} + dt_t * xs_t (x) B_t,g        [P, N] a head
+      y_t = H_t C_t,g + D * xs_t          head h reads group g = h // (H/G)
+      out = RMSNorm_g(y * silu(z)) W_out  the norm within each group of
+                                          H*P/G channels, one gain of H*P
+    Granite 4.0-H has one group (`ssm_groups` 1: the norm over all H*P),
+    Nemotron-H eight (`nn/layers/nemotron_h.py`).
     Attention (Hq query heads, Hkv key/value heads of Dh, Hq/Hkv queries
     a key/value head, no rotation and no positional table):
       p = softmax_causal(q k^T * attention_multiplier);  out = (p v) W_o
@@ -38,7 +41,7 @@ attention variant pages keys and values of `Hkv * Dh` (2 channels); a
 prefill attends over its local projections, a tick over the gathered view.
 The Mamba variant keeps NO pages and, for each sequence, a constant-size
 STATE (`decode_state`): the recurrent state `ssm [slots, H, P, N]` float32
-and the convolution's last K-1 inputs `conv [K-1, slots, H*P + 2N]`
+and the convolution's last K-1 inputs `conv [K-1, slots, H*P + 2GN]`
 (the slot axis second: the device tiles the two minor dimensions, and 3
 rows would be padded to 8). A prefill writes the state its prompt leaves
 into its slot, a tick reads its rows' slots and writes them back; the
@@ -67,32 +70,52 @@ MIXERS = ("mamba", "attention")
 
 
 @functools.lru_cache(maxsize=256)
-def _scan_record(batch, tokens, chunk, heads, head_dim, state) -> int:
+def _scan_record(batch, tokens, chunk, heads, head_dim, state,
+                 groups=1) -> None:
     """The span-log instant `dl4j/layers/ssm_scan`, once a call shape a
-    process, written while a program is traced, never while one runs.
-    Returns the number of chunks."""
+    process, written while a program is traced, never while one runs."""
     from ...telemetry import tracer
 
-    chunks = -(-tokens // chunk)
     tracer().instant(
         "dl4j/layers/ssm_scan", batch=batch, tokens=tokens, chunk=chunk,
-        chunks=chunks, heads=heads, head_dim=head_dim, state=state,
+        chunks=-(-tokens // chunk), heads=heads, head_dim=head_dim,
+        state=state, groups=groups,
         state_bytes=4 * batch * heads * head_dim * state)
-    return chunks
 
 
 def ssm_scan(xs, dt, a, bm, cm, chunk: int, dot_dtype=_F32):
     """The recurrence over whole sequences, chunked (module docstring).
 
     xs [B, T, H, P], dt [B, T, H] (0 where a position is padding), a [H]
-    (negative), bm, cm [B, T, N], all float32; from the zero state.
+    (negative), bm, cm [B, T, N] (one group) or [B, T, G, N] (G groups:
+    head h reads group h // (H/G)), all float32; from the zero state.
     Returns (y [B, T, H, P] without the `D xs` term, the state after the
     last position [B, H, P, N]), float32. The matrix products take their
     operands in `dot_dtype`."""
     b, t, h, p = xs.shape
+    g = bm.shape[2] if bm.ndim == 4 else 1
+    _scan_record(b, t, min(int(chunk), t), h, p, bm.shape[-1], g)
+    if bm.ndim == 3:
+        return _scan(xs, dt, a, bm, cm, chunk, dot_dtype)
+    # each group is a sequence of its own over its H/G heads: the groups
+    # are folded into the batch, and the one-group scan runs over them
+    fold = lambda z: jnp.moveaxis(z.reshape(b, t, g, -1, *z.shape[3:]), 2,
+                                  1).reshape(b * g, t, -1, *z.shape[3:])
+    groups = lambda z: jnp.moveaxis(z, 2, 1).reshape(b * g, t, -1)
+    y, last = _scan(fold(xs), fold(dt),
+                    jnp.tile(a.reshape(g, 1, -1), (b, 1, 1)), groups(bm),
+                    groups(cm), chunk, dot_dtype)
+    y = jnp.moveaxis(y.reshape(b, g, t, -1, p), 1, 2).reshape(b, t, h, p)
+    return y, last.reshape(b, h, p, -1)
+
+
+def _scan(xs, dt, a, bm, cm, chunk: int, dot_dtype):
+    """`ssm_scan` of one group; `a` [H], or any shape that broadcasts
+    against dt [B, T, H]."""
+    b, t, h, p = xs.shape
     n = bm.shape[-1]
     q = min(int(chunk), t)
-    c = _scan_record(b, t, q, h, p, n)
+    c = -(-t // q)
     pad = c * q - t
     if pad:     # dt = 0 there: the state passes through
         grow = lambda z: jnp.pad(z, [(0, 0), (0, pad)] + [(0, 0)] * (z.ndim - 2))
@@ -128,8 +151,18 @@ def ssm_scan(xs, dt, a, bm, cm, chunk: int, dot_dtype=_F32):
 
 def ssm_step(state, xs, dt, a, bm, cm):
     """One step of the recurrence: state [B, H, P, N], xs [B, H, P], dt
-    [B, H], a [H], bm, cm [B, N] -> (y [B, H, P] without `D xs`, the new
-    state), in float32 with no matrix unit in it."""
+    [B, H], a [H], bm, cm [B, N] (or [B, G, N]: G groups) -> (y [B, H, P]
+    without `D xs`, the new state), in float32 with no matrix unit in
+    it."""
+    if bm.ndim == 3:        # G groups: the heads as [G, H/G]
+        b, h, p, n = state.shape
+        g = bm.shape[1]
+        heads = lambda z: z.reshape(b, g, h // g, *z.shape[2:])
+        keep = jnp.exp(heads(dt) * a.reshape(g, -1))[..., None, None]
+        state = keep * heads(state) + heads(xs * dt[..., None])[..., None] \
+            * bm[:, :, None, None]
+        y = jnp.sum(state * cm[:, :, None, None], axis=-1)
+        return y.reshape(b, h, p), state.reshape(b, h, p, n)
     keep = jnp.exp(dt * a)[..., None, None]
     state = keep * state + (xs * dt[..., None])[..., None] * bm[:, None, None]
     return jnp.sum(state * cm[:, None, None], axis=-1), state
@@ -224,6 +257,7 @@ class HybridSSMBlock(LayerConf):
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
+    ssm_groups: int = 1
     conv_kernel: int = 4
     chunk: int = 256
     # the attention mixer
@@ -273,42 +307,46 @@ class HybridSSMBlock(LayerConf):
 
     @property
     def _conv_width(self) -> int:
-        return self._inner + 2 * self.ssm_state
+        return self._inner + 2 * self.ssm_groups * self.ssm_state
 
     def init_params(self, rng, it: InputType):
         d = self._width(it)
         keys = iter(jax.random.split(rng, 12))
+        one = lambda n: jnp.ones((n,), jnp.dtype(self.dtype or "float32"))
+        return {"n1": one(d), "mixer": self.init_mixer(keys, d), "n2": one(d),
+                "moe": self.experts().init_params(next(keys), it, width=d)}
+
+    def init_mixer(self, keys, d: int):
+        """The mixer's parameters for a width `d`, drawn from the iterator of
+        keys `keys`."""
         w = lambda *s: self._winit(next(keys), s, s[0], s[1])
         dtype = jnp.dtype(self.dtype or "float32")
         one = lambda n: jnp.ones((n,), dtype)
         if self.mixer == "attention":
             kv = self.n_kv_heads * self.head_dim
-            mixer = {"W_q": w(d, self.n_heads * self.head_dim),
-                     "W_k": w(d, kv), "W_v": w(d, kv),
-                     "W_o": w(self.n_heads * self.head_dim, d)}
-        else:
-            h, k = self.ssm_heads, self.conv_kernel
-            uni = lambda lo, hi: jax.random.uniform(
-                next(keys), (h,), _F32, lo, hi)
-            step = jnp.exp(uni(jnp.log(1e-3), jnp.log(1e-1)))
-            mixer = {
-                "W_in": w(d, 2 * self._inner + 2 * self.ssm_state + h),
-                "conv_W": jax.random.uniform(
-                    next(keys), (k, self._conv_width), _F32,
-                    -k ** -0.5, k ** -0.5).astype(dtype),
-                "conv_b": jnp.zeros((self._conv_width,), dtype),
-                # softplus(dt_bias) in 0.001-0.1, A in 1-16: the public
-                # initialiser's ranges
-                "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dtype),
-                "A_log": jnp.log(uni(1.0, 16.0)).astype(dtype),
-                "D": one(h), "norm": one(self._inner),
-                "W_out": w(self._inner, d)}
-        return {"n1": one(d), "mixer": mixer, "n2": one(d),
-                "moe": self.experts().init_params(next(keys), it, width=d)}
+            return {"W_q": w(d, self.n_heads * self.head_dim),
+                    "W_k": w(d, kv), "W_v": w(d, kv),
+                    "W_o": w(self.n_heads * self.head_dim, d)}
+        h, k = self.ssm_heads, self.conv_kernel
+        uni = lambda lo, hi: jax.random.uniform(
+            next(keys), (h,), _F32, lo, hi)
+        step = jnp.exp(uni(jnp.log(1e-3), jnp.log(1e-1)))
+        return {
+            "W_in": w(d, self._inner + self._conv_width + h),
+            "conv_W": jax.random.uniform(
+                next(keys), (k, self._conv_width), _F32,
+                -k ** -0.5, k ** -0.5).astype(dtype),
+            "conv_b": jnp.zeros((self._conv_width,), dtype),
+            # softplus(dt_bias) in 0.001-0.1, A in 1-16: the public
+            # initialiser's ranges
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dtype),
+            "A_log": jnp.log(uni(1.0, 16.0)).astype(dtype),
+            "D": one(h), "norm": one(self._inner),
+            "W_out": w(self._inner, d)}
 
     # -- the Mamba-2 mixer -------------------------------------------------
     def _project(self, p, u):
-        """u [..., d] -> (z [..., H*P], xBC [..., H*P + 2N] before the
+        """u [..., d] -> (z [..., H*P], xBC [..., H*P + 2GN] before the
         convolution, dt [..., H] before its bias), float32."""
         zxd = _mm(u, p["W_in"])
         i, c = self._inner, self._conv_width
@@ -316,18 +354,26 @@ class HybridSSMBlock(LayerConf):
 
     def _split(self, p, xbc, dt):
         """(xs [..., H, P], B [..., N], C [..., N], dt [..., H] after its
-        bias and softplus, a [H])."""
-        i, n = self._inner, self.ssm_state
+        bias and softplus, a [H]); B and C [..., G, N] for G groups."""
+        i, n = self._inner, self.ssm_groups * self.ssm_state
         xs = xbc[..., :i].reshape(*xbc.shape[:-1], self.ssm_heads,
                                   self.ssm_head_dim)
         dt = jax.nn.softplus(dt + p["dt_bias"].astype(_F32))
-        return (xs, xbc[..., i:i + n], xbc[..., i + n:], dt,
-                -jnp.exp(p["A_log"].astype(_F32)))
+        bm, cm = xbc[..., i:i + n], xbc[..., i + n:]
+        if self.ssm_groups > 1:
+            bm, cm = (z.reshape(*z.shape[:-1], self.ssm_groups, -1)
+                      for z in (bm, cm))
+        return xs, bm, cm, dt, -jnp.exp(p["A_log"].astype(_F32))
 
     def _gate_out(self, p, y, xs, z):
-        """(y + D xs) gated by z, normed over all H*P, projected."""
+        """(y + D xs) gated by z, normed over all H*P (within each group of
+        H*P/G channels for G groups, one gain), projected."""
         y = y + p["D"].astype(_F32)[:, None] * xs
         y = y.reshape(*y.shape[:-2], self._inner) * jax.nn.silu(z)
+        if self.ssm_groups > 1:
+            y = y.reshape(*y.shape[:-1], self.ssm_groups, -1)
+            y = _rms_norm(y, jnp.ones((), _F32), self.eps).reshape(z.shape)
+            return _mm(y * p["norm"].astype(_F32), p["W_out"])
         return _mm(_rms_norm(y, p["norm"], self.eps), p["W_out"])
 
     def _mamba(self, p, u, lengths=None):

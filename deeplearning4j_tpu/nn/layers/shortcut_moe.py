@@ -23,11 +23,22 @@ output skips the second attention and FFN (the shortcut):
              m = sum over picked routed e of w_e * Expert_e(u)
                + sum over picked identity e of w_e * u
 
-`SparseExpertsLayer` knows a second scoring rule, `scoring=
+`SparseExpertsLayer` knows two more scoring rules, `scoring=
 "softmax_picked"` (Granite 4.0): the picks are the top-k router LOGITS,
 no correction bias, and `w_e = scale * softmax over the picked logits`;
-and a SHARED expert (`shared_hidden`), the same gated unit at its own
-width, that every token takes with weight 1.
+and `scoring="sigmoid"` (Nemotron-H, DeepSeek-V3): `s = sigmoid(u W_r)`
+in float32, picks by `s + b_corr`, `w_e = scale * s_e / sum over ALL the
+picks of s` (held or not). It knows a SHARED expert (`shared_hidden`), the
+same unit at its own width, that every token takes with weight 1; a
+second expert unit, `expert_activation="relu2"` (Nemotron-H):
+`relu(x W_u)^2 W_d`, two matrices and no gate; and a LATENT width
+(`latent`, Nemotron-H's LatentMoE): the routed experts live between
+`W_down` [d, latent] and `W_up` [latent, d],
+
+    m = (sum over picked held e of w_e * Expert_e(u W_down)) W_up
+
+while the router and the shared expert read `u` at full width. `W_up` is
+linear, so the shares still add up.
 
 No biases. `s_q = sqrt(d / q_rank)` and `s_kv = sqrt(d / kv_rank)` where
 `mla_scale` is set. Matrix products take their operands in the weights'
@@ -49,19 +60,22 @@ that picked it and no other, gathered into `rows_per_expert(n)` slots:
 among hundreds of tokens (a prefill) one batched product over all the
 held experts' slots. Where an expert's load passes its slots, the rows
 left over are worked off in further passes of a quarter of the slots, by
-the experts that have rows left and no other, each under a conditional: no
+the experts that have rows left and no other, each under a conditional (one
+in a loop over the experts where a layer holds over `_UNROLLED_EXPERTS`,
+so that the program's size and its compile do not grow with them): no
 pick is ever dropped, and an uneven routing costs the experts it loads,
 not every expert over every token. A batch of at most twice the slots (a
 decode tick) is not gathered: every held expert runs over all the rows
 under their weights, and not at all where no row picked it (its weights
-are then not read). That batch takes one of two paths (`EXPERT_PATHS`):
-a tick on the TPU streams the held experts through ONE Pallas kernel a
-layer ("grouped_kernel", `kernels.grouped_experts`: a grid step an expert
-or a tile of it, the next one's weights fetched while this one computes,
-the experts no row picked neither fetched nor computed), where the layer's
+are then not read). A tick takes one of two paths (`EXPERT_PATHS`): on
+the TPU it streams the held experts through ONE Pallas kernel a layer
+("grouped_kernel", `kernels.grouped_experts`: a grid step an expert or a
+tile of it, the next one's weights fetched while this one computes, the
+experts no row picked neither fetched nor computed), where the layer's
 `decode_experts("tick", width)` finds the TPU and weights of whole lane
-tiles; everywhere else (`apply`, a prefill, the CPU) each expert runs
-under its own conditional ("cond": the kernel's oracle).
+tiles, whatever the tick's rows (Nemotron-H's 128 are four times a held
+expert's slots); everywhere else (`apply`, a prefill, the CPU) each
+expert runs under its own conditional ("cond": the kernel's oracle).
 
 Serving (`serving/decode/engine.py` states the layers' contract): the
 block caches, for a token and an attention, the latent `c` (after norm
@@ -104,7 +118,13 @@ PICK_COUNTS = ("picks", "identity", "held", "held_hit", "held_load_max")
 # Grouping engages for every density of picks at batches over twice the
 # slots (`_held_sum`).
 _SLOT_FACTOR = 8
-SCORINGS = ("softmax_all", "softmax_picked")
+# held experts up to which each has a conditional of its own in the program;
+# a layer that holds more runs them in a loop under one conditional (in a
+# program each costs compile time: Nemotron-H's 128 held in 5 layers made a
+# prefill of 645, about 70 s to compile for a v5e)
+_UNROLLED_EXPERTS = 64
+SCORINGS = ("softmax_all", "softmax_picked", "sigmoid")
+EXPERT_ACTIVATIONS = ("swiglu", "relu2")
 EXPERT_PATHS = ("cond", "grouped_kernel")
 
 
@@ -121,6 +141,15 @@ def _mm(x, w):
 
 def _swiglu(x, w_g, w_u, w_d):
     return _mm(jax.nn.silu(_mm(x, w_g)) * _mm(x, w_u), w_d)
+
+
+def _relu2(x, w_u, w_d):
+    return _mm(jnp.square(jax.nn.relu(_mm(x, w_u))), w_d)
+
+
+def _unit(x, w_g, w_u, w_d):
+    """One expert: SwiGLU where it has a gate, else relu^2 (`w_g` None)."""
+    return _relu2(x, w_u, w_d) if w_g is None else _swiglu(x, w_g, w_u, w_d)
 
 
 def _ffn(p, x):
@@ -195,8 +224,8 @@ class RMSNormLayer(LayerConf):
 @register_layer
 @dataclass
 class SparseExpertsLayer(LayerConf):
-    """Top-k routed SwiGLU experts with zero-compute (identity) experts,
-    in sequence layout: x [B, T, d] -> [B, T, d] float32 (module
+    """Top-k routed SwiGLU (or relu^2) experts with zero-compute (identity)
+    experts, in sequence layout: x [B, T, d] -> [B, T, d] float32 (module
     docstring). `held_experts` = [lo, hi) of the `n_experts` routed ones
     live here (None: all); the weights hold those alone."""
 
@@ -208,8 +237,10 @@ class SparseExpertsLayer(LayerConf):
     expert_hidden: int = 0          # default: 4 * width
     routed_scaling: float = 1.0
     held_experts: Optional[List[int]] = None
-    scoring: str = "softmax_all"    # or "softmax_picked" (module docstring)
+    scoring: str = "softmax_all"    # or "softmax_picked", "sigmoid"
     shared_hidden: int = 0          # the shared expert's width; 0: none
+    expert_activation: str = "swiglu"   # or "relu2" (module docstring)
+    latent: int = 0                 # the routed experts' width; 0: the model's
 
     def output_type(self, it: InputType) -> InputType:
         return InputType.recurrent(it.size, it.timesteps)
@@ -222,6 +253,10 @@ class SparseExpertsLayer(LayerConf):
         if self.scoring not in SCORINGS:
             raise ValueError(f"scoring must be one of {SCORINGS}, got "
                              f"{self.scoring!r}")
+        if self.expert_activation not in EXPERT_ACTIVATIONS:
+            raise ValueError(f"expert_activation must be one of "
+                             f"{EXPERT_ACTIVATIONS}, got "
+                             f"{self.expert_activation!r}")
 
     def held(self) -> range:
         lo, hi = self.held_experts or (0, self.n_experts)
@@ -244,20 +279,28 @@ class SparseExpertsLayer(LayerConf):
         h = self.expert_hidden or 4 * d
         e, routes = len(self.held()), self.n_experts + self.n_identity
         k = jax.random.split(rng, 7)
+        gated = self.expert_activation == "swiglu"
+        de = self.latent or d
         p = {
             "router_W": self._winit(k[0], (d, routes), d, routes),
             # expert_-prefixed tensors shard on axis 0 (expert parallelism)
-            "expert_W_g": self._winit(k[1], (e, d, h), d, h),
-            "expert_W_u": self._winit(k[2], (e, d, h), d, h),
-            "expert_W_d": self._winit(k[3], (e, h, d), h, d),
+            "expert_W_u": self._winit(k[2], (e, de, h), de, h),
+            "expert_W_d": self._winit(k[3], (e, h, de), h, de),
         }
-        if self.scoring == "softmax_all":       # the correction bias
+        if gated:
+            p["expert_W_g"] = self._winit(k[1], (e, de, h), de, h)
+        if self.scoring != "softmax_picked":     # the correction bias
             p["router_bias"] = self._binit((routes,))
         if self.shared_hidden:
             s = self.shared_hidden
-            p.update(shared_W_g=self._winit(k[4], (d, s), d, s),
-                     shared_W_u=self._winit(k[5], (d, s), d, s),
+            p.update(shared_W_u=self._winit(k[5], (d, s), d, s),
                      shared_W_d=self._winit(k[6], (s, d), s, d))
+            if gated:
+                p["shared_W_g"] = self._winit(k[4], (d, s), d, s)
+        if self.latent:
+            down, up = jax.random.split(jax.random.fold_in(rng, 7))
+            p.update(W_down=self._winit(down, (d, de), d, de),
+                     W_up=self._winit(up, (de, d), de, d))
         return p
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
@@ -271,13 +314,21 @@ class SparseExpertsLayer(LayerConf):
         float32. "softmax_all": softmax over all routes, picks by score +
         correction bias, weights the scaled scores themselves (not
         renormalised). "softmax_picked": picks by logit, weights the
-        scaled softmax over the picked logits."""
+        scaled softmax over the picked logits. "sigmoid": picks by sigmoid
+        score + correction bias, weights the scaled scores over their sum
+        over the k picks."""
         with jax.default_matmul_precision("highest"):
             s = jnp.dot(u.astype(_F32), p["router_W"].astype(_F32))
         k = min(self.top_k, s.shape[-1])
         if self.scoring == "softmax_picked":
             top, ids = jax.lax.top_k(s, k)
             return ids, self.routed_scaling * jax.nn.softmax(top, axis=-1)
+        if self.scoring == "sigmoid":
+            s = jax.nn.sigmoid(s)
+            _, ids = jax.lax.top_k(s + p["router_bias"].astype(_F32), k)
+            w = jnp.take_along_axis(s, ids, axis=-1)
+            return ids, self.routed_scaling * w / (
+                jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         s = jax.nn.softmax(s, axis=-1)
         _, ids = jax.lax.top_k(s + p["router_bias"].astype(_F32), k)
         return ids, self.routed_scaling * jnp.take_along_axis(s, ids, axis=-1)
@@ -286,15 +337,17 @@ class SparseExpertsLayer(LayerConf):
         """The path of the held experts' products in `phase` of a served
         stack (module docstring): "grouped_kernel" for a tick where the
         backend is the TPU (`pallas_supported`: and the kernels are not
-        switched off) and the experts, `width` wide, are float32 or
-        bfloat16 in whole lane tiles (`grouped_experts_supported`), else
-        "cond"."""
+        switched off) and the experts, `width` wide (or `latent`), are
+        float32 or bfloat16 in whole lane tiles
+        (`grouped_experts_supported`), else "cond"."""
         from ...kernels import pallas_supported
         from ...kernels.grouped_experts import grouped_experts_supported
 
         h = self.expert_hidden or 4 * width
+        matrices = 3 if self.expert_activation == "swiglu" else 2
         if phase == "tick" and pallas_supported() and \
-                grouped_experts_supported(width, h, self.dtype or "float32"):
+                grouped_experts_supported(self.latent or width, h,
+                                          self.dtype or "float32", matrices):
             return "grouped_kernel"
         return "cond"
 
@@ -303,7 +356,8 @@ class SparseExpertsLayer(LayerConf):
         held experts' part, the identity experts' part and the shared
         expert's of the layer's output for `x`; `live` [B, T] leaves pad
         tokens out of the first two and of the counts. `experts` is the
-        path of a batch within twice the slots (`EXPERT_PATHS`)."""
+        path of a batch within twice the slots, and of any tick's batch
+        for "grouped_kernel" (`EXPERT_PATHS`)."""
         if experts not in EXPERT_PATHS:
             raise ValueError(f"experts must be one of {EXPERT_PATHS}, got "
                              f"{experts!r}")
@@ -321,13 +375,17 @@ class SparseExpertsLayer(LayerConf):
         took = jnp.any(hit, axis=1)                             # [N, E]
         w_held = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
         loads = jnp.sum(took, axis=0)
-        m = self._held_sum(p, u, w_held, took, loads, experts)
+        if self.latent:
+            m = _mm(self._held_sum(p, _mm(u, p["W_down"]), w_held, took,
+                                   loads, experts), p["W_up"])
+        else:
+            m = self._held_sum(p, u, w_held, took, loads, experts)
         if self.n_identity:
             m = m + jnp.sum(jnp.where(on_identity, w, 0.0), axis=-1,
                             keepdims=True) * u.astype(_F32)
         if self.shared_hidden:
-            m = m + _swiglu(u, p["shared_W_g"], p["shared_W_u"],
-                            p["shared_W_d"])
+            m = m + _unit(u, p.get("shared_W_g"), p["shared_W_u"],
+                          p["shared_W_d"])
         counts = jnp.stack([jnp.sum(picked), jnp.sum(on_identity),
                             jnp.sum(loads), jnp.sum(loads > 0),
                             jnp.max(loads)]).astype(jnp.int32)
@@ -335,32 +393,39 @@ class SparseExpertsLayer(LayerConf):
 
     def _held_sum(self, p, u, w_held, took, loads, experts="cond"):
         """sum over held e of w_held[:, e] * Expert_e(u): grouped (module
-        docstring). u [N, d]; w_held, took [N, E]; loads [E]."""
+        docstring). u [N, d] (d the latent width where there is one);
+        w_held, took [N, E]; loads [E]."""
         n, d = u.shape
         e_held = w_held.shape[1]
         slots = self.rows_per_expert(n)
-        u = u.astype(p["expert_W_g"].dtype)     # what the products take
-        if n <= 2 * slots and experts == "grouped_kernel":
+        w_g = p.get("expert_W_g")                 # None: relu^2 experts
+        u = u.astype(p["expert_W_u"].dtype)     # what the products take
+        if experts == "grouped_kernel":     # a tick, whatever its rows
             from ...kernels.grouped_experts import grouped_experts
             # always the COMPILED kernel, whatever the process's backend
-            return grouped_experts(u, w_held, loads, p["expert_W_g"],
-                                   p["expert_W_u"], p["expert_W_d"],
-                                   interpret=False)
+            return grouped_experts(u, w_held, loads, w_g, p["expert_W_u"],
+                                   p["expert_W_d"], interpret=False)
 
         def expert(e, rows, w_rows, left):
             """w_rows * Expert_e(rows), or nothing where no row is `left`
             for e (its weights are then not read)."""
             def run(_):
-                y = _swiglu(rows, p["expert_W_g"][e], p["expert_W_u"][e],
-                            p["expert_W_d"][e])
+                y = _unit(rows, None if w_g is None else w_g[e],
+                          p["expert_W_u"][e], p["expert_W_d"][e])
                 return y * w_rows[:, None]
             return jax.lax.cond(
                 left > 0, run,
                 lambda _: jnp.zeros((rows.shape[0], d), _F32), None)
 
+        unrolled = e_held <= _UNROLLED_EXPERTS
         if n <= 2 * slots:      # not worth a gather and a scatter
-            return sum(expert(e, u, w_held[:, e], loads[e])
-                       for e in range(e_held))
+            if unrolled:
+                return sum(expert(e, u, w_held[:, e], loads[e])
+                           for e in range(e_held))
+            return jax.lax.fori_loop(
+                0, e_held, lambda e, out: out + expert(e, u, w_held[:, e],
+                                                       loads[e]),
+                jnp.zeros((n, d), _F32))
 
         # rank of token n in expert e's group: how many before it took e
         rank = jnp.cumsum(took, axis=0) - 1                     # [N, E]
@@ -386,14 +451,21 @@ class SparseExpertsLayer(LayerConf):
             the experts' weights where a conditional an expert would stop
             and start 36 times a layer."""
             dot = functools.partial(jnp.einsum, preferred_element_type=_F32)
-            hidden = jax.nn.silu(dot("esd,edh->esh", rows, p["expert_W_g"])) \
-                * dot("esd,edh->esh", rows, p["expert_W_u"])
+            if w_g is None:
+                hidden = jnp.square(jax.nn.relu(
+                    dot("esd,edh->esh", rows, p["expert_W_u"])))
+            else:
+                hidden = jax.nn.silu(dot("esd,edh->esh", rows, w_g)) \
+                    * dot("esd,edh->esh", rows, p["expert_W_u"])
             return dot("esh,ehd->esd", hidden.astype(rows.dtype),
                        p["expert_W_d"]) * w_rows[..., None]
 
         def one_by_one(rows, w_rows, left):
-            return jnp.stack([expert(e, rows[e], w_rows[e], left[e])
-                              for e in range(e_held)])
+            if unrolled:
+                return jnp.stack([expert(e, rows[e], w_rows[e], left[e])
+                                  for e in range(e_held)])
+            return jax.lax.map(lambda a: expert(*a), (
+                jnp.arange(e_held), rows, w_rows, left))
 
         # what passes the slots is a few rows of a few experts: a quarter
         # of the slots a pass, so that a pass gathers and scatters little

@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import json
 import re
+import socket
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -107,6 +108,16 @@ def require(body: Dict, key: str):
     return body[key]
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    """The listening socket queues as many connections not yet accepted as
+    the kernel allows. socketserver's default of 5 drops the connects of a
+    burst (128 closed-loop clients, several answered by one tick, connect
+    again together): a dropped connect waits out TCP's retransmissions, 1,
+    3, 7, 15 s ..., or is reset."""
+
+    request_queue_size = socket.SOMAXCONN
+
+
 class InferenceServer:
     """HTTP front end over a ModelRegistry with per-model dynamic
     batching. `batching=False` serves every request on the direct
@@ -140,7 +151,7 @@ class InferenceServer:
             "request latency through the serving data plane (queue wait + "
             "forward) by path", labels=("model", "path"))
         self.slo = SloSurface(m, targets=slo_targets)
-        self._httpd = ThreadingHTTPServer((host, port), self._make_handler())
+        self._httpd = _HTTPServer((host, port), self._make_handler())
         self.host, self.port = self._httpd.server_address[:2]
         self._thread: Optional[threading.Thread] = None
 

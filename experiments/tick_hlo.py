@@ -5,7 +5,8 @@ The weights are shapes: nothing is made and nothing runs.
     python3 experiments/tick_hlo.py <config> [attention] [experts] [described]
 
 `config` names `benchmarks/configs/<config>.json`: `longcat-flash-chat`
-(cell 3, bucket 32) or `granite-4.0-h-small` (cell 4, bucket 64).
+(cell 3, bucket 32), `granite-4.0-h-small` (cell 4, bucket 64) or
+`nemotron-3-super-120b-a12b` (cell 6, bucket 128).
 `attention` (`mla_absorbed`, `mla_paged`, `gather`, `paged_kernel`) and
 `experts` (`cond`, `grouped_kernel`) are the tick's paths (default: what the
 stack's layers answer on this backend); `described` compiles for a v5e that
@@ -30,7 +31,8 @@ import jax
 import jax.numpy as jnp
 
 FAMILIES = {"longcat-flash-chat": ("longcat_flash", 32),
-            "granite-4.0-h-small": ("granite_moe_hybrid", 64)}
+            "granite-4.0-h-small": ("granite_moe_hybrid", 64),
+            "nemotron-3-super-120b-a12b": ("nemotron_h", 128)}
 ATTENTIONS = ("mla_absorbed", "mla_paged", "mla_expanded", "gather",
               "paged_kernel")
 EXPERTS = ("cond", "grouped_kernel")

@@ -647,3 +647,124 @@ def test_expert_kernel_ticks_hold_no_conditional_and_no_copy_on_v5e(
     copies = [m.group(0) for m in re.finditer(r"= \w+\[([\d,]*)\]\S* copy\(",
                                               text) if m.group(1) in leaves]
     assert len(leaves) == 2 and not copies, copies
+
+
+@pytest.mark.parametrize("rows", [128, 1], ids=["n3s-128", "n3s-1"])
+def test_relu2_experts_kernel_compiles_for_v5e(one_chip, rows):
+    """The held experts' kernel in its relu^2 form at Nemotron 3 Super's
+    shape, bfloat16 as served: 128 experts of two matrices, 1,024 x 2,688
+    in the latent, whole a grid step, 128 rows (the cell's tick) or one."""
+    from deeplearning4j_tpu.kernels.grouped_experts import (experts_plan,
+                                                            grouped_experts)
+
+    d, h, experts = 1024, 2688, 128
+    plan = experts_plan(rows, d, h, experts, 2, matrices=2)
+    assert plan.tile == h and plan.vmem_bytes < plan.vmem_limit_bytes
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    step = jax.jit(lambda u, w, loads, up, dn: grouped_experts(
+        u, w, loads, None, up, dn, interpret=False))
+    with jax.enable_x64(False):
+        lowered = step.lower(
+            arg(jnp.float32, rows, d), arg(jnp.float32, rows, experts),
+            arg(jnp.int32, experts), arg(jnp.bfloat16, experts, d, h),
+            arg(jnp.bfloat16, experts, h, d))
+        assert "grouped_experts" in lowered.as_text()
+        compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 20
+
+
+@pytest.fixture(scope="module")
+def nemotron_stack():
+    """(model, snapshot, spec) of one Mamba-2 layer (8 groups), the
+    attention layer and one LatentMoE layer at Nemotron 3 Super's widths
+    (4096 wide, 128 heads of 64 with a state of 128, 32 queries on 2
+    key/value heads of 128, experts of 1,024 x 2,688 in the latent), 8 of
+    the 512 experts held and a 256-token vocabulary, over the served cell's
+    129 state slots and a page table of 256 positions. The weights are
+    shapes: nothing is made."""
+    import functools
+    import importlib.util
+    import json
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec
+    from deeplearning4j_tpu.serving.decode.engine import cache_geometry
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+
+    def load(kind):
+        spec = importlib.util.spec_from_file_location(
+            f"compile_test_n3s_{kind}", bench / kind / "nemotron_h.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    ref, models = load("reference"), load("models")
+    real = json.loads(
+        (bench / "configs" / "nemotron-3-super-120b-a12b.json").read_text())
+    config = dict(
+        real, num_hidden_layers=3, hybrid_override_pattern="M*E",
+        n_routed_experts=8, vocab_size=256, max_position_embeddings=256,
+        deployment=dict(real["deployment"], held_experts=[0, 8]))
+    shapes = SimpleNamespace(
+        dims=ref.dims, init_params=lambda c, s: jax.eval_shape(
+            functools.partial(ref.init_params, c, s)))
+    model = models.build(config, 0, shapes, train=False)
+    leaves, treedef = jax.tree_util.tree_flatten(model.params)
+    snapshot = SimpleNamespace(
+        data=tuple(leaves),
+        rebuild=lambda data: jax.tree_util.tree_unflatten(treedef, list(data)))
+    channels, width, context, state = cache_geometry(model)
+    spec = KvCacheSpec(channels=channels, width=width, block_len=16,
+                       num_blocks=1 + 16 * 128, max_context=context,
+                       kv_dtype="bf16", state=state, state_slots=129)
+    return model, snapshot, spec
+
+
+@pytest.mark.parametrize("phase", ["tick", "prefill"])
+def test_nemotron_steps_update_arena_and_state_in_place_on_v5e(
+        one_chip, nemotron_stack, phase):
+    """The served tick of 128 rows as the TPU serves it: the attention
+    layer's pages through the grouped paged kernel, the held experts
+    through ONE relu^2 `grouped_experts` call, no conditional left, no copy
+    of an expert leaf; a 256-token prefill (its experts under conditionals,
+    the oracle). Both update the arena and the grouped Mamba-2 state where
+    they lie, with no temporary of a quarter of the state."""
+    import functools
+
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_prefill_fn,
+                                                          build_tick_fn)
+
+    model, snapshot, spec = nemotron_stack
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    w = spec.table_width
+    if phase == "tick":
+        fn = functools.partial(build_tick_fn, rows_max=128,
+                               attention="paged_kernel",
+                               experts="grouped_kernel")
+        args = (i32(128), i32(128), i32(128), i32(128, w), i32(128))
+    else:
+        fn, args = build_prefill_fn, (i32(1, 256), i32(1), i32(1, w), i32(1))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn(model, snapshot, spec),
+                           donate_argnums=(1,)).lower(
+            on_chip(snapshot.data), on_chip(_cache_arg_specs(spec)),
+            *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= spec.arena_nbytes() + spec.state_nbytes()
+    assert mem.temp_size_in_bytes < spec.state_nbytes() // 4
+    text = compiled.as_text()
+    kernels = re.findall(r"%grouped_experts[.\d]* = \S+ custom-call\(", text)
+    if phase == "tick":
+        assert len(kernels) == 1 and " conditional(" not in text
+        leaves = {"8,1024,2688", "8,2688,1024"}
+        copies = [m.group(0) for m in re.finditer(
+            r"= \w+\[([\d,]*)\]\S* copy\(", text) if m.group(1) in leaves]
+        assert not copies, copies
+    else:
+        assert not kernels

@@ -4,9 +4,11 @@ against the conditionals it replaces on the TPU (`SparseExpertsLayer.
 _held_sum`'s "cond" path, its oracle): at experts shaped as Granite 4.0-H's
 (4096 x 768: whole experts a grid step) and as LongCat-Flash's (6144 x 2048:
 tiles of the hidden width) but narrower, for every row count a tick bucket
-of the two cells holds, and every expert hit, none, a few, dead rows. Then
-which blocks the kernel fetches, which path each block's tick takes, and a
-served tick of each family through either path.
+of the two cells holds, and every expert hit, none, a few, dead rows; the
+relu^2 form of two matrices (Nemotron-H's experts in their latent) at up to
+128 rows, past twice a tick's slots. Then which blocks the kernel fetches,
+which path each block's tick takes, and a served tick of each family
+through either path.
 
 The weights are float32 (XLA's CPU backend has no bfloat16 batch product)
 and both sides sum in float32 in the same order but for a tiled expert's
@@ -27,6 +29,7 @@ import pytest
 from deeplearning4j_tpu import InputType, telemetry
 from deeplearning4j_tpu.kernels import grouped_experts as ge
 from deeplearning4j_tpu.nn.layers.hybrid_ssm import HybridSSMBlock
+from deeplearning4j_tpu.nn.layers.nemotron_h import NemotronHBlock
 from deeplearning4j_tpu.nn.layers.shortcut_moe import (ShortcutMoEBlock,
                                                        SparseExpertsLayer)
 from deeplearning4j_tpu.serving.decode.cache import KvCacheSpec, make_cache
@@ -55,11 +58,13 @@ def shape(request, monkeypatch):
     ge._planned.cache_clear()
 
 
-def _experts(d, h, e, seed=0):
+def _experts(d, h, e, seed=0, gated=True):
     k = jax.random.split(jax.random.PRNGKey(seed), 3)
     w = lambda key, s: jax.random.normal(key, s, jnp.float32) * s[-2] ** -0.5
-    return {"expert_W_g": w(k[0], (e, d, h)), "expert_W_u": w(k[1], (e, d, h)),
-            "expert_W_d": w(k[2], (e, h, d))}
+    p = {"expert_W_u": w(k[1], (e, d, h)), "expert_W_d": w(k[2], (e, h, d))}
+    if gated:
+        p["expert_W_g"] = w(k[0], (e, d, h))
+    return p
 
 
 def _routing(rows, e, hit, seed):
@@ -103,6 +108,35 @@ def test_kernel_matches_the_conditionals(shape, rows):
             assert not np.any(np.asarray(got[-1]))
 
 
+@pytest.mark.parametrize("rows", [1, 7, 64, 128])
+def test_relu2_kernel_matches_the_conditionals(rows):
+    """Two matrices an expert, no gate: `relu(u W_u)^2 W_d`, whole experts a
+    grid step at 256 x 384 (as Nemotron-H's 1,024 x 2,688), 16 held of 64
+    with 6 picks, up to 128 rows: more than twice a tick's slots, where a
+    tick still takes the kernel."""
+    d, h, e = 256, 384, 16
+    layer = SparseExpertsLayer(n_experts=64, top_k=6, expert_hidden=h,
+                               held_experts=[0, e],
+                               expert_activation="relu2")
+    assert layer.decode_experts("tick", d) == "cond"           # the CPU
+    p = _experts(d, h, e, gated=False)
+    assert ge.experts_plan(rows, d, h, e, 4, 2).tiles_an_expert == 1
+    u = jax.random.normal(jax.random.PRNGKey(rows), (rows, d), jnp.float32)
+    kernel = jax.jit(lambda u, w, loads: ge.grouped_experts(
+        u, w, loads, None, p["expert_W_u"], p["expert_W_d"], interpret=True))
+    for hit in (range(e), (), (3,), (0, e - 1, 7)):
+        took, w, loads = _routing(rows, e, hit, seed=rows + len(hit))
+        want = layer._held_sum(p, u, w, took, loads, "cond")
+        got = kernel(u, w, loads)
+        scale = max(float(jnp.max(jnp.abs(want))), 1e-30)
+        assert float(jnp.max(jnp.abs(got - want))) <= RTOL * scale, hit
+        by_hand = sum(w[:, x:x + 1] * jnp.square(jax.nn.relu(
+            u @ p["expert_W_u"][x])) @ p["expert_W_d"][x] for x in hit)
+        assert float(jnp.max(jnp.abs(got - by_hand))) <= RTOL * scale
+        if rows > 1:
+            assert not np.any(np.asarray(got[-1]))
+
+
 def _fetched(loads, tiles):
     """The blocks the pipeline fetches: a step's block where it differs
     from the step before's, the first step's always."""
@@ -138,6 +172,13 @@ def test_plan_tiles_and_vmem_at_the_cells_shapes():
     assert ge.experts_plan(8, 64, 32, 4) is None          # no lane tiles
     assert not ge.grouped_experts_supported(4096, 768, "float16")
     assert ge.grouped_experts_supported(4096, 768, "bfloat16")
+    # Nemotron-H: relu^2 experts of two matrices, 1,024 x 2,688, 128 rows
+    n3s = ge.experts_plan(128, 1024, 2688, 128, 2, matrices=2)
+    assert (n3s.tile, n3s.tiles_an_expert, n3s.steps_a_call) == (2688, 1, 128)
+    assert 2 * 2 * 1024 * n3s.tile * 2 <= ge._WEIGHT_VMEM
+    assert n3s.vmem_bytes < n3s.vmem_limit_bytes < 64 << 20
+    assert ge.grouped_experts_supported(1024, 2688, "bfloat16")
+    assert ge.grouped_experts_supported(1024, 2688, "bfloat16", matrices=2)
 
 
 @pytest.mark.parametrize("block", [
@@ -145,7 +186,10 @@ def test_plan_tiles_and_vmem_at_the_cells_shapes():
     HybridSSMBlock(n_model=256, mixer="attention", expert_hidden=384,
                    n_experts=8, top_k=2, dtype="bfloat16"),
     ShortcutMoEBlock(n_model=256, expert_hidden=384, n_experts=8,
-                     n_identity=4, top_k=2)], ids=["mamba", "gqa", "latent"])
+                     n_identity=4, top_k=2),
+    NemotronHBlock(n_model=512, mixer="moe", expert_hidden=384, latent=256,
+                   n_experts=8, top_k=2, dtype="bfloat16")],
+    ids=["mamba", "gqa", "latent", "nemotron-moe"])
 def test_the_tick_takes_the_kernel_only_on_the_tpu(block, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert block.decode_experts("tick", 256) == "grouped_kernel"
@@ -191,14 +235,31 @@ def _load(kind, name):
     return mod
 
 
+CONFIG_OF = {"granite_moe_hybrid": "granite-4.0-h-small",
+             "longcat_flash": "longcat-flash-chat",
+             "nemotron_h": "nemotron-3-super-120b-a12b"}
+
+
 def _tiny(family):
     """The cell's configuration at a CPU test's size, experts in whole lane
-    tiles: 128 wide, experts 128 wide, two layers, float32."""
-    real = json.loads((BENCH / "configs" / (
-        "granite-4.0-h-small.json" if family == "granite_moe_hybrid"
-        else "longcat-flash-chat.json")).read_text())
+    tiles: 128 wide, experts 128 wide (in a latent of 128), two layers
+    (three for Nemotron-H: Mamba, attention, experts), float32."""
+    real = json.loads((BENCH / "configs" / f"{CONFIG_OF[family]}.json")
+                      .read_text())
     f32 = dict(real["precision"], weights="float32", registry="fp32",
                kv_dtype="fp32", reference="float32")
+    if family == "nemotron_h":
+        return dict(
+            real, hidden_size=256, num_hidden_layers=3,
+            hybrid_override_pattern="M*E", expand=1, mamba_num_heads=8,
+            mamba_head_dim=32, ssm_state_size=16, n_groups=2, chunk_size=8,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+            moe_intermediate_size=128, moe_latent_size=128,
+            moe_shared_expert_intermediate_size=128, n_routed_experts=4,
+            num_experts_per_tok=3, vocab_size=96, max_position_embeddings=64,
+            published=dict(real["published"], n_routed_experts=8),
+            deployment=dict(real["deployment"], held_experts=[0, 4]),
+            precision=f32)
     if family == "granite_moe_hybrid":
         return dict(
             real, hidden_size=128, num_hidden_layers=2,
@@ -232,7 +293,8 @@ def kernel_interpreted(monkeypatch):
                             *a, interpret=True, **kw))
 
 
-@pytest.mark.parametrize("family", ["granite_moe_hybrid", "longcat_flash"])
+@pytest.mark.parametrize("family", ["granite_moe_hybrid", "longcat_flash",
+                                    "nemotron_h"])
 def test_a_tick_through_the_kernel_is_the_tick_through_the_conditionals(
         family, kernel_interpreted):
     """Three live rows and a pad row in one tick over a random cache: the
@@ -277,17 +339,19 @@ def test_the_kernel_leaves_its_record_once_a_call_shape():
         p = _experts(128, 256, 3)
         u = jnp.ones((5, 128), jnp.float32)
         took, w, loads = _routing(5, 3, (0, 2), seed=1)
-        for _ in range(2):
-            ge.grouped_experts(u, w, loads, p["expert_W_g"], p["expert_W_u"],
+        for gate in (p["expert_W_g"], p["expert_W_g"], None, None):
+            ge.grouped_experts(u, w, loads, gate, p["expert_W_u"],
                                p["expert_W_d"], interpret=True)
         records = [e["attrs"] for e in telemetry.tracer().snapshot()
                    if e["name"] == "dl4j/kernels/grouped_experts"]
     finally:
         telemetry.install_tracer(previous)
         ge._planned.cache_clear()
-    assert len(records) == 1
-    rec = records[0]
-    assert (rec["experts"], rec["rows"], rec["d"], rec["h"], rec["tile"],
-            rec["steps_a_call"], rec["rows_padded"], rec["dtype"]) == (
-        3, 5, 128, 256, 256, 3, 8, "float32")
-    assert rec["vmem_bytes"] < rec["vmem_limit_bytes"]
+    assert len(records) == 2            # one a call shape: gated, relu^2
+    for rec, form in zip(records, (("swiglu", 3), ("relu2", 2))):
+        assert (rec["experts"], rec["rows"], rec["d"], rec["h"], rec["tile"],
+                rec["steps_a_call"], rec["rows_padded"], rec["dtype"]) == (
+            3, 5, 128, 256, 256, 3, 8, "float32")
+        assert (rec["activation"], rec["matrices"]) == form
+        assert rec["vmem_bytes"] < rec["vmem_limit_bytes"]
+    assert records[1]["vmem_bytes"] < records[0]["vmem_bytes"]

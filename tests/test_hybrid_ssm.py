@@ -242,7 +242,7 @@ def test_softmax_over_the_picked_logits_and_the_old_rule_beside_it():
     np.testing.assert_array_equal(np.asarray(ids), want_ids)
     close(w, 6.0 * np.take_along_axis(s, want_ids, 1))
     with pytest.raises(ValueError, match="scoring"):
-        SparseExpertsLayer(scoring="sigmoid")
+        SparseExpertsLayer(scoring="sparsemax")
 
 
 def test_experts_layer_matches_the_reference_with_its_shared_expert():
@@ -641,7 +641,8 @@ def test_state_on_the_instants_the_spans_and_the_fetch_counts(span_log):
     # forward over the 64 positions, then the prefill's 8 tokens, one chunk
     scans = [r["attrs"] for r in named("dl4j/layers/ssm_scan")]
     assert scans == [dict(batch=1, tokens=t, chunk=8, chunks=t // 8, heads=8,
-                          head_dim=16, state=16, state_bytes=4 * 8 * 16 * 16)
+                          head_dim=16, state=16, groups=1,
+                          state_bytes=4 * 8 * 16 * 16)
                      for t in (64, 8)]
     # the experts' five counts, summed over the four layers
     fetch = named("dl4j/engine/tick.fetch")[-1]["attrs"]

@@ -59,7 +59,7 @@ def _case(name):
         MixtureOfExpertsLayer, RMSNormLayer, RnnOutputLayer,
         ShortcutMoEBlock, SparseExpertsLayer, Subsampling1DLayer,
         HybridSSMBlock, SambaYBlock, CrossDecoderBlock, LayerNormLayer,
-        SubsamplingLayer, TransformerBlock, VariationalAutoencoder,
+        NemotronHBlock, SubsamplingLayer, TransformerBlock, VariationalAutoencoder,
         ZeroPaddingLayer)
     from deeplearning4j_tpu.nn.layers import RBM
 
@@ -161,6 +161,15 @@ def _case(name):
                             head_dim=2, attention_multiplier=0.5,
                             n_experts=4, top_k=2, expert_hidden=8,
                             held_experts=[0, 2]), rnn_head],
+            InputType.recurrent(8, 6), _rnn_data(f=8)),
+        "NemotronHBlock": lambda: (
+            [NemotronHBlock(mixer="mamba", ssm_heads=4, ssm_head_dim=4,
+                            ssm_state=4, ssm_groups=2, chunk=4),
+             NemotronHBlock(mixer="moe", n_experts=8, top_k=3,
+                            expert_hidden=8, shared_hidden=8, latent=4,
+                            routed_scaling=2.0, held_experts=[0, 4]),
+             NemotronHBlock(mixer="attention", n_heads=4, n_kv_heads=2,
+                            head_dim=2, attention_multiplier=0.5), rnn_head],
             InputType.recurrent(8, 6), _rnn_data(f=8)),
         "SambaYBlock": lambda: (
             [SambaYBlock(mixer="mamba", ssm_state=4, dt_rank=2, chunk=4,

@@ -481,6 +481,24 @@ def test_server_max_batch_above_largest_bucket_is_clamped():
             rtol=1e-5, atol=1e-6)
 
 
+def test_a_burst_of_connects_is_queued_not_dropped():
+    """128 clients connecting at once before one is accepted: every connect
+    completes at once (the listening socket queues them), where a queue of
+    socketserver's default 5 leaves all but a few waiting on TCP's
+    retransmission (1 s, then 3, 7 ...)."""
+    import socket
+    srv = InferenceServer(ModelRegistry())          # bound, not serving
+    socks = []
+    try:
+        for _ in range(128):        # a connect left waiting raises here
+            socks.append(socket.create_connection((srv.host, srv.port),
+                                                  timeout=0.5))
+    finally:
+        for s in socks:
+            s.close()
+        srv._httpd.server_close()
+
+
 def test_batcher_stop_drains_then_rejects():
     b = DynamicBatcher(_echo_runner(), bucket_for=lambda r: 2,
                        max_batch=2, max_wait_us=1000, name="t")

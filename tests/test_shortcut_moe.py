@@ -226,6 +226,31 @@ def test_held_experts_grouped_match_every_expert_over_every_token(
                                np.asarray(got[0][live]), rtol=RTOL, atol=ATOL)
 
 
+@pytest.mark.parametrize("tokens,skew", [(24, False), (400, False),
+                                         (400, True)],
+                         ids=["every-row", "gathered", "overflow"])
+def test_held_experts_in_a_loop_match_one_conditional_each(
+        tokens, skew, monkeypatch):
+    """A layer holding more experts than `_UNROLLED_EXPERTS` runs them in a
+    loop under one conditional: the same products in the same order, so the
+    same sums to float32 rounding, in each of the three ways."""
+    monkeypatch.setattr(shortcut_moe, "_SLOT_FACTOR", 2)
+    layer, p = _experts(held=(4, 8))
+    if skew:
+        p = dict(p, router_bias=p["router_bias"].at[5].add(10.0))
+    u = jax.random.normal(jax.random.PRNGKey(3), (1, tokens, 64), jnp.float32)
+    live = (jnp.arange(tokens) % 3 != 0)[None]
+    want, want_counts = layer.mix(p, u, live)
+    monkeypatch.setattr(shortcut_moe, "_UNROLLED_EXPERTS", 2)
+    lowered = jax.jit(lambda p, u, live: layer.mix(p, u, live)).lower(
+        p, u, live).as_text()
+    assert lowered.count("stablehlo.case") == (1 if tokens == 24 else 2)
+    got, counts = layer.mix(p, u, live)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    assert counts.tolist() == want_counts.tolist()
+
+
 # ---------------------------------------------------------------------------
 # served: prefill, then ticks through the paged latent cache
 # ---------------------------------------------------------------------------
