@@ -53,7 +53,9 @@ values as per-sequence state too, a RING of W slots a sequence (`k`, `v`
 `[slots, W, Hkv*2Dh]` in the weights' dtype): token t lies in ring slot t mod
 W, so a sequence's bytes are bounded by the window whatever its length; a
 prefill writes the last min(length, W) real tokens, a tick writes its token
-and reads its ring. The cross-decoder pages ONE pair of channels of Hkv*2Dh,
+and reads its ring's live slots in place (`ring_diff_attention`, a Pallas
+kernel) where the TPU takes it, else the rows' rings gathered (the oracle).
+The cross-decoder pages ONE pair of channels of Hkv*2Dh,
 written by the full-attention layer and read by it and every cross layer: a
 tick attends over the live pages in place (`paged_diff_attention`, a Pallas
 kernel) where the TPU takes it, else over the gathered view (the oracle). A
@@ -74,8 +76,7 @@ import jax.numpy as jnp
 
 from ..conf.base import LayerConf, register_layer
 from ..conf.input_type import InputType
-from .hybrid_ssm import (conv_prompt, conv_tail, conv_tick, everywhere,
-                         state_tick, to_slots)
+from .hybrid_ssm import conv_prompt, conv_tail, conv_tick, state_tick
 from .shortcut_moe import _F32, _NEG, RMSNormLayer, _mm, _rms_norm
 
 __all__ = ["SambaYBlock", "CrossDecoderBlock", "LayerNormLayer",
@@ -514,7 +515,31 @@ class SambaYBlock(_SambaYConf):
             return _sublayer(p, x, mix, w), kv, sc, None, kept
         return step
 
-    def decode_tick_step(self, io, attention=None):
+    def decode_window_attention(self, phase: str, width: int):
+        """A window layer's tick over its ring: "ring_kernel" (the Pallas
+        kernel, each row's live ring slots read in place) where the backend
+        is the TPU and the ring's heads and window are whole tiles, else
+        "ring_gather" (the rows' rings gathered, the kernel's oracle). A
+        Mamba layer, or a prefill, has nothing to choose."""
+        from ...kernels import pallas_supported
+        from ...kernels.ring_attention import ring_attention_supported
+
+        if self.mixer != "window" or phase != "tick":
+            return None
+        w = self.widths(width)
+        if pallas_supported() and ring_attention_supported(
+                2 * w.head, w.window, self.dtype or "float32"):
+            return "ring_kernel"
+        return "ring_gather"
+
+    def decode_tick_step(self, io, attention=None, window_attention=None):
+        from ...kernels import ring_attention as ring
+
+        window_attention = window_attention or "ring_gather"
+        if window_attention not in ("ring_kernel", "ring_gather"):
+            raise ValueError(f"window_attention must be ring_kernel|"
+                             f"ring_gather, got {window_attention!r}")
+
         def step(p, x, kv, sc, channel, blk, off, tables, positions, lengths,
                  state, slot):
             w = self.widths(x.shape[-1])
@@ -526,24 +551,27 @@ class SambaYBlock(_SambaYConf):
                     out, _, new = mamba_tick(pm, u, state, slot, w)
                     kept.update(new)
                     return out[:, None]
-                q, k, v = self._qkv(pm, u, w)
+                q = _mm(u, pm["W_q"])                           # [B, H*2Dh]
+                k, v = _mm(u, pm["W_k"]), _mm(u, pm["W_v"])
                 at = positions % w.window
                 ring_k = state["k"].at[slot, at].set(k.astype(state["k"].dtype))
                 ring_v = state["v"].at[slot, at].set(v.astype(state["v"].dtype))
                 kept.update(k=ring_k, v=ring_v)
                 lam = _lambda(pm, self.lambda_init)
-                dt = _attention_dtype(pm, ring_k)
-                slots = ring_k.shape[0]
                 # ring slot j is live once the row has reached position j
-                live = lambda pos: jnp.arange(w.window)[None, :] <= pos[:, None]
-                if everywhere(slot.shape[0], slots):
-                    o = diff_attend_rows(
-                        to_slots(q, slot, slots), ring_k, ring_v,
-                        live(to_slots(positions, slot, slots)), lam, w,
-                        dt)[slot]
+                if window_attention == "ring_kernel":  # the COMPILED kernel
+                    o = ring.ring_diff_attention(
+                        q, ring_k, ring_v, slot,
+                        jnp.minimum(positions + 1, w.window),
+                        n_heads=w.heads, n_kv_heads=w.kv_heads,
+                        sm_scale=1.0 / math.sqrt(w.head), interpret=False)
+                    o = o[:, 0] - lam * o[:, 1]
                 else:
-                    o = diff_attend_rows(q, ring_k[slot], ring_v[slot],
-                                         live(positions), lam, w, dt)
+                    o = diff_attend_rows(
+                        q.reshape(-1, w.heads, 2, w.head), ring_k[slot],
+                        ring_v[slot],
+                        jnp.arange(w.window)[None, :] <= positions[:, None],
+                        lam, w, _attention_dtype(pm, ring_k))
                 return _diff_out(pm, o, self.lambda_init, w)[:, None]
 
             return _sublayer(p, x, mix, w), kv, sc, None, kept

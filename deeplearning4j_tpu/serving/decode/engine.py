@@ -114,7 +114,11 @@ routed experts also answers `decode_experts(phase, width)`, the path of
 its held experts' products (`grouped_kernel`: one Pallas kernel a layer,
 on the TPU; `cond`: a conditional an expert), and its tick step takes
 that answer as a third argument; the tick's instant carries it as
-`experts`.
+`experts`. A window layer that keeps a ring answers
+`decode_window_attention(phase, width)` the same way (`ring_kernel`: its
+rows' rings read in place by one Pallas kernel, on the TPU; `ring_gather`:
+the rings gathered), is handed it as `window_attention`, and the tick's
+instant carries it under that name.
 
 The cache pytree is DONATED and laid out `[2L, num_blocks, block_len,
 H*Dh]` (`cache.py` says why), so the arena updates in place on device: a
@@ -334,17 +338,20 @@ def build_prefill_fn(model, snapshot, spec: KvCacheSpec,
 
 def _decode_step(model, snapshot, spec: KvCacheSpec,
                  attention: Optional[str] = None,
-                 experts: Optional[str] = None):
+                 experts: Optional[str] = None,
+                 window_attention: Optional[str] = None):
     emb, blocks, head = split_decode_layers(model)
     attention = attention or _answer_of(blocks, "decode_attention", "tick",
                                         spec)
-    experts = experts or _answer_of(blocks, "decode_experts", "tick",
-                                    emb.n_out)
+    # a layer that answers a question of its own is handed its answer
+    paths = {"experts": (experts, "decode_experts"),
+             "window_attention": (window_attention, "decode_window_attention")}
+    paths = {name: given or _answer_of(blocks, question, "tick", emb.n_out)
+             for name, (given, question) in paths.items()}
     io = CacheIO(spec)
-    steps = _shared_steps(
-        blocks, lambda layer: layer.decode_tick_step(io, attention, experts)
-        if hasattr(layer, "decode_experts")
-        else layer.decode_tick_step(io, attention))
+    steps = _shared_steps(blocks, lambda layer: layer.decode_tick_step(
+        io, attention, **{name: path for name, path in paths.items()
+                          if hasattr(layer, f"decode_{name}")}))
 
     def decode(data, cache, tokens, positions, tables, *slot):
         params = snapshot.rebuild(data)
@@ -364,24 +371,28 @@ def _decode_step(model, snapshot, spec: KvCacheSpec,
 
 def build_decode_fn(model, snapshot, spec: KvCacheSpec,
                     attention: Optional[str] = None,
-                    experts: Optional[str] = None):
-    """Pure one-token decode step (see module docstring). `attention` and
-    `experts` are what the stack's layers answer for a tick over `spec`
-    unless given: a GPT block's "paged_kernel" or an expert layer's
-    "grouped_kernel" is the compiled kernel, whatever the process's
-    default backend (a test compiles it for a described chip)."""
+                    experts: Optional[str] = None,
+                    window_attention: Optional[str] = None):
+    """Pure one-token decode step (see module docstring). `attention`,
+    `experts` and `window_attention` are what the stack's layers answer for
+    a tick over `spec` unless given: a GPT block's "paged_kernel", an expert
+    layer's "grouped_kernel" or a window layer's "ring_kernel" is the
+    compiled kernel, whatever the process's default backend (a test
+    compiles it for a described chip)."""
     return named_step("tick", _decode_step(model, snapshot, spec, attention,
-                                           experts))
+                                           experts, window_attention))
 
 
 def build_tick_fn(model, snapshot, spec: KvCacheSpec, rows_max: int,
                   attention: Optional[str] = None,
-                  experts: Optional[str] = None):
+                  experts: Optional[str] = None,
+                  window_attention: Optional[str] = None):
     """The served tick (see module docstring): the decode step, its input
     tokens selected on the device between the host's and the previous
     tick's ids `last` `[rows_max]`, and its rows' argmax returned beside
     the logits, padded to `rows_max`."""
-    decode = _decode_step(model, snapshot, spec, attention, experts)
+    decode = _decode_step(model, snapshot, spec, attention, experts,
+                          window_attention)
 
     def tick(data, cache, last, tokens, positions, tables, *slot):
         tokens = jnp.where(tokens < 0, last[jnp.maximum(-tokens - 1, 0)],
@@ -471,6 +482,8 @@ class DecodeEngine:
                                             "prefill", self.spec)
         self.experts = _answer_of(blocks, "decode_experts", "tick",
                                   emb.n_out)
+        self.window_attention = _answer_of(
+            blocks, "decode_window_attention", "tick", emb.n_out)
         # what a stack may state of itself beside the contract, for the
         # records: a window of keys a tick reads at most, the layers that
         # read one shared pair of channels, a prefill that hands on each
@@ -569,8 +582,10 @@ class DecodeEngine:
         tables have rows. `options` go to the builder and into the
         record: the phase's `attention`, where its layers have a choice
         (`paged_kernel` / `gather`, `mla_paged` / `mla_absorbed` /
-        `mla_expanded`), and a tick's `experts` (`grouped_kernel` /
-        `cond`), where it has layers with experts."""
+        `mla_expanded`), a tick's `experts` (`grouped_kernel` /
+        `cond`), where it has layers with experts, and its
+        `window_attention` (`ring_kernel` / `ring_gather`), where it has
+        window layers."""
         spec = self.spec
         options = {k: o for k, o in options.items() if o is not None}
         record = {k: n for k, n in (("window", self.window),
@@ -623,7 +638,7 @@ class DecodeEngine:
                 v, functools.partial(build_tick_fn, rows_max=rows_max), "tick",
                 bucket, _i32(rows_max), _i32(bucket), _i32(bucket),
                 _i32(bucket, w), attention=self.attention,
-                experts=self.experts),
+                experts=self.experts, window_attention=self.window_attention),
             f"decode-b{bucket}")
 
     # -- host-facing phases ----------------------------------------------

@@ -2,20 +2,24 @@
 compiled by the chip's own compiler, and what it holds (PERF.md section 5).
 The weights are shapes: nothing is made and nothing runs.
 
-    python3 experiments/tick_hlo.py <config> [attention] [experts] [described]
+    python3 experiments/tick_hlo.py <config> [attention] [experts] [window]
+                                    [described]
 
 `config` names `benchmarks/configs/<config>.json`: `longcat-flash-chat`
-(cell 3, bucket 32), `granite-4.0-h-small` (cell 4, bucket 64) or
+(cell 3, bucket 32), `granite-4.0-h-small` (cell 4, bucket 64),
+`phi-4-mini-flash-reasoning` (cell 5, bucket 64) or
 `nemotron-3-super-120b-a12b` (cell 6, bucket 128).
-`attention` (`mla_absorbed`, `mla_paged`, `gather`, `paged_kernel`) and
-`experts` (`cond`, `grouped_kernel`) are the tick's paths (default: what the
-stack's layers answer on this backend); `described` compiles for a v5e that
-is described, not attached (on the CPU). It writes the optimised HLO to
-.bench_out/hlo/<config>.tick.<attention>.<experts>.hlo.txt (git-ignored)
-and prints the number of conditionals, the ten heaviest fusions by the bytes
-of their operands, every `copy` (with the bytes of its operands and the
-source line its metadata names), and the kernels' custom calls. Nothing is
-timed."""
+`attention` (`mla_absorbed`, `mla_paged`, `gather`, `paged_kernel`,
+`diff_gather`, `diff_paged`), `experts` (`cond`, `grouped_kernel`) and
+`window` (`ring_gather`, `ring_kernel`) are the tick's paths (default: what
+the stack's layers answer on this backend; with `described`, the window
+layers' `ring_kernel`, as they answer on the chip); `described` compiles for
+a v5e that is described, not attached (on the CPU). It writes the optimised
+HLO to .bench_out/hlo/<config>.tick.<attention>.<experts>[.<window>].hlo.txt
+(git-ignored) and prints the number of conditionals, the ten heaviest
+fusions by the bytes of their operands, every `copy` (with the bytes of its
+operands and the source line its metadata names), and the kernels' custom
+calls. Nothing is timed."""
 import functools
 import importlib.util
 import json
@@ -32,10 +36,12 @@ import jax.numpy as jnp
 
 FAMILIES = {"longcat-flash-chat": ("longcat_flash", 32),
             "granite-4.0-h-small": ("granite_moe_hybrid", 64),
+            "phi-4-mini-flash-reasoning": ("phi4flash", 64),
             "nemotron-3-super-120b-a12b": ("nemotron_h", 128)}
 ATTENTIONS = ("mla_absorbed", "mla_paged", "mla_expanded", "gather",
-              "paged_kernel")
+              "paged_kernel", "diff_gather", "diff_paged")
 EXPERTS = ("cond", "grouped_kernel")
+WINDOWS = ("ring_gather", "ring_kernel")
 
 
 def load(kind, name):
@@ -68,11 +74,16 @@ def main(argv):
     described = "described" in argv
     attention = next((a for a in argv if a in ATTENTIONS), None)
     experts = next((a for a in argv if a in EXPERTS), None)
+    ringed = FAMILIES[name][0] == "phi4flash"
+    window = next((a for a in argv if a in WINDOWS),
+                  "ring_kernel" if described and ringed else None)
     config = json.loads(
         (ROOT / "benchmarks" / "configs" / f"{name}.json").read_text())
     ref, models = load("reference", family), load("models", family)
-    shapes = SimpleNamespace(dims=ref.dims, init_params=lambda c, s: jax.eval_shape(
-        functools.partial(ref.init_params, c, s)))
+    shapes = SimpleNamespace(
+        dims=ref.dims, kinds=getattr(ref, "kinds", None),
+        init_params=lambda c, s: jax.eval_shape(
+            functools.partial(ref.init_params, c, s)))
     model = models.build(config, 0, shapes, train=False)
     leaves, treedef = jax.tree_util.tree_flatten(model.params)
     snapshot = SimpleNamespace(
@@ -94,8 +105,9 @@ def main(argv):
     put = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)
+    paths = {"window_attention": window} if window else {}
     fn = build_tick_fn(model, snapshot, spec, rows_max=rows,
-                       attention=attention, experts=experts)
+                       attention=attention, experts=experts, **paths)
     slots = (i32(rows),) if state else ()
     with jax.enable_x64(False):
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(
@@ -105,7 +117,8 @@ def main(argv):
     text = compiled.as_text()
     out = ROOT / ".bench_out" / "hlo"
     out.mkdir(parents=True, exist_ok=True)
-    tag = f"{attention or 'default'}.{experts or 'default'}"
+    tag = f"{attention or 'default'}.{experts or 'default'}" \
+        + (f".{window}" if window else "")
     (out / f"{name}.tick.{tag}.hlo.txt").write_text(text)
     mem = compiled.memory_analysis()
     print(f"{name} tick bucket {rows}, {tag}, "
@@ -136,8 +149,8 @@ def main(argv):
         for size, op, shape, ops, where in rows_:
             print(f"  {op}: {shape} <- {', '.join(ops)[:120]} ({size} bytes)"
                   f" {where}")
-    kernels = re.findall(r"%(paged_\w+?|grouped_experts)[.\d]* = \S+ "
-                         r"custom-call\(", text)
+    kernels = re.findall(r"%(paged_\w+?|ring_\w+?|grouped_experts)[.\d]* = "
+                         r"\S+ custom-call\(", text)
     print("custom calls:", {k: kernels.count(k) for k in set(kernels)})
 
 

@@ -579,6 +579,81 @@ def test_sambay_steps_update_arena_rings_and_state_in_place_on_v5e(
     assert f"bf16[{bucket},256,16,1280]" not in text
 
 
+@pytest.mark.parametrize("rows", [8, 64])
+def test_ring_diff_attention_kernel_compiles_for_v5e(one_chip, rows):
+    """The window layers' kernel at the Phi-4-mini-flash cell's geometry: 20
+    heads of query pairs on 10 key/value heads, the projection's 2,560 lanes
+    as they are, over bfloat16 rings of 65 slots x 512 x 1,280 lanes. The
+    rings are abstract and are not copied: the program holds the rows'
+    queries and outputs beside them."""
+    from deeplearning4j_tpu.kernels.ring_attention import (ring_diff_attention,
+                                                           ring_plan)
+
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    step = jax.jit(lambda q, k, v, slot, live: ring_diff_attention(
+        q, k, v, slot, live, n_heads=20, n_kv_heads=10, sm_scale=0.125,
+        interpret=False))
+    ring = arg(jnp.bfloat16, 65, 512, 1280)
+    with jax.enable_x64(False):
+        lowered = step.lower(arg(jnp.float32, rows, 2560), ring, ring,
+                             arg(jnp.int32, rows), arg(jnp.int32, rows))
+        assert "ring_diff_attention" in lowered.as_text()
+        compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert ring_plan(rows, 512, 20, 10, 1280).vmem_bytes < 16 << 20
+
+
+def test_sambay_tick_reads_the_rings_in_place_through_the_ring_kernel_on_v5e(
+        one_chip, sambay_stack):
+    """The cell's served tick with the window layers' `ring_kernel`: one
+    `ring_diff_attention` call a window layer beside the shared pages' two
+    `paged_diff_attention` calls; no block-diagonal query of the rings' 65
+    slots (`[65, 40, 1280]`) or its scores (`[65, 40, 512]`) left, no copy
+    of a ring, the window layers' `W_q` not relaid out (the cross layers'
+    prologue still copies its own), the rings and the arena aliased."""
+    import functools
+
+    from deeplearning4j_tpu.serving.decode.engine import (_cache_arg_specs,
+                                                          build_tick_fn)
+
+    model, snapshot, spec = sambay_stack
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    w = spec.table_width
+    texts = {}
+    for window in ("ring_kernel", "ring_gather"):
+        fn = functools.partial(build_tick_fn, rows_max=64,
+                               attention="diff_paged",
+                               window_attention=window)
+        with jax.enable_x64(False):
+            compiled = jax.jit(fn(model, snapshot, spec),
+                               donate_argnums=(1,)).lower(
+                on_chip(snapshot.data), on_chip(_cache_arg_specs(spec)),
+                i32(64), i32(64), i32(64), i32(64, w), i32(64)).compile()
+        texts[window] = compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= spec.state_nbytes() \
+            + spec.arena_nbytes()
+    text = texts["ring_kernel"]
+    calls = lambda name, t: len(re.findall(
+        rf"%{name}[.\d]* = \S+ custom-call\(", t))
+    assert (calls("ring_diff_attention", text),
+            calls("paged_diff_attention", text)) == (2, 2)
+    assert calls("ring_diff_attention", texts["ring_gather"]) == 0
+    for shape in ("[65,40,1280]", "[65,40,512]", "[64,40,512]"):
+        assert shape not in text, shape
+    assert "[64,40,512]" in texts["ring_gather"]
+    copies = lambda t: [m.group(0) for m in re.finditer(
+        r"= (\w+)\[([\d,]*)\]\S* copy\(", t)
+        if _nbytes(m.group(1), m.group(2)) >= 2560 * 2560 * 2]
+    # a W_q relaid out a layer: the shared pages' two readers' alone
+    assert len(copies(text)) == 2 < len(copies(texts["ring_gather"])), \
+        copies(text)
+    assert not any("[65,512,1280]" in c for c in copies(text))
+
+
 @pytest.mark.parametrize("rows,d,h,experts", [
     (64, 4096, 768, 36), (1, 4096, 768, 36), (32, 6144, 2048, 16),
     (8, 6144, 2048, 16)], ids=["g4h-64", "g4h-1", "lcf-32", "lcf-8"])

@@ -14,7 +14,12 @@ compiles it for a described v5e.
   * BIT-exact: a row's result is the same alone and among fifteen others;
   * the engine picks the kernel only where it can run (`TransformerBlock.decode_attention`),
     says which path an executable took, and its tick through the kernel
-    agrees with its tick through the view.
+    agrees with its tick through the view;
+  * the differential kernels of the SambaY stack: over paged keys
+    (`paged_diff_attention`) and over each row's own ring of window keys
+    (`kernels/ring_attention.py`, against `diff_attend_rows` over the
+    gathered rings: live edges, several chunks, NaN in every slot it must
+    not read, scattered slots, a row alone and among 63).
 """
 import functools
 
@@ -28,9 +33,11 @@ from deeplearning4j_tpu import (Adam, EmbeddingSequenceLayer, InputType,
                                 MultiLayerNetwork, NeuralNetConfiguration,
                                 RnnOutputLayer, TransformerBlock, telemetry)
 from deeplearning4j_tpu.kernels import paged_attention as paged_mod
+from deeplearning4j_tpu.kernels import ring_attention as ring_mod
 from deeplearning4j_tpu.kernels.paged_attention import (
     paged_attention_supported, paged_decode_attention, paged_latent_attention,
     paged_plan)
+from deeplearning4j_tpu.nn.layers.sambay import Widths, diff_attend_rows
 from deeplearning4j_tpu.serving.decode import engine as engine_mod
 from deeplearning4j_tpu.serving.decode.cache import CacheIO, KvCacheSpec
 from deeplearning4j_tpu.serving.decode.engine import DecodeEngine
@@ -612,3 +619,151 @@ def test_diff_kernel_leaves_its_record_once_a_call_shape():
     assert (recs[0]["diff"], recs[0]["n_heads"], recs[0]["width"],
             recs[0]["dtype"], recs[0]["heads_padded"]) == (
                 1, 4, 256, "bfloat16", 16)
+
+
+# ---------------------------------------------------------------------------
+# differential attention over a window layer's rings (the SambaY self-
+# decoder's tick): each row reads the live slots of its own ring in place
+# ---------------------------------------------------------------------------
+def _rings(positions, heads, kv_heads, dtype="float32", window=512, seed=0,
+           slots=None, d_head=64):
+    """(q [B, H*2Dh], k, v rings [slots, W, Hkv*2Dh], slot [B], positions
+    [B]): every row on a ring of its own, the rows' slots drawn in a
+    shuffled order from 1 .. slots-1 (slot 0 is the trash slot)."""
+    r = np.random.default_rng(seed)
+    b = len(positions)
+    slots = slots or 2 * b + 1
+    width = kv_heads * 2 * d_head
+    k, v = (jnp.asarray(r.normal(size=(slots, window, width)), dtype)
+            for _ in range(2))
+    slot = jnp.asarray(1 + r.permutation(slots - 1)[:b], jnp.int32)
+    q = jnp.asarray(r.normal(size=(b, heads * 2 * d_head)), jnp.float32)
+    return q, k, v, slot, jnp.asarray(positions, jnp.int32)
+
+
+def _ring_run(q, k, v, slot, positions, heads, kv_heads, scale=0.125):
+    return np.asarray(ring_mod.ring_diff_attention(
+        q, k, v, slot, jnp.minimum(positions + 1, k.shape[1]), n_heads=heads,
+        n_kv_heads=kv_heads, sm_scale=scale, interpret=True))
+
+
+def _ring_oracle(q, k, v, slot, positions, heads, kv_heads, lam, scale=0.125):
+    """`diff_attend_rows` over the gathered rings, the window layer's plain
+    path: (A1 - lam A2) v [B, H, 2Dh]; ring slot j is live once the row has
+    reached position j."""
+    d_head = q.shape[1] // (2 * heads)
+    w = Widths(d=0, e=0, n=0, r=0, k=0, chunk=0, heads=heads,
+               kv_heads=kv_heads, head=d_head, window=k.shape[1], mlp=0,
+               eps=0.0)
+    live = jnp.arange(k.shape[1])[None, :] <= positions[:, None]
+    return np.asarray(diff_attend_rows(
+        q.reshape(-1, heads, 2, d_head) * (scale * d_head ** 0.5), k[slot],
+        v[slot], live, lam, w, k.dtype))
+
+
+@pytest.mark.parametrize("positions,heads,kv_heads,dtype", [
+    # live 1, 15, 16, 17, 300, 511, 512 and a row that has wrapped its ring
+    ([0, 14, 15, 16, 299, 510, 511, 1300], 4, 2, "float32"),
+    ([33, 128, 700, 4], 20, 10, "bfloat16"),    # the cell's heads
+], ids=["live-edges", "cell-heads"])
+def test_ring_kernel_matches_both_maps_over_the_gathered_ring(
+        positions, heads, kv_heads, dtype):
+    q, k, v, slot, pos = _rings(positions, heads, kv_heads, dtype)
+    got = _ring_run(q, k, v, slot, pos, heads, kv_heads)
+    assert got.shape == (len(positions), 2, heads, 128)
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" else \
+        dict(rtol=2e-2, atol=2e-2)      # the products' operands in bfloat16
+    # map 1 alone, and A1 - A2
+    for lam, mine in ((0.0, got[:, 0]), (1.0, got[:, 0] - got[:, 1])):
+        np.testing.assert_allclose(
+            mine, _ring_oracle(q, k, v, slot, pos, heads, kv_heads, lam),
+            **tol)
+    with pytest.raises(ValueError, match="disagree"):
+        ring_mod.ring_diff_attention(q, k, v, slot, pos, n_heads=heads,
+                                     n_kv_heads=kv_heads * 4, sm_scale=1.0)
+
+
+def test_ring_rows_of_several_chunks_match_the_gathered_ring(monkeypatch):
+    """Chunks of 64 slots: a row's loop walks up to 8 chunks, the next
+    chunk's DMAs in flight while one is computed, and a chunk's live pieces
+    are 1 to 4 of 16 (one DMA, or the bits of their number)."""
+    monkeypatch.setattr(ring_mod, "_RING_CHUNK_SLOTS", 64)
+    ring_mod._planned_ring.cache_clear()
+    ring_mod._ring_call.clear_cache()
+    try:
+        positions = [0, 63, 64, 65, 79, 200, 447, 511, 900, 30]
+        q, k, v, slot, pos = _rings(positions, 4, 2, seed=6)
+        got = _ring_run(q, k, v, slot, pos, 4, 2)
+        assert ring_mod._planned_ring(10, 21, 512, 4, 2, 256,
+                                      "float32").chunks_a_row == 8
+    finally:
+        ring_mod._planned_ring.cache_clear()
+        ring_mod._ring_call.clear_cache()
+    for lam, mine in ((0.0, got[:, 0]), (1.0, got[:, 0] - got[:, 1])):
+        np.testing.assert_allclose(
+            mine, _ring_oracle(q, k, v, slot, pos, 4, 2, lam),
+            rtol=2e-5, atol=2e-6)
+
+
+def test_ring_dead_slots_and_other_rings_are_never_read():
+    """Every ring slot past a row's live ones, and every ring no row names,
+    holds NaN: the result stays finite and bit for bit what the kernel gives
+    over clean rings."""
+    positions = [0, 14, 16, 300, 511, 2000, 40]
+    q, k, v, slot, pos = _rings(positions, 4, 2, seed=1, slots=12)
+    live = jnp.zeros((12, 512), bool).at[slot].set(
+        jnp.arange(512)[None, :] <= pos[:, None])[..., None]
+    dirty = [jnp.where(live, z, jnp.nan) for z in (k, v)]
+    got = _ring_run(q, *dirty, slot, pos, 4, 2)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, _ring_run(q, k, v, slot, pos, 4, 2))
+
+
+def test_ring_rows_on_scattered_slots_read_their_own_rings():
+    """Rows on slots 9, 2, 6 of 11 (not contiguous, not in order): each
+    row's answer is the oracle's over its own ring alone."""
+    q, k, v, _, pos = _rings([70, 200, 5], 4, 2, seed=4, slots=11)
+    slot = jnp.asarray([9, 2, 6], jnp.int32)
+    got = _ring_run(q, k, v, slot, pos, 4, 2)
+    np.testing.assert_allclose(
+        got[:, 0], _ring_oracle(q, k, v, slot, pos, 4, 2, 0.0),
+        rtol=2e-5, atol=2e-6)
+    for row in range(3):
+        one = jnp.asarray([row])
+        alone = _ring_run(q[one], k[slot[one]], v[slot[one]],
+                          jnp.zeros(1, jnp.int32), pos[one], 4, 2)
+        np.testing.assert_array_equal(alone[0], got[row])
+
+
+def test_ring_row_is_bit_identical_alone_and_among_63_others():
+    positions = np.random.default_rng(5).integers(0, 700, 64)
+    positions[:3] = (0, 511, 95)
+    q, k, v, slot, pos = _rings(positions, 4, 2, "bfloat16", window=128,
+                                seed=5)
+    among = _ring_run(q, k, v, slot, pos, 4, 2)
+    for row in (0, 1, 2, 40):
+        one = slice(row, row + 1)
+        alone = _ring_run(q[one], k, v, slot[one], pos[one], 4, 2)
+        np.testing.assert_array_equal(alone[0], among[row])
+
+
+def test_ring_kernel_leaves_its_record_once_a_call_shape():
+    previous = telemetry.tracer()
+    telemetry.install_tracer(telemetry.Tracer())
+    ring_mod._planned_ring.cache_clear()
+    try:
+        q, k, v, slot, pos = _rings([40, 900], 20, 10, "bfloat16", seed=3)
+        for _ in range(2):
+            _ring_run(q, k, v, slot, pos, 20, 10)
+        recs = [r["attrs"] for r in telemetry.tracer().snapshot()
+                if r["name"] == "dl4j/kernels/ring_attention"]
+    finally:
+        telemetry.install_tracer(previous)
+    assert len(recs) == 1
+    assert {key: recs[0][key] for key in (
+        "rows", "slots", "window", "chunk", "piece", "chunks_a_row",
+        "steps_a_call", "heads_padded", "query_rows", "width", "dtype")} == {
+            "rows": 2, "slots": 5, "window": 512, "chunk": 512, "piece": 16,
+            "chunks_a_row": 1, "steps_a_call": 2, "heads_padded": 24,
+            "query_rows": 48, "width": 1280, "dtype": "bfloat16"}
+    assert recs[0]["vmem_bytes"] < 16 << 20

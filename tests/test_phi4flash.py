@@ -31,6 +31,7 @@ import pytest
 
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.kernels import paged_attention as paged_mod
+from deeplearning4j_tpu.kernels import ring_attention as ring_mod
 from deeplearning4j_tpu.nn.layers import sambay
 from deeplearning4j_tpu.nn.layers.sambay import (CrossDecoderBlock,
                                                  SambaYBlock, selective_scan,
@@ -431,30 +432,49 @@ def test_the_scheduler_serves_it_and_gives_everything_back():
 # ---------------------------------------------------------------------------
 def test_the_tick_through_the_kernel_agrees_with_the_tick_through_the_view(
         monkeypatch):
-    """The cross-decoder's tick with `diff_paged` (the kernel interpreted, as
-    the TPU would compile it) and `diff_gather` (the view), over the same
-    prefilled pages: the same logits, and the same pages written."""
+    """The tick with the kernels (the cross-decoder's `diff_paged` and the
+    window layers' `ring_kernel`, interpreted, as the TPU would compile
+    them) and with the views (`diff_gather`, `ring_gather`), over the same
+    prefilled pages and rings: the same logits, and the same pages and
+    rings written."""
     monkeypatch.setattr(
         paged_mod, "paged_diff_attention",
         lambda *a, interpret, **kw: paged_mod._diff_call(
             *a, kw["n_kv_heads"], kw["sm_scale"], True))
+    monkeypatch.setattr(
+        ring_mod, "ring_diff_attention",
+        lambda *a, interpret, **kw: ring_mod._ring_call(
+            *a, kw["n_heads"], kw["n_kv_heads"], kw["sm_scale"], True))
     config = tiny_config()
     out = {}
-    for attention in ("diff_paged", "diff_gather"):
+    for attention, window in (("diff_paged", "ring_kernel"),
+                              ("diff_gather", "ring_gather")):
         monkeypatch.setattr(CrossDecoderBlock, "decode_attention",
                             lambda self, phase, spec, a=attention:
                             a if phase == "tick" else None)
+        monkeypatch.setattr(SambaYBlock, "decode_window_attention",
+                            lambda self, phase, width, a=window:
+                            a if phase == "tick" and self.mixer == "window"
+                            else None)
         model, registry, engine = serve(config, attention)
-        assert engine.attention == attention
+        assert (engine.attention, engine.window_attention) == (attention,
+                                                               window)
         pool, v = engine.new_pool(), registry.get(attention)
         r = np.random.default_rng(2)
-        prompts = [r.integers(0, 96, n).tolist() for n in (9, 14)]
+        # the first row's ring fills during the ticks; the second's wraps
+        prompts = [r.integers(0, 96, n).tolist() for n in (5, 14)]
         _, logits, tables = _serve(engine, v, pool, prompts, 5)
-        out[attention] = (np.stack(logits), np.asarray(pool.cache["kv"]))
+        rings = [np.asarray(leaf) for layer in pool.cache["state"]
+                 for name, leaf in sorted(layer.items()) if name in ("k", "v")]
+        out[attention] = (np.stack(logits), np.asarray(pool.cache["kv"]),
+                          rings)
     np.testing.assert_allclose(out["diff_paged"][0], out["diff_gather"][0],
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(out["diff_paged"][1], out["diff_gather"][1],
                                rtol=1e-5, atol=1e-6)
+    assert len(out["diff_paged"][2]) == 4       # two window layers' k and v
+    for got, want in zip(out["diff_paged"][2], out["diff_gather"][2]):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
 def test_records_the_paths_the_window_and_the_scan(span_log):
@@ -480,6 +500,48 @@ def test_records_the_paths_the_window_and_the_scan(span_log):
     assert all(s["state_bytes"] == 4 * s["batch"] * 128 * 4 for s in scans)
 
 
+def test_records_the_window_path_and_the_ring_kernel_once_a_call_shape(
+        span_log, monkeypatch):
+    """The tick's executable names the path its window layers took: the
+    rings gathered on the CPU, the kernel where the layers answer it (here
+    interpreted); the prefill has no such choice. The kernel's instant is
+    written once for the call shape its two window layers share."""
+    config = tiny_config()
+    prompts = [[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], [9, 2, 6], [2, 7, 1]]
+    _, registry, engine = serve(config, "gathered")
+    _serve(engine, registry.get("gathered"), engine.new_pool(), prompts, 1)
+    monkeypatch.setattr(
+        ring_mod, "ring_diff_attention",
+        lambda *a, interpret, **kw: ring_mod._ring_call(
+            *a, kw["n_heads"], kw["n_kv_heads"], kw["sm_scale"], True))
+    monkeypatch.setattr(SambaYBlock, "decode_window_attention",
+                        lambda self, phase, width:
+                        "ring_kernel" if phase == "tick"
+                        and self.mixer == "window" else None)
+    ring_mod._planned_ring.cache_clear()
+    ring_mod._ring_call.clear_cache()
+    _, registry, engine = serve(config, "ringed")
+    pool = engine.new_pool()
+    _serve(engine, registry.get("ringed"), pool, prompts, 3)
+    log = span_log.snapshot()
+    named = lambda name: [r["attrs"] for r in log if r["name"] == name]
+    built = {(a["model"], a["phase"]): a
+             for a in named("dl4j/engine/executable")}
+    assert built[("gathered", "tick")]["window_attention"] == "ring_gather"
+    assert built[("ringed", "tick")]["window_attention"] == "ring_kernel"
+    assert not any("window_attention" in a for (_, phase), a in built.items()
+                   if phase == "prefill")
+    rings = named("dl4j/kernels/ring_attention")
+    assert len(rings) == 1
+    assert {key: rings[0][key] for key in (
+        "rows", "slots", "window", "chunk", "piece", "chunks_a_row",
+        "steps_a_call", "n_heads", "n_kv_heads", "width")} == {
+            "rows": 4, "slots": engine.spec.state_slots, "window": 8,
+            "chunk": 8, "piece": 8, "chunks_a_row": 1, "steps_a_call": 4,
+            "n_heads": 4, "n_kv_heads": 2, "width": 32}
+    assert rings[0]["vmem_bytes"] > 0
+
+
 def test_refusals():
     with pytest.raises(ValueError, match="mixer"):
         SambaYBlock(mixer="rwkv")
@@ -488,3 +550,5 @@ def test_refusals():
     with pytest.raises(ValueError, match="diff_paged"):
         CrossDecoderBlock(n_heads=4, n_kv_heads=2, head_dim=8,
                           mlp_hidden=8).decode_tick_step(None, "paged_kernel")
+    with pytest.raises(ValueError, match="ring_kernel"):
+        SambaYBlock(mixer="window").decode_tick_step(None, None, "gather")
